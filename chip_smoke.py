@@ -23,13 +23,14 @@ The quickest proof that the system still starts on the chip.  The phases:
                ``{"ok": true, "device": {...}}``, printed only when every
                phase passed.
 
-Each phase prints one JSON progress line: wall seconds, compile seconds
-(set-up, from jax's own compile events), events/s where it counts events,
-and the device's peak bytes in use.  One process holds the chip for the
-whole run and starts no child that needs it (the native oracle's ``make``
-touches no device).  The phase functions take their sizes, so the tests
-run them tiny on the CPU with ``interpret=True``; ``main`` never runs on a
-CPU.
+Each phase prints one JSON progress line: wall seconds, its compiles and
+their seconds (from the program's compile counter, persistent-cache loads
+included), events/s where it counts events, and the device's peak bytes
+in use.  No timed window may hold a compile.  One process holds the chip
+for the whole run and starts no child that needs it (the native oracle's
+``make`` touches no device).  The phase functions take their sizes, so
+the tests run them tiny on the CPU with ``interpret=True``; ``main``
+never runs on a CPU.
 """
 
 from __future__ import annotations
@@ -55,37 +56,14 @@ def _check(cond, what):
 
 # -- measurement --------------------------------------------------------------
 
-_COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
-)
+def compiled(sp) -> dict:
+    """The compiles inside a finished program span
+    (``cimba_tpu.obs.telemetry.span``), on any thread, from the program's
+    compile counter: their count and their seconds."""
+    from cimba_tpu.obs import telemetry
 
-
-class CompileClock:
-    """Seconds jax spends tracing, lowering and compiling, summed from
-    its own monitoring events while the clock is open."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.compiles = 0
-
-    def _listen(self, event, duration, **_):
-        if event in _COMPILE_EVENTS:
-            self.seconds += duration
-            if event == _COMPILE_EVENTS[-1]:
-                self.compiles += 1
-
-    def __enter__(self):
-        import jax
-
-        jax.monitoring.register_event_duration_secs_listener(self._listen)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-
-        jax.monitoring.unregister_event_duration_listener(self._listen)
+    n, secs = telemetry.compiles_between(sp.t0_ns, sp.t1_ns)
+    return {"compiles": n, "compile_s": secs}
 
 
 def peak_bytes():
@@ -157,6 +135,7 @@ def _stream_timed(spec, n_objects, R, wave, chunk_steps, seed):
     import jax
 
     from cimba_tpu.models import mm1
+    from cimba_tpu.obs.telemetry import span
     from cimba_tpu.runner import experiment as ex
     from cimba_tpu.serve import ProgramCache
 
@@ -166,24 +145,24 @@ def _stream_timed(spec, n_objects, R, wave, chunk_steps, seed):
         summary_path=rep_mean_wait, program_cache=cache,
     )
     t0 = time.perf_counter()
-    with CompileClock() as setup:
+    with span("smoke.setup") as setup:
         jax.block_until_ready(
             ex.run_experiment_stream(
                 spec, mm1.params(16), min(R, 2 * wave), **kw
             )
         )
     setup_wall = time.perf_counter() - t0
-    with CompileClock() as window:
+    with span("smoke.window") as window:
         t0 = time.perf_counter()
         res = ex.run_experiment_stream(spec, mm1.params(n_objects), R, **kw)
         jax.block_until_ready(res)
         wall = time.perf_counter() - t0
-    _check(window.compiles == 0,
-           f"{window.compiles} compiles inside the timed window")
+    n_window = compiled(window)["compiles"]
+    _check(n_window == 0, f"{n_window} compiles inside the timed window")
     events = int(res.total_events)
     return res, {
         "R": R, "N": n_objects, "wave": wave, "chunk_steps": chunk_steps,
-        "setup_s": setup_wall, "compile_s": setup.seconds,
+        "setup_s": setup_wall, **compiled(setup),
         "wall_s": wall, "events": events, "events_per_s": events / wall,
         "n_failed": int(res.n_failed),
     }
@@ -303,7 +282,6 @@ def phase_runner(
     out["uniform01"] = {"reps": list(uniform_reps), "draws": n_draws,
                         "bit_identical": True}
     out["events_per_s"] = out["f32"]["events_per_s"]
-    out["compile_s"] = out["f32"]["compile_s"] + out["f64"]["compile_s"]
     return out
 
 
@@ -364,12 +342,13 @@ def _serve_and_store(reqs):
 
     from cimba_tpu import serve
     from cimba_tpu.obs.audit import stream_result_digest
+    from cimba_tpu.obs.telemetry import span
 
     cache = serve.ProgramCache(store=False)
     out = {}
     events = 0
     t0 = time.perf_counter()
-    with CompileClock() as cc:
+    with span("smoke.serve") as cc:
         max_wave = max(r.n_replications for r in reqs.values())
         with serve.Service(max_wave=max_wave, cache=cache) as svc:
             handles = {k: svc.submit(r) for k, r in reqs.items()}
@@ -384,13 +363,13 @@ def _serve_and_store(reqs):
             out[k] = {"R": r.n_replications, "digest": got,
                       "events": int(served[k].total_events)}
     out["served_wall_s"] = time.perf_counter() - t0
-    out["compile_s"] = cc.seconds
+    out["serve_compile_s"] = compiled(cc)["compile_s"]
 
     # program store: save -> hydrate into a fresh cache -> serve
     req = reqs["mm1"]
     with tempfile.TemporaryDirectory(prefix="cimba_smoke_store_") as root:
         st = serve.ProgramStore(root)
-        with CompileClock() as cc:
+        with span("smoke.store_save") as cc:
             report = st.save_programs(
                 req.spec, req.params, req.n_replications,
                 wave_sizes=(req.wave_size,), chunk_steps=req.chunk_steps,
@@ -418,7 +397,7 @@ def _serve_and_store(reqs):
             "artifact_dispatches",
         )
     }
-    out["store"]["save_compile_s"] = cc.seconds
+    out["store"]["save_compile_s"] = compiled(cc)["compile_s"]
     out["events_per_s"] = events / out["served_wall_s"]
     return out
 
@@ -456,6 +435,7 @@ def phase_kernels(
     from cimba_tpu.core import loop as cl
     from cimba_tpu.core import pallas_run
     from cimba_tpu.models import awacs, mm1
+    from cimba_tpu.obs.telemetry import span
 
     out = {}
     with config.profile("f32"):
@@ -468,7 +448,7 @@ def phase_kernels(
         krun = pallas_run.make_kernel_run(
             spec, chunk_steps=chunk_steps, interpret=interpret
         )
-        with CompileClock() as cc:
+        with span("smoke.kernel_first_call") as cc:
             t0 = time.perf_counter()
             ker = krun(sims)
             jax.block_until_ready(ker)
@@ -480,7 +460,7 @@ def phase_kernels(
     events = int(np.asarray(ker.n_events).sum())
     out["mm1_kernel"] = {
         "L": L, "N": N, "chunk_steps": chunk_steps, "events": events,
-        "compile_s": cc.seconds, "first_call_s": first, "wall_s": wall,
+        **compiled(cc), "first_call_s": first, "wall_s": wall,
         "events_per_s": events / wall,
     }
 
@@ -500,7 +480,6 @@ def phase_kernels(
            f"awacs scorer differs from the jnp trace by {diff}")
     out["awacs_scorer"] = {"targets": n_targets, "max_abs_diff": diff}
     out["events_per_s"] = out["mm1_kernel"]["events_per_s"]
-    out["compile_s"] = cc.seconds
     return out
 
 
@@ -520,6 +499,7 @@ def phase_mesh(*, n_devices=4, R=65536, N=2000, L=4096, chunk_steps=512,
     from cimba_tpu.core import loop as cl
     from cimba_tpu.core import pallas_run
     from cimba_tpu.models import mm1
+    from cimba_tpu.obs.telemetry import span
     from cimba_tpu.runner import experiment as ex
     from cimba_tpu.stats import summary as sm
 
@@ -530,7 +510,7 @@ def phase_mesh(*, n_devices=4, R=65536, N=2000, L=4096, chunk_steps=512,
     with config.profile("f32"):
         spec, _ = mm1.build(record=False)
         params = mm1.params(N)
-        with CompileClock() as cc:
+        with span("smoke.mesh") as cc:
             t0 = time.perf_counter()
             one = ex.run_experiment(spec, params, R, seed=SEED)
             jax.block_until_ready(one)
@@ -579,7 +559,7 @@ def phase_mesh(*, n_devices=4, R=65536, N=2000, L=4096, chunk_steps=512,
         )(sims)
     kernel_matches_xla(k_one, k_many, "the kernel under a mesh")
     out["kernel"] = {"L": L, "devices": n_devices}
-    out["compile_s"] = cc.seconds
+    out["xla"].update(compiled(cc))
     out["events_per_s"] = ev / (t2 - t1)
     return out
 
@@ -588,10 +568,13 @@ def phase_mesh(*, n_devices=4, R=65536, N=2000, L=4096, chunk_steps=512,
 
 
 def _run_phase(name, fn, **kw):
+    from cimba_tpu.obs.telemetry import span
+
     t0 = time.perf_counter()
     line = {"phase": name}
     try:
-        res = fn(**kw)
+        with span("smoke." + name) as sp:
+            res = fn(**kw)
     except Exception as e:
         line.update(status="failed", wall_s=time.perf_counter() - t0,
                     error=f"{type(e).__name__}: {e}"[:2000])
@@ -600,7 +583,7 @@ def _run_phase(name, fn, **kw):
     line.update(
         status="passed",
         wall_s=time.perf_counter() - t0,
-        compile_s=res.pop("compile_s", None),
+        **compiled(sp),
         events_per_s=res.pop("events_per_s", None),
         peak_bytes=peak_bytes(),
         detail=res,

@@ -32,8 +32,11 @@ Host-side: :mod:`~cimba_tpu.obs.telemetry` (the serving control-plane's
 time-series registry, request spans, health sampler — stdlib-only) and
 :mod:`~cimba_tpu.obs.expose` (``/metrics`` Prometheus text, ``/healthz``,
 ``/varz`` over HTTP).  Opt-in with the same discipline: everything takes
-``telemetry=None`` and a None means no threads, no span allocations, and
-compiled programs bitwise-unchanged (docs/17_telemetry.md).
+``telemetry=None`` and a None means no threads, no request span trees,
+and compiled programs bitwise-unchanged (docs/17_telemetry.md).  Always
+on beside it: the program spans of the Service's wave loop and the
+stream runner, and the compile counter, in one bounded ring
+(``telemetry.span``, ``telemetry.program_spans``).
 
 Provenance: :mod:`~cimba_tpu.obs.audit` — the determinism-audit plane
 (docs/18_audit.md): chunk-boundary carry digests (trace-time gated,
@@ -41,8 +44,12 @@ Provenance: :mod:`~cimba_tpu.obs.audit` — the determinism-audit plane
 divergence localization (``tools/audit_diff.py``).
 """
 
-from cimba_tpu.obs import metrics, trace  # noqa: F401
+from cimba_tpu.obs import metrics, telemetry, trace  # noqa: F401
 
-# export, prof, telemetry, and expose are imported lazily by callers
-# (they pull in numpy/json/http and the runner surface; the hot loop
-# only ever needs trace/metrics)
+# the process's one compile counter (docs/17_telemetry.md), from the
+# first import on
+telemetry.count_compiles()
+
+# export, prof and expose are imported lazily by callers (they pull in
+# numpy/json/http and the runner surface; the hot loop only ever needs
+# trace/metrics, and the Service and runner the program spans)

@@ -22,14 +22,26 @@ how often do deadlines miss, is the dispatcher thread alive.  Three parts:
   liveness (the watchdog primitive ``bench.py`` rides), and the
   ``healthz()``/``varz()`` snapshots the exposition server serves.
 
+Beside the plane, and with no switch: the process's **program spans**
+(:func:`span`, :func:`program_spans`) — one bounded in-memory
+:class:`SpanRecorder` ring that the Service's wave loop and the stream
+runner write a span per phase into, each also a
+``jax.profiler.TraceAnnotation`` so that it lands on a device trace's
+host plane — and one compile counter (:func:`count_compiles`) that
+writes every XLA compile into the same ring, charged to the innermost
+span open on the compiling thread.
+
 The disabled == zero-overhead contract (the host-side image of
 ``obs.trace``'s disabled == jaxpr-identical rule): every integration
 point takes ``telemetry=None`` as its default, and None means NO
-background threads, NO span objects allocated on the hot submit path,
-and — because everything here is host-side bookkeeping that never joins
-a trace — compiled programs bitwise-unchanged either way (pinned in
-tests/test_telemetry.py).  This module is stdlib-only by design: it
-imports no jax, so the operator tooling (tools/metrics_dump.py) stays
+background threads, NO request span trees and NO span file, and —
+because everything here is host-side bookkeeping that never joins a
+trace — compiled programs bitwise-unchanged either way (pinned in
+tests/test_telemetry.py).  The program spans are not part of the plane:
+a few per wave and one per submit, always on, in a ring of
+:data:`PROGRAM_RING` records (tests/test_program_spans.py).  This module
+is stdlib-only at import time: jax is imported inside the functions
+that need it, so the operator tooling (tools/metrics_dump.py) stays
 light and nothing here can perturb trace-time state.
 
 See docs/17_telemetry.md.
@@ -48,6 +60,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 __all__ = [
     "Registry", "Family", "SpanRecorder", "Telemetry",
     "METRIC_PREFIX", "build_info",
+    "span", "program_spans", "compiles_between", "count_compiles",
+    "PROGRAM_RING", "COMPILE",
 ]
 
 #: every metric family this package creates is namespaced under this
@@ -594,6 +608,26 @@ class SpanRecorder:
             except OSError:
                 pass
 
+    def next_span_id(self) -> str:
+        """A fresh span id in this recorder's namespace, for a span the
+        caller times itself and hands to :meth:`add` once complete."""
+        with self._lock:
+            self._n += 1
+            return f"s{self._n:08x}{self._suffix}"
+
+    def add(self, rec: dict) -> None:
+        """Append one complete record (a span timed by the caller, or an
+        instant with ``"ph": "i"``) to the ring; no JSONL line."""
+        with self._lock:
+            self.counters["events" if rec.get("ph") == "i"
+                          else "spans_ended"] += 1
+            self.completed.append(rec)
+
+    def records(self) -> List[dict]:
+        """The ring's completed records, oldest first (a copy)."""
+        with self._lock:
+            return list(self.completed)
+
     def open_count(self) -> int:
         with self._lock:
             return len(self._open)
@@ -614,8 +648,7 @@ class SpanRecorder:
         (``pid_of(trace)`` may return None to skip a record).  The
         caller is responsible for per-pid timestamp ordering (sort by
         ``ts``)."""
-        with self._lock:
-            recs = list(self.completed)
+        recs = self.records()
         out = []
         for r in recs:
             pid = pid_of(r["trace"])
@@ -638,6 +671,124 @@ class SpanRecorder:
                     ev["args"]["outcome"] = r["outcome"]
             out.append(ev)
         return out
+
+
+# ---------------------------------------------------------------------------
+# the program's own spans: one recorder per process, always on
+# ---------------------------------------------------------------------------
+
+#: the ring of the process's program spans and compile instants
+PROGRAM_RING = 65536
+#: the name of a compile instant in :func:`program_spans`
+COMPILE = "jax.compile"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_program = SpanRecorder(cap=PROGRAM_RING)
+_local = threading.local()
+_annotation = None
+_counter_lock = threading.Lock()
+_counting = False
+
+
+class _Span:
+    """One program span (see :func:`span`)."""
+
+    __slots__ = ("name", "attrs", "sid", "parent", "t0_ns", "t1_ns", "_ann")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name = name
+        self.attrs = attrs
+        self.t1_ns = None
+
+    def __enter__(self) -> "_Span":
+        global _annotation
+        if _annotation is None:
+            import jax
+
+            _annotation = jax.profiler.TraceAnnotation
+        stack = getattr(_local, "stack", None)
+        if stack is None:
+            stack = _local.stack = []
+        self.parent = stack[-1].sid if stack else None
+        self.sid = _program.next_span_id()
+        stack.append(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.t0_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1_ns = time.monotonic_ns()
+        self._ann.__exit__(exc_type, exc, tb)
+        _local.stack.pop()
+        if exc_type is not None:
+            self.attrs["error"] = exc_type.__name__
+        _program.add({
+            "trace": None, "span": self.sid, "parent": self.parent,
+            "name": self.name, "thread": threading.get_ident(),
+            "t0_ns": self.t0_ns, "t1_ns": self.t1_ns, "attrs": self.attrs,
+        })
+
+
+def span(name: str, **attrs) -> _Span:
+    """A program span on this thread: ``with span("serve.pack", wave=3)
+    as sp:`` stamps ``time.monotonic_ns()`` at entry and exit, records
+    the innermost span open on the thread as its parent, and runs inside
+    ``jax.profiler.TraceAnnotation(name)``, so that a profiled run shows
+    it on the host plane of the device trace.  ``sp.attrs`` may be added
+    to until the span ends.  The complete span lands in the process's
+    ring (:func:`program_spans`); there is no switch and no file."""
+    return _Span(name, attrs)
+
+
+def program_spans() -> List[dict]:
+    """The process's completed program spans and compile instants, in the
+    order they ended, at most :data:`PROGRAM_RING` of them.  A span is
+    ``{"span", "parent", "name", "thread", "t0_ns", "t1_ns", "attrs"}``
+    on the ``time.monotonic_ns()`` clock; a compile instant has ``"ph":
+    "i"``, the name :data:`COMPILE`, ``t0_ns == t1_ns`` (when the
+    compile ended), the innermost span open on the compiling thread as
+    ``parent`` and ``attrs`` ``{"seconds", "fun", "in"}`` (``in`` is that
+    span's name)."""
+    return _program.records()
+
+
+def compiles_between(t0_ns: int, t1_ns: int) -> Tuple[int, float]:
+    """``(count, seconds)`` of the compile instants from ``t0_ns`` to
+    ``t1_ns``, on any thread."""
+    hits = [r["attrs"]["seconds"] for r in program_spans()
+            if r["name"] == COMPILE and t0_ns <= r["t0_ns"] <= t1_ns]
+    return len(hits), float(sum(hits))
+
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    now = time.monotonic_ns()
+    stack = getattr(_local, "stack", None)
+    inner = stack[-1] if stack else None
+    _program.add({
+        "trace": None, "span": None, "ph": "i", "name": COMPILE,
+        "parent": None if inner is None else inner.sid,
+        "thread": threading.get_ident(), "t0_ns": now, "t1_ns": now,
+        "attrs": {"seconds": float(duration), "fun": kw.get("fun_name"),
+                  "in": None if inner is None else inner.name},
+    })
+
+
+def count_compiles() -> None:
+    """Register the process's one compile listener with
+    ``jax.monitoring`` (idempotent; ``cimba_tpu.obs`` calls it on
+    import).  Every XLA backend compile, a persistent-cache load
+    included, becomes a :data:`COMPILE` instant in the ring."""
+    global _counting
+    with _counter_lock:
+        if _counting:
+            return
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _counting = True
 
 
 # ---------------------------------------------------------------------------

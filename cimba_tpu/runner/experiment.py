@@ -730,6 +730,7 @@ def run_experiment_stream(
     (tuned/default/override) is recorded in the run card's
     ``schedule`` block when auditing.
     """
+    from cimba_tpu.obs.telemetry import span
     from cimba_tpu.serve import cache as _pcache_r
     from cimba_tpu.tune import registry as _tune_reg
 
@@ -737,21 +738,22 @@ def run_experiment_stream(
     if isinstance(program_cache, _pcache_r.ProgramCache):
         # respect an explicitly opted-out cache (store=False)
         store = program_cache._store
-    rs = _tune_reg.resolve_entry(
-        spec, n_replications, schedule=schedule, pack=pack,
-        chunk_steps=chunk_steps, wave_size=wave_size, store=store,
-    )
-    with rs.scope():
-        return _stream_impl(
-            spec, params, n_replications,
-            wave_size=rs.wave_size, seed=seed, mesh=mesh, t_end=t_end,
-            pack=rs.pack, chunk_steps=rs.chunk_steps,
-            poll_every=poll_every, summary_path=summary_path,
-            max_regrows=max_regrows, on_wave=on_wave,
-            on_chunk=on_chunk, telemetry=telemetry,
-            program_cache=program_cache, audit=audit,
-            sched_block=rs.block(),
+    with span("runner.stream", spec=spec.name, R=int(n_replications)):
+        rs = _tune_reg.resolve_entry(
+            spec, n_replications, schedule=schedule, pack=pack,
+            chunk_steps=chunk_steps, wave_size=wave_size, store=store,
         )
+        with rs.scope():
+            return _stream_impl(
+                spec, params, n_replications,
+                wave_size=rs.wave_size, seed=seed, mesh=mesh, t_end=t_end,
+                pack=rs.pack, chunk_steps=rs.chunk_steps,
+                poll_every=poll_every, summary_path=summary_path,
+                max_regrows=max_regrows, on_wave=on_wave,
+                on_chunk=on_chunk, telemetry=telemetry,
+                program_cache=program_cache, audit=audit,
+                sched_block=rs.block(),
+            )
 
 
 def _stream_impl(
@@ -786,6 +788,7 @@ def _stream_impl(
     from cimba_tpu.core import loop as _cl
     from cimba_tpu.obs import audit as _obs_audit
     from cimba_tpu.obs import metrics as _metrics
+    from cimba_tpu.obs.telemetry import span
     from cimba_tpu.serve import cache as _pcache
 
     R = int(n_replications)
@@ -870,15 +873,18 @@ def _stream_impl(
                 def on_digest(c, d, _w=n_waves, _aud=aud):
                     _aud.on_chunk(_w, c, d)
             while True:
-                init_j, chunk_j = get_programs(spec)
-                sims = init_j(reps, seeds, t_stops, pw)
-                sims = drive_chunks(
-                    chunk_j, sims, poll_every=poll_every,
-                    on_chunk=on_chunk, on_digest=on_digest,
-                )
+                with span("runner.init", wave=n_waves, lanes=n):
+                    init_j, chunk_j = get_programs(spec)
+                    sims = init_j(reps, seeds, t_stops, pw)
+                with span("runner.run", wave=n_waves):
+                    sims = drive_chunks(
+                        chunk_j, sims, poll_every=poll_every,
+                        on_chunk=on_chunk, on_digest=on_digest,
+                    )
                 if n_regrows >= max_regrows:
                     break
-                err = np.asarray(sims.err)
+                with span("runner.check", wave=n_waves):
+                    err = np.asarray(sims.err)
                 if not np.isin(err, grow_errs).any():
                     break
                 # wave-granular regrow: double the event cap and re-run
@@ -892,7 +898,8 @@ def _stream_impl(
                 )
                 n_regrows += 1
                 sims = None
-            acc = fold_j(acc, sims)
+            with span("runner.fold", wave=n_waves):
+                acc = fold_j(acc, sims)
             # release the wave's batched sims before the next wave's
             # init allocates: the one-wave peak-memory contract (fold_j
             # has the buffers; the host must not keep a second live

@@ -50,6 +50,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
+from cimba_tpu.obs.telemetry import span as _span
 from cimba_tpu.serve import cache as _pcache
 from cimba_tpu.serve.sched import (
     AdmissionQueue,
@@ -799,6 +800,14 @@ class Service:
         default) waits for queue space — the backpressure arm;
         ``block=False`` (or a ``timeout`` expiry) raises
         :class:`QueueFull` instead and counts an admission reject."""
+        with _span("serve.submit") as sp:
+            return self._admit(request, block, timeout, sp)
+
+    def _admit(self, request: Request, block: bool,
+               timeout: Optional[float], sp) -> ResultHandle:
+        """:meth:`submit`'s body, inside its ``serve.submit`` span
+        ``sp``, which it gives the request's seq, its lanes and the
+        nanoseconds spent waiting for the service lock."""
         R = int(request.n_replications)
         if R <= 0:
             raise ValueError(f"n_replications must be positive, got {R}")
@@ -880,7 +889,9 @@ class Service:
                 )
             except _fuse_mod.FusionError:
                 fuse_cls = None
+        asked = time.monotonic_ns()
         with self._lock:
+            sp.attrs["lock_wait_ns"] = time.monotonic_ns() - asked
             if self._closed:
                 raise ServiceClosed(
                     "service is draining/shut down — no new requests"
@@ -896,6 +907,7 @@ class Service:
             self._schedules[label] = rs.block()
             entry = _Entry(request, self._seq, cls, eff_wave,
                            with_metrics)
+            sp.attrs.update(seq=entry.seq, lanes=R)
             entry.tenant = self._tenants.resolve(request.tenant)
             if self.qos:
                 self._qos_tenant(entry.tenant)["submitted"] += 1
@@ -988,7 +1000,9 @@ class Service:
             if rec is not None:
                 rec.end_trace(entry.trace, "rejected")
             raise
+        asked = time.monotonic_ns()
         with self._lock:
+            sp.attrs["lock_wait_ns"] += time.monotonic_ns() - asked
             self._counters["admitted"] += 1
             if self.qos:
                 self._qos_tenant(entry.tenant)["admitted"] += 1
@@ -1615,24 +1629,28 @@ class Service:
                 # delivered)
                 self._serve_refill_wave(entry)
                 continue
-            slots, members, fused = self._pack(entry)
-            try:
-                # the fold is inside the guard too: a summary_path whose
-                # SHAPE preflights fine but whose fold-trace raises (e.g.
-                # a non-Summary statistic fed to the Pébay merge) must
-                # fail the REQUESTS, never kill the dispatcher thread —
-                # a dead dispatcher hangs every outstanding future
-                # (the fused kwarg is only passed when set, so the
-                # retry tests' _run_batch seams keep their signature)
-                sims = (
-                    self._run_batch(slots) if fused is None
-                    else self._run_batch(slots, fused=fused)
-                )
-                self._fold_slots(slots, sims)
-            except Exception as e:
-                self._batch_failed(members, e)
-                continue
-            self._complete_members(members)
+            # one program span per wave, over the pack, assemble, run,
+            # fold and deliver spans its phases open (docs/17)
+            with _span("serve.wave"):
+                slots, members, fused = self._pack(entry)
+                try:
+                    # the fold is inside the guard too: a summary_path
+                    # whose SHAPE preflights fine but whose fold-trace
+                    # raises (e.g. a non-Summary statistic fed to the
+                    # Pébay merge) must fail the REQUESTS, never kill
+                    # the dispatcher thread — a dead dispatcher hangs
+                    # every outstanding future (the fused kwarg is only
+                    # passed when set, so the retry tests' _run_batch
+                    # seams keep their signature)
+                    sims = (
+                        self._run_batch(slots) if fused is None
+                        else self._run_batch(slots, fused=fused)
+                    )
+                    self._fold_slots(slots, sims)
+                except Exception as e:
+                    self._batch_failed(members, e)
+                    continue
+                self._complete_members(members)
 
     def _pack(self, lead: _Entry):
         """Build one wave: the lead's slots first (its own wave
@@ -1651,6 +1669,13 @@ class Service:
         lock); fill candidates are claimed here the same way — one that
         was cancelled in the gap between leaving the queue and the
         claim is dropped, never dispatched (cancel() stays truthful)."""
+        with _span("serve.pack") as sp:
+            return self._pack_wave(lead, sp)
+
+    def _pack_wave(self, lead: _Entry, sp):
+        """:meth:`_pack`'s body, inside its ``serve.pack`` span ``sp``,
+        which it gives the wave's number, its members' seqs and its live
+        and padded lanes."""
         budget = self.max_wave
 
         def plan(entry) -> list:
@@ -1734,6 +1759,8 @@ class Service:
                 time.monotonic(), self._queue.depth(),
                 self._class_sample(), total, padded,
             ))
+        sp.attrs.update(wave=batch_no, seqs=[e.seq for e in members],
+                        lanes=total, padded=padded)
         rec = self._tel.spans if self._tel is not None else None
         if rec is not None:
             for e in members:
@@ -1771,7 +1798,11 @@ class Service:
         static cond and no result reads the leaf).  Folds are
         untouched: each request's slot still folds its own lanes
         through its own fold program, so results stay bitwise the solo
-        run's."""
+        run's.
+
+        Two program spans: ``serve.assemble`` from the class check up
+        to and including the init dispatch, ``serve.run`` until a poll
+        finds no live lane."""
         import jax
         import jax.numpy as jnp
 
@@ -1780,142 +1811,143 @@ class Service:
 
         from cimba_tpu.obs import metrics as _metrics
 
-        lead = slots[0][0]
-        req = lead.request
-        cls_now = _pcache.program_class_key(
-            req.spec, _metrics.enabled(), mesh=self.mesh, pack=req.pack,
-        )
-        if cls_now != lead.cls[0]:
-            # the program CLASS (dtype profile, obs.metrics/trace
-            # flags, eventset layout, the pack default...) was frozen
-            # into the compatibility key at submit; tracing now under
-            # drifted globals would cache a program whose behavior
-            # contradicts that key (and silently serve it to every
-            # later request at this key).  ValueError = permanent:
-            # fail the request loudly instead.
-            raise ValueError(
-                "serve: a trace-time global (dtype profile, "
-                "obs.metrics/obs.trace state, eventset layout, or the "
-                "pack default) changed between this request's submit "
-                "and its dispatch — the compatibility key binds at "
-                "submit time; resubmit after settling the globals"
+        with _span("serve.assemble"):
+            lead = slots[0][0]
+            req = lead.request
+            cls_now = _pcache.program_class_key(
+                req.spec, _metrics.enabled(), mesh=self.mesh, pack=req.pack,
             )
-        if fused is None:
-            init_j, chunk_j = _pcache.get_programs(
-                self.cache, req.spec, mesh=self.mesh, pack=req.pack,
-                chunk_steps=req.chunk_steps,
-                with_metrics=lead.with_metrics,
-            )
-            sid_of = None
-        else:
-            init_j, chunk_j = _pcache.get_fused_wave_programs(
-                self.cache, fused, mesh=self.mesh, pack=req.pack,
-                chunk_steps=req.chunk_steps,
-                with_metrics=lead.with_metrics,
-            )
-            sid_of = {
-                _pcache.spec_fingerprint(s): k
-                for k, s in enumerate(fused.members)
-            }
-        # each member's summary_path preflights against ITS params
-        # shapes (paths may differ — every request folds its own slice
-        # through its own fold program); fingerprint-cached, so a warm
-        # cache skips the re-trace.  On the fused path the member's
-        # spec-id is pinned into an adapter so the preflight traces the
-        # member's OWN init branch (the preflight key is per member
-        # fingerprint either way).
-        seen: set = set()
-        for e, _, n in slots:
-            if id(e) in seen:
-                continue
-            seen.add(id(e))
+            if cls_now != lead.cls[0]:
+                # the program CLASS (dtype profile, obs.metrics/trace
+                # flags, eventset layout, the pack default...) was frozen
+                # into the compatibility key at submit; tracing now under
+                # drifted globals would cache a program whose behavior
+                # contradicts that key (and silently serve it to every
+                # later request at this key).  ValueError = permanent:
+                # fail the request loudly instead.
+                raise ValueError(
+                    "serve: a trace-time global (dtype profile, "
+                    "obs.metrics/obs.trace state, eventset layout, or the "
+                    "pack default) changed between this request's submit "
+                    "and its dispatch — the compatibility key binds at "
+                    "submit time; resubmit after settling the globals"
+                )
             if fused is None:
-                member_init = init_j
+                init_j, chunk_j = _pcache.get_programs(
+                    self.cache, req.spec, mesh=self.mesh, pack=req.pack,
+                    chunk_steps=req.chunk_steps,
+                    with_metrics=lead.with_metrics,
+                )
+                sid_of = None
             else:
-                sid = self._entry_sid(sid_of, e)
+                init_j, chunk_j = _pcache.get_fused_wave_programs(
+                    self.cache, fused, mesh=self.mesh, pack=req.pack,
+                    chunk_steps=req.chunk_steps,
+                    with_metrics=lead.with_metrics,
+                )
+                sid_of = {
+                    _pcache.spec_fingerprint(s): k
+                    for k, s in enumerate(fused.members)
+                }
+            # each member's summary_path preflights against ITS params
+            # shapes (paths may differ — every request folds its own slice
+            # through its own fold program); fingerprint-cached, so a warm
+            # cache skips the re-trace.  On the fused path the member's
+            # spec-id is pinned into an adapter so the preflight traces the
+            # member's OWN init branch (the preflight key is per member
+            # fingerprint either way).
+            seen: set = set()
+            for e, _, n in slots:
+                if id(e) in seen:
+                    continue
+                seen.add(id(e))
+                if fused is None:
+                    member_init = init_j
+                else:
+                    sid = self._entry_sid(sid_of, e)
 
-                def member_init(r, s, t, p, _f=init_j, _sid=sid):
-                    return _f(
-                        r, s, t, jnp.full(r.shape, _sid, jnp.int32), p,
-                    )
+                    def member_init(r, s, t, p, _f=init_j, _sid=sid):
+                        return _f(
+                            r, s, t, jnp.full(r.shape, _sid, jnp.int32), p,
+                        )
 
-            _pcache.preflight_summary_path(
-                self.cache, e.request.spec, member_init,
-                e.request.summary_path, e.request.params,
-                e.request.n_replications, n, e.with_metrics,
-            )
-        total, pad = self._plan_pad(slots)
-        reps = [jnp.arange(lo, lo + n) for _, lo, n in slots]
-        seeds = [
-            ex._seed_column(e.request.seed, n) for e, _, n in slots
-        ]
-        sids = (
-            None if fused is None else [
-                jnp.full((n,), self._entry_sid(sid_of, e), jnp.int32)
-                for e, _, n in slots
+                _pcache.preflight_summary_path(
+                    self.cache, e.request.spec, member_init,
+                    e.request.summary_path, e.request.params,
+                    e.request.n_replications, n, e.with_metrics,
+                )
+            total, pad = self._plan_pad(slots)
+            reps = [jnp.arange(lo, lo + n) for _, lo, n in slots]
+            seeds = [
+                ex._seed_column(e.request.seed, n) for e, _, n in slots
             ]
-        )
-        if fused is None and pad == 0 and all(
-            e.request.t_end is None for e, _, n in slots
-        ):
-            # unpadded all-run-to-completion wave: omit the t_stop leaf
-            # entirely, like the direct stream path — the chunk cond
-            # then skips the per-event horizon check (same program key;
-            # jit re-specializes per pytree structure).  Fused waves
-            # always carry the column (one program per class).
-            t_stops = None
-        else:
-            t_stops = [
-                ex._horizon_column(e.request.t_end, n)
-                for e, _, n in slots
+            sids = (
+                None if fused is None else [
+                    jnp.full((n,), self._entry_sid(sid_of, e), jnp.int32)
+                    for e, _, n in slots
+                ]
+            )
+            if fused is None and pad == 0 and all(
+                e.request.t_end is None for e, _, n in slots
+            ):
+                # unpadded all-run-to-completion wave: omit the t_stop leaf
+                # entirely, like the direct stream path — the chunk cond
+                # then skips the per-event horizon check (same program key;
+                # jit re-specializes per pytree structure).  Fused waves
+                # always carry the column (one program per class).
+                t_stops = None
+            else:
+                t_stops = [
+                    ex._horizon_column(e.request.t_end, n)
+                    for e, _, n in slots
+                ]
+            pws = [
+                ex._slice_params(
+                    e.request.params, e.request.n_replications, lo, n
+                )
+                for e, lo, n in slots
             ]
-        pws = [
-            ex._slice_params(
-                e.request.params, e.request.n_replications, lo, n
+            if pad:
+                # dead masked lanes: t_stop=-inf means the liveness cond is
+                # false at entry — the lane never dispatches an event, and
+                # its (sliced-off) state never joins any fold.  Its params
+                # are the lead's row 0 (real, valid values, so user_init
+                # cannot trip on them); rep/seed values are irrelevant.
+                reps.append(jnp.zeros((pad,), reps[0].dtype))
+                seeds.append(ex._seed_column(0, pad))
+                t_stops.append(jnp.full((pad,), -jnp.inf, t_stops[0].dtype))
+                if sids is not None:
+                    # dead lanes dispatch no events; member 0's init runs
+                    # on them only to produce a valid (masked-off) row
+                    sids.append(jnp.zeros((pad,), jnp.int32))
+                row0 = ex._slice_params(
+                    req.params, req.n_replications, 0, 1
+                )
+                pws.append(jax.tree.map(
+                    lambda x: jnp.broadcast_to(x, (pad,) + x.shape[1:]),
+                    row0,
+                ))
+            if len(reps) == 1:
+                reps_cat, seed_cat, pw_cat = reps[0], seeds[0], pws[0]
+                ts_cat = None if t_stops is None else t_stops[0]
+                sid_cat = None if sids is None else sids[0]
+            else:
+                reps_cat = jnp.concatenate(reps, axis=0)
+                seed_cat = jnp.concatenate(seeds, axis=0)
+                ts_cat = (
+                    None if t_stops is None
+                    else jnp.concatenate(t_stops, axis=0)
+                )
+                sid_cat = (
+                    None if sids is None else jnp.concatenate(sids, axis=0)
+                )
+                pw_cat = jax.tree.map(
+                    lambda *xs: jnp.concatenate(xs, axis=0), *pws
+                )
+            sims = (
+                init_j(reps_cat, seed_cat, ts_cat, pw_cat) if fused is None
+                else init_j(reps_cat, seed_cat, ts_cat, sid_cat, pw_cat)
             )
-            for e, lo, n in slots
-        ]
-        if pad:
-            # dead masked lanes: t_stop=-inf means the liveness cond is
-            # false at entry — the lane never dispatches an event, and
-            # its (sliced-off) state never joins any fold.  Its params
-            # are the lead's row 0 (real, valid values, so user_init
-            # cannot trip on them); rep/seed values are irrelevant.
-            reps.append(jnp.zeros((pad,), reps[0].dtype))
-            seeds.append(ex._seed_column(0, pad))
-            t_stops.append(jnp.full((pad,), -jnp.inf, t_stops[0].dtype))
-            if sids is not None:
-                # dead lanes dispatch no events; member 0's init runs
-                # on them only to produce a valid (masked-off) row
-                sids.append(jnp.zeros((pad,), jnp.int32))
-            row0 = ex._slice_params(
-                req.params, req.n_replications, 0, 1
-            )
-            pws.append(jax.tree.map(
-                lambda x: jnp.broadcast_to(x, (pad,) + x.shape[1:]),
-                row0,
-            ))
-        if len(reps) == 1:
-            reps_cat, seed_cat, pw_cat = reps[0], seeds[0], pws[0]
-            ts_cat = None if t_stops is None else t_stops[0]
-            sid_cat = None if sids is None else sids[0]
-        else:
-            reps_cat = jnp.concatenate(reps, axis=0)
-            seed_cat = jnp.concatenate(seeds, axis=0)
-            ts_cat = (
-                None if t_stops is None
-                else jnp.concatenate(t_stops, axis=0)
-            )
-            sid_cat = (
-                None if sids is None else jnp.concatenate(sids, axis=0)
-            )
-            pw_cat = jax.tree.map(
-                lambda *xs: jnp.concatenate(xs, axis=0), *pws
-            )
-        sims = (
-            init_j(reps_cat, seed_cat, ts_cat, pw_cat) if fused is None
-            else init_j(reps_cat, seed_cat, ts_cat, sid_cat, pw_cat)
-        )
         on_chunk = self._on_chunk
         tel = self._tel
         if tel is not None:
@@ -1970,10 +2002,11 @@ class Service:
             self._note_occupancy(_live(s), _L)
             return None
 
-        return drive_chunks(
-            chunk_j, sims, poll_every=self.poll_every,
-            on_chunk=on_chunk, on_boundary=on_boundary,
-        )
+        with _span("serve.run"):
+            return drive_chunks(
+                chunk_j, sims, poll_every=self.poll_every,
+                on_chunk=on_chunk, on_boundary=on_boundary,
+            )
 
     def _note_occupancy(self, live, lanes: int) -> None:
         """Append one per-chunk occupancy sample — ``live`` is either a
@@ -2740,50 +2773,53 @@ class Service:
         at the first unfolded slot."""
         import jax.numpy as jnp
 
-        off = 0
-        for entry, lo, n in slots:
-            fold_j = _pcache.get_fold(
-                self.cache, entry.with_metrics,
-                entry.request.summary_path,
-            )
-            sl = _pcache.get_gather(self.cache)(
-                sims, jnp.arange(off, off + n)
-            )
-            if entry.acc is None:
-                entry.acc = _pcache.stream_acc(
-                    entry.request.spec, entry.with_metrics
+        with _span("serve.fold"):
+            off = 0
+            for entry, lo, n in slots:
+                fold_j = _pcache.get_fold(
+                    self.cache, entry.with_metrics,
+                    entry.request.summary_path,
                 )
-            entry.acc = fold_j(entry.acc, sl)
-            entry.n_waves += 1
-            entry.next_lo = lo + n
-            off += n
-            if entry.trace is not None:
-                self._tel.spans.event(
-                    entry.trace, "fold", parent=entry.span_wave,
-                    lo=lo, n=n,
+                sl = _pcache.get_gather(self.cache)(
+                    sims, jnp.arange(off, off + n)
                 )
+                if entry.acc is None:
+                    entry.acc = _pcache.stream_acc(
+                        entry.request.spec, entry.with_metrics
+                    )
+                entry.acc = fold_j(entry.acc, sl)
+                entry.n_waves += 1
+                entry.next_lo = lo + n
+                off += n
+                if entry.trace is not None:
+                    self._tel.spans.event(
+                        entry.trace, "fold", parent=entry.span_wave,
+                        lo=lo, n=n,
+                    )
 
     def _complete_members(self, members) -> None:
         """After a successful fold: finish done requests, requeue the
         rest.  No user code runs here — it must not raise (a raise
         after partial requeues could double-queue an entry)."""
-        for entry in members:
-            with self._lock:
-                entry.in_flight = False
-            if entry.trace is not None and entry.span_wave is not None:
-                self._tel.spans.end(entry.span_wave, outcome="ok")
-                entry.span_wave = None
-            if entry.next_lo >= entry.request.n_replications:
-                self._finish_completed(entry)
-            else:
-                # a request larger than one packed wave: remaining
-                # slots go back through the queue at its own priority
-                if entry.trace is not None:
-                    entry.span_queue = self._tel.spans.start(
-                        entry.trace, "queue", parent=entry.span_root,
-                        requeue=True,
-                    )
-                self._queue.requeue(entry)
+        with _span("serve.deliver"):
+            for entry in members:
+                with self._lock:
+                    entry.in_flight = False
+                if entry.trace is not None and entry.span_wave is not None:
+                    self._tel.spans.end(entry.span_wave, outcome="ok")
+                    entry.span_wave = None
+                if entry.next_lo >= entry.request.n_replications:
+                    self._finish_completed(entry)
+                else:
+                    # a request larger than one packed wave: remaining
+                    # slots go back through the queue at its own
+                    # priority
+                    if entry.trace is not None:
+                        entry.span_queue = self._tel.spans.start(
+                            entry.trace, "queue", parent=entry.span_root,
+                            requeue=True,
+                        )
+                    self._queue.requeue(entry)
 
     def _finish_completed(self, entry: _Entry) -> None:
         """Deliver a fully-folded request's StreamResult — the same

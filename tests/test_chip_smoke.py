@@ -9,6 +9,7 @@ surfaces here and not in a chip call.  ``main`` must refuse the CPU.
 import json
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 import chip_smoke as cs
@@ -20,6 +21,7 @@ def test_runner_phase_tiny():
         oracle_reps=64, lanes_exact=8, uniform_reps=(0, 1, 63), n_draws=16,
     )
     assert out["f32"]["events"] > 0 and out["f64"]["events"] > 0
+    assert out["f32"]["compiles"] >= 1 and out["f32"]["compile_s"] > 0
     assert out["per_lane"]["matched"] >= 6
     assert out["uniform01"]["bit_identical"]
 
@@ -48,6 +50,18 @@ def test_mesh_phase_on_four_virtual_devices():
         n_devices=4, R=64, N=100, L=8, chunk_steps=64, interpret=True
     )
     assert out["xla"]["events"] > 0
+
+
+def test_a_phase_line_reports_its_compiles(capsys):
+    def probe():
+        f = jax.jit(lambda x: x * 2.0 + 5.0)
+        f(jnp.ones((29,), jnp.float32)).block_until_ready()
+        return {"events_per_s": None}
+
+    cs._run_phase("probe", probe)
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["status"] == "passed"
+    assert line["compiles"] >= 1 and line["compile_s"] > 0
 
 
 def test_device_phase_refuses_the_cpu():
