@@ -9,6 +9,7 @@ pytree.  Commands (the yield points) live in :mod:`cimba_tpu.core.process`.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from cimba_tpu import config
 from cimba_tpu.core import dyn
@@ -123,8 +124,11 @@ def queue_position(sim: Sim, q, item):
     `include/cmb_objectqueue.h:199`; the reference matches object pointers,
     this matches the f64 payload)."""
     qid = q.id if hasattr(q, "id") else q
-    items = sim.queues.items[qid]
-    cap = items.shape[0]
+    # queue qid's places in the flat ring; the QueueRef's capacity is the
+    # modulus its ring wraps at (a bare id reads the shared ring width)
+    width = sim.queues.items.shape[0] // sim.queues.head.shape[0]
+    cap = getattr(q, "capacity", width)
+    items = lax.dynamic_slice_in_dim(sim.queues.items, qid * width, cap)
     # gather-free: for each physical slot c, its queue position is
     # (c - head) mod cap; a slot is occupied if that position < size.
     # (A permutation gather over the ring would not lower in Mosaic.)

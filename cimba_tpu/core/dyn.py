@@ -429,31 +429,28 @@ def _scan_set2(arr, i0, i1, v, pred, ax: int, B: int, nb: int, add=False):
     return kfori(0, nb, body, arr)
 
 
-def _scan_exchange2(arr, i0, i1, v, do_write, pred, ax: int, B: int, nb: int):
-    n = arr.shape[ax]
+def _scan_exchange1(arr, i, v, do_write, pred, B: int, nb: int):
+    n = arr.shape[0]
     if pred is not True:
-        if ax == 0:
-            i0 = _gate_idx(i0, pred)
-        else:
-            i1 = _gate_idx(i1, pred)
+        i = _gate_idx(i, pred)
     v = jnp.asarray(v, arr.dtype)
 
     def body(k, carry):
         item, a = carry
         start = _blk_start(k, B, n)
-        m = _blk_oh2(arr.shape[0], arr.shape[1], i0, i1, ax, B, k, start)
-        blk = lax.dynamic_slice_in_dim(a, start, B, ax)
+        m = _oh1(B, _blk_own(i, k, B, start))
+        blk = lax.dynamic_slice_in_dim(a, start, B, 0)
         it = _reduce_pick(m, blk)
         # the target row lives in exactly one block, so the owning
         # block's pick IS the full read and non-owning blocks write
         # back their rows bitwise-unchanged
         wv = jnp.where(do_write, v, it)
         blk = _masked_write(blk, m, wv, True)
-        a = lax.dynamic_update_slice_in_dim(a, blk, start, ax)
+        a = lax.dynamic_update_slice_in_dim(a, blk, start, 0)
         item = item | it if arr.dtype == jnp.bool_ else item + it
         return item, a
 
-    item0 = jnp.zeros(arr.shape[2:], arr.dtype)
+    item0 = jnp.zeros(arr.shape[1:], arr.dtype)
     return kfori(0, nb, body, (item0, arr))
 
 
@@ -579,10 +576,10 @@ def dadd(arr, i, v, pred=True):
     return arr + jnp.where(m, v, jnp.zeros((), arr.dtype))
 
 
-def dexchange2(arr, i0, i1, v, do_write, pred=True):
+def dexchange(arr, i, v, do_write, pred=True):
     """Read-or-write at ONE shared one-hot: returns
-    ``(arr[i0, i1], arr.at[i0, i1].set(where(do_write, v, arr[i0, i1])))``
-    gated by ``pred``.
+    ``(arr[i], arr.at[i].set(where(do_write, v, arr[i])))`` gated by
+    ``pred``.
 
     Where ``do_write`` is false the written value is the read itself — a
     bitwise no-op — so a single mask (and a single full-width select)
@@ -590,16 +587,13 @@ def dexchange2(arr, i0, i1, v, do_write, pred=True):
     ring's full-width ops: put and get share the compare and the write
     pass, differing only in a scalar select of the value.
     """
-    n0, n1 = arr.shape[0], arr.shape[1]
-    b2 = _blk2(n0, n1)
-    if b2 is not None:
-        return _scan_exchange2(arr, i0, i1, v, do_write, pred, *b2)
-    if pred is not True:
-        if n1 >= _GATE_IDX_MIN:
-            i1, pred = _gate_idx(i1, pred), True
-        elif n0 >= _GATE_IDX_MIN:
-            i0, pred = _gate_idx(i0, pred), True
-    mask = _oh2(n0, n1, i0, i1)
+    n = arr.shape[0]
+    b = _blk(n)
+    if b is not None:
+        return _scan_exchange1(arr, i, v, do_write, pred, *b)
+    if pred is not True and n >= _GATE_IDX_MIN:
+        i, pred = _gate_idx(i, pred), True
+    mask = _oh1(n, i)
     if pred is not True:
         mask = mask & pred
     item = _reduce_pick(mask, arr)

@@ -79,7 +79,10 @@ ERR_BOUNDARY = 6   # boundary block entered mid-chain inside the kernel
 
 
 class Queues(NamedTuple):
-    items: jnp.ndarray  # [NQ, QCAP] f64 ring buffers
+    #: [NQ * QCAP] f64 ring buffers, queue q's slot k at q * QCAP + k:
+    #: rank-1 so that, vmapped, the lanes fill the TPU tile's 8 sublanes
+    #: ([lanes, NQ, QCAP] with small NQ tiles (NQ, 128): docs/11)
+    items: jnp.ndarray
     head: jnp.ndarray   # [NQ] i32
     size: jnp.ndarray   # [NQ] i32
     acc: ts.StepAccum   # leaves [NQ]: queue-length recording
@@ -227,7 +230,7 @@ def init_sim(spec: ModelSpec, seed, replication, params=None, t0=0.0,
         # pytree — the while_loop body then never touches those leaves),
         # and recording accumulators exist only if some member records
         queues=Queues(
-            items=jnp.zeros((nq, spec.queue_cap_max), _R),
+            items=jnp.zeros((nq * spec.queue_cap_max,), _R),
             head=jnp.zeros((nq,), _I),
             size=jnp.zeros((nq,), _I),
             acc=_batched(ts.step_create(t0, 0.0), nq)
@@ -1342,7 +1345,7 @@ def _make_apply(spec: ModelSpec, used_tags=None):
         slots (so _vswitch traces it once).  The verbs differ only in a
         few scalar selects; sharing lets the ring's full-width ops —
         the largest line in the kernel's per-event budget — serve both:
-        one one-hot, one read pass, one write pass (dyn.dexchange2: a
+        one one-hot, one read pass, one write pass (dyn.dexchange: a
         get "writes back" the value it read, a bitwise no-op).  All
         writes pred-gated straight-line, as before (no whole-Sim branch
         select — each saved select is a full pass over the ring).
@@ -1367,9 +1370,9 @@ def _make_apply(spec: ModelSpec, used_tags=None):
         ok_get = ok & ~is_put
 
         idx = jnp.where(is_put, (head + size) % cap, head)
-        item, items2 = dyn.dexchange2(
-            sim.queues.items, qid, idx, cmd.f, is_put, ok
-        )
+        if len(spec.queues) > 1:
+            idx = qid * spec.queue_cap_max + idx
+        item, items2 = dyn.dexchange(sim.queues.items, idx, cmd.f, is_put, ok)
         dsz = jnp.where(is_put, 1, -1).astype(size.dtype)
         sim = sim._replace(
             queues=Queues(

@@ -13,12 +13,14 @@ Regenerate after an INTENTIONAL semantic change with:
     python -m tests.test_golden
 """
 
+import hashlib
+
 import jax
 import numpy as np
 import pytest
 
 from cimba_tpu.core import loop as cl
-from cimba_tpu.models import awacs, jobshop, mg1, mm1, mmc
+from cimba_tpu.models import awacs, jobshop, mg1, mm1, mmc, tandem
 
 GOLDEN = {
     # model: (seed, rep, params, stat_key) -> (clock, n_events, m1, m2, mn, mx)
@@ -50,7 +52,22 @@ GOLDEN = {
         (200.0, 596, 2.6716417910447765, 1450.3283582089562,
          0.0, 8.0),
     ),
+    "tandem": (
+        # two queues of 8 places: the one golden model whose ring holds
+        # a second queue (at offset 8), wrapping about 50 times a station
+        (777, 17, tandem.params(300), "wait"),
+        (610.2336635248299, 1405, 2.6120549214781335, 4702.333895629721,
+         0.0005334725133820939, 12.385029218394266),
+    ),
 }
+
+#: sha256 over the bytes of every leaf of the tandem run's final Sim,
+#: in pytree order: ring contents included, so it pins the queue slots
+#: element for element.  Taken while the ring was still stored
+#: [queues, places]; the flat ring keeps the same row-major order.
+TANDEM_DIGEST = (
+    "96cba414ff7e19968353cbaed479b8e0ebf2a46762f5cdb0a7ea77e00afda04f"
+)
 
 
 def _run(name):
@@ -62,6 +79,8 @@ def _run(name):
         spec, _ = mg1.build()
     elif name == "jobshop":
         spec, _ = jobshop.build()
+    elif name == "tandem":
+        spec, _ = tandem.build(queue_cap=8)
     else:
         spec, _ = awacs.build(8)
     (seed, rep, params, _key), _ = GOLDEN[name]
@@ -80,6 +99,7 @@ def _check(name):
         np.testing.assert_allclose(float(w.m2), m2, rtol=1e-9)
         np.testing.assert_allclose(float(w.mn), mn, rtol=1e-12)
         np.testing.assert_allclose(float(w.mx), mx, rtol=1e-12)
+    return sim
 
 
 def test_golden_mm1():
@@ -101,6 +121,14 @@ def test_golden_jobshop():
 
 def test_golden_awacs():
     _check("awacs")
+
+
+def test_golden_tandem():
+    sim = _check("tandem")
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(sim):
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == TANDEM_DIGEST
 
 
 if __name__ == "__main__":  # regeneration helper

@@ -300,6 +300,32 @@ def test_random_blocks_compile(one_chip):
             assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_xla_chunk_ring_tiles_eight_lanes(one_chip):
+    """The XLA chunk program (the stream runner's, packed carry) keeps
+    mm1's FIFO ring rank-1 per lane: vmapped, it is ``[lanes, 128]`` in
+    dense ``(8,128)`` tiles.  A ``[lanes, 1, 128]`` ring tiles ``(1,128)``
+    (one lane's row per vreg, 7 of 8 sublanes empty) and made the ring's
+    read and write passes the chip's slowest ops."""
+    import re
+
+    from cimba_tpu import serve
+    from cimba_tpu.runner import experiment as ex
+    from cimba_tpu.serve import cache as pc
+
+    L = 1024
+    with config.profile("f32"):
+        spec, args = _mm1()
+        init_j, chunk_j = pc.get_programs(
+            serve.ProgramCache(store=False), spec, mesh=None, pack=True,
+            chunk_steps=1024, with_metrics=False)
+        sims = jax.eval_shape(
+            init_j, jnp.arange(L), ex._seed_column(1, L), None,
+            ex._slice_params(args, L, 0, L))
+        hlo = chunk_j.lower(_on(one_chip, sims)).compile().as_text()
+    assert f"f32[{L},1,128]" not in hlo
+    assert re.search(rf"f32\[{L},128\]\{{1,0:T\(8,128\)", hlo)
+
+
 # -- slow: the bench width and the other models ------------------------------
 
 

@@ -140,3 +140,50 @@ def test_logger_default_format_includes_rep(capfd):
     out = capfd.readouterr().out
     assert "r=11" in out
     assert "replay: key=0x" in out
+
+
+def test_objectqueue_position_second_queue():
+    """Two queues of different capacities share one flat ring (queue
+    q's slot k at q * widest + k): positions on the second, wrapped
+    queue count from its own head, modulo its own capacity, and never
+    see the first queue's items."""
+    m = Model("twoq", n_ilocals=1, event_cap=16)
+    q1 = m.objectqueue("q1", capacity=8, record=False)
+    q2 = m.objectqueue("q2", capacity=4, record=False)
+
+    # k 0-2: 10, 20, 30 into q1; k 3-6: 1..4 into q2; k 7-8: two gets
+    # from q2; k 9-10: 5, 6 into q2, which wraps its ring
+    @m.block
+    def drive(sim, p, sig):
+        k = api.local_i(sim, p, 0)
+        sim = api.add_local_i(sim, p, 0, 1)
+        f = k.astype(jnp.float64)
+        return sim, cmd.select(
+            k < 3,
+            cmd.put(q1.id, 10.0 * (f + 1), next_pc=drive.pc),
+            cmd.select(
+                k < 7,
+                cmd.put(q2.id, f - 2, next_pc=drive.pc),
+                cmd.select(
+                    k < 9,
+                    cmd.get(q2.id, next_pc=drive.pc),
+                    cmd.select(
+                        k < 11,
+                        cmd.put(q2.id, f - 4, next_pc=drive.pc),
+                        cmd.exit_(),
+                    ),
+                ),
+            ),
+        )
+
+    m.process("driver", entry=drive)
+    spec = m.build()
+    out = jax.jit(cl.make_run(spec))(cl.init_sim(spec, 0, 0, None))
+    assert int(out.err) == 0
+    assert out.queues.items.shape == (2 * 8,)
+    # q2 now holds (front->rear): 3, 4, 5, 6, with 5 and 6 in slots 0, 1
+    for item, pos in [(3.0, 1), (4.0, 2), (5.0, 3), (6.0, 4), (1.0, 0),
+                      (10.0, 0)]:
+        assert int(api.queue_position(out, q2, item)) == pos
+    for item, pos in [(10.0, 1), (20.0, 2), (30.0, 3), (3.0, 0)]:
+        assert int(api.queue_position(out, q1, item)) == pos

@@ -22,7 +22,8 @@ Contracts pinned here:
   stays within 1.2x of the P=32 one;
 * **primitive-level parity**: blocked ``dget/dset/dget2/dset2/dadd``
   match their dense answers under ``vmap`` for float/int/bool leaves
-  (the lanelast + bool32 dynamic-slice rules);
+  (the lanelast + bool32 dynamic-slice rules), and ``dexchange`` (the
+  queue ring's read-or-write) matches plain indexing in both arms;
 * **registration**: both env knobs live in ``config.ENV_KNOBS`` and
   the ``table_scan`` gate rides the check/gates.py identity sweep.
 
@@ -240,3 +241,34 @@ def test_at_scale_compile_and_parity():
         scan = _chunk_leaves(spec, lanes=2, max_steps=32)
     for a, b in zip(dense, scan):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("n", [24, 40], ids=["narrow", "wide"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32, jnp.bool_])
+def test_dexchange_matches_indexing(dtype, n):
+    """``dyn.dexchange`` is ``(arr[j], arr.at[j].set(where(do_write, v,
+    arr[j])))`` gated by ``pred``, in the dense arm and the blocked one:
+    a gated-off lane or an out-of-range index reads zero and writes
+    nothing.  ``n`` 24 keeps the gate in the mask, 40 folds it into the
+    index."""
+    base = jax.random.normal(jax.random.PRNGKey(2), (n,))
+    arr = (base > 0) if dtype == jnp.bool_ else (base * 100).astype(dtype)
+    v = jnp.asarray(True if dtype == jnp.bool_ else 7, dtype)
+    j = jnp.array([0, 5, n - 1, 9, n, -1, 3, 17], jnp.int32)
+    write = jnp.array([True, False, True, True, True, True, False, True])
+    pred = jnp.array([True, True, True, False, True, True, True, True])
+
+    def run():
+        return jax.jit(jax.vmap(
+            lambda i, w, p: dyn.dexchange(arr, i, v, w, p)))(j, write, pred)
+
+    a = np.asarray(arr)
+    for item, out in _dense_scan_pair(run):
+        for k in range(len(j)):
+            jk = int(j[k])
+            hit = bool(pred[k]) and 0 <= jk < n
+            want = a.copy()
+            if hit and bool(write[k]):
+                want[jk] = np.asarray(v)
+            assert item[k] == (a[jk] if hit else np.zeros((), a.dtype)), k
+            np.testing.assert_array_equal(np.asarray(out[k]), want)
