@@ -64,12 +64,14 @@ BANNED_PRIMITIVES = frozenset({
 GATHER_BUDGET: Dict[str, int] = {}
 
 #: per-model chunk-program equation ceiling for JXL004.  Calibrated
-#: ~1.3x over the measured default-knob counts (mm1 8675, awacs 4191
-#: dense / 4475 scan-on, both profiles within a few eqns) so dtype
-#: profiles and the table-scan arm fit, but a per-row unroll (which
-#: multiplies the count by table height) cannot.  Raise only with a
-#: program_size measurement justifying the new floor.
-EQN_BUDGET: Dict[str, int] = {"mm1": 11000, "awacs": 6000}
+#: ~1.3x over the measured default-knob counts (mm1 8675; awacs 8057
+#: dense / 8990 scan-on, both profiles within a few eqns — its chunk
+#: traces the step twice since the boundary phase runs in it: the
+#: deferring per-event step and the boundary phase's full one; it was
+#: 4273 / 4711 before) so dtype profiles and the table-scan arm fit, but
+#: a per-row unroll (which multiplies the count by table height) cannot.
+#: Raise only with a program_size measurement justifying the new floor.
+EQN_BUDGET: Dict[str, int] = {"mm1": 11000, "awacs": 11700}
 
 #: JXL004 sublinearity factor for fused superprograms
 #: (docs/26_wave_fusion.md): a K-member fused chunk program's equation

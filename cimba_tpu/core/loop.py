@@ -134,8 +134,9 @@ class Sim(NamedTuple):
     done: jnp.ndarray      # bool, set by model code (api.stop)
     err: jnp.ndarray       # i32, ERR_* (0 = healthy)
     n_events: jnp.ndarray  # i64, dispatched events (bench metric)
-    #: kernel path only: this lane's next dispatch targets a boundary
-    #: block — the chunk freezes it for the host driver (pallas_run)
+    #: this lane's next dispatch targets a boundary block: a deferring
+    #: step (the kernel's, the XLA boundary chunk's) holds the lane for
+    #: the boundary phase (make_boundary_apply)
     boundary_pending: jnp.ndarray
     #: flight recorder ring (obs.trace.TraceRing) or None — None prunes
     #: the leaves from the pytree, so a disabled recorder costs zero ops
@@ -1902,9 +1903,28 @@ def _make_apply(spec: ModelSpec, used_tags=None):
 # --- the dispatcher -----------------------------------------------------------
 
 
-def make_step(spec: ModelSpec):
-    """Build ``step(sim) -> sim`` dispatching exactly one event."""
+def _defers(spec: ModelSpec, defer_boundary: Optional[bool]) -> bool:
+    """Whether a step or cond built with ``defer_boundary`` defers
+    boundary dispatches, decided at trace time (see :func:`make_step`)."""
+    if not spec.boundary_pcs:
+        return False
+    if defer_boundary is None:
+        return config.KERNEL_MODE
+    return defer_boundary
+
+
+def make_step(spec: ModelSpec, defer_boundary: Optional[bool] = None):
+    """Build ``step(sim) -> sim`` dispatching exactly one event.
+
+    ``defer_boundary`` (models with boundary blocks only): a resume whose
+    target block is a boundary block is not dispatched; the lane raises
+    ``boundary_pending`` and the block's body is a stub in this trace,
+    for :func:`make_boundary_apply` to run the dispatch batched.  ``None``
+    defers exactly in the kernel trace (``config.KERNEL_MODE``)."""
     blocks = list(spec.blocks)
+
+    def _defer():
+        return _defers(spec, defer_boundary)
 
     # The handler table is specialized to the tags the model's blocks can
     # emit, which requires a Sim to trace them against — so it is built
@@ -1918,16 +1938,16 @@ def make_step(spec: ModelSpec):
         return _cache["apply"](sim, p, cmd, is_retry)
 
     def _boundary_stub(sim, p, sig):
-        # placeholder for a boundary block in the KERNEL trace: the block
-        # is unreachable there (dispatch defers it to the chunk driver;
-        # mid-chain entry is flagged ERR_BOUNDARY before the switch), so
-        # its body — the whole point of the marker — stays out of the
-        # kernel jaxpr
+        # placeholder for a boundary block in a deferring trace: the
+        # block is unreachable there (dispatch defers it to the boundary
+        # phase; mid-chain entry is flagged ERR_BOUNDARY before the
+        # switch), so its body — the whole point of the marker — stays
+        # out of the per-event jaxpr
         return sim, pr.exit_()
 
     def run_block(sim: Sim, p, sig):
         table = blocks
-        if config.KERNEL_MODE and spec.boundary_pcs:
+        if _defer():
             table = [
                 _boundary_stub if pc in spec.boundary_pcs else b
                 for pc, b in enumerate(blocks)
@@ -2042,12 +2062,12 @@ def make_step(spec: ModelSpec):
                 if not may_pend:
                     # no retry arm exists: the block always runs and its
                     # command applies directly (no use_pend merge at all)
-                    if config.KERNEL_MODE and spec.boundary_pcs:
+                    if _defer():
                         in_b = boundary_table[dyn.dget(sim.procs.pc, p)] != 0
                         sim = _set_err(sim, in_b, ERR_BOUNDARY)
                     sim2, cmd = run_block(sim, p, sig)
                 elif config.KERNEL_MODE:
-                    if spec.boundary_pcs:
+                    if _defer():
                         # boundary blocks may only be entered by dispatch
                         # (which the kernel defers to the chunk driver) —
                         # reaching one mid-chain would run its stub, so it
@@ -2063,6 +2083,9 @@ def make_step(spec: ModelSpec):
                         lambda a, b: dyn.bwhere(use_pend, a, b), pend, c_blk
                     )
                 else:
+                    if _defer():
+                        in_b = boundary_table[dyn.dget(sim.procs.pc, p)] != 0
+                        sim = _set_err(sim, in_b & ~use_pend, ERR_BOUNDARY)
                     # scalar/XLA path keeps lax.cond: an unbatched pend-retry
                     # must not execute the block (user side effects fire once)
                     sim2, cmd = lax.cond(
@@ -2153,12 +2176,13 @@ def make_step(spec: ModelSpec):
         event, take_e, take_w = ev.peek_merged(
             sim.events, sim.wakes, sim.procs.prio, K_PROC
         )
-        if config.KERNEL_MODE and spec.boundary_pcs:
+        if _defer():
             # a resume whose target block is a boundary block is NOT
             # dispatched here: the event stays in its table, the lane
-            # raises boundary_pending, and the chunk driver applies one
-            # plain-XLA engine step to it between chunks (MXU physics —
-            # parity with the reference's in-coroutine CUDA launches)
+            # raises boundary_pending, and the boundary phase applies one
+            # full engine step to it (make_boundary_apply: MXU physics
+            # batched over lanes — parity with the reference's
+            # in-coroutine CUDA launches)
             pc_t = dyn.dget(
                 sim.procs.pc, jnp.maximum(event.subj, 0)
             )
@@ -2230,10 +2254,13 @@ def make_step(spec: ModelSpec):
     return step
 
 
-def make_cond(spec: ModelSpec, t_end: Optional[float] = None):
+def make_cond(spec: ModelSpec, t_end: Optional[float] = None,
+              defer_boundary: Optional[bool] = None):
     """Build the per-replication liveness predicate ``cond(sim) -> bool``
     used by :func:`make_run` (and by the Pallas kernel runner, which hoists
-    the while-loop out of vmap and needs the same predicate)."""
+    the while-loop out of vmap and needs the same predicate).
+    ``defer_boundary`` as in :func:`make_step`: a deferring cond holds a
+    lane whose next dispatch is a boundary block."""
 
     def cond(sim: Sim):
         empty = ev.is_empty(sim.events) & ev.wakes_empty(sim.wakes)
@@ -2249,10 +2276,9 @@ def make_cond(spec: ModelSpec, t_end: Optional[float] = None):
         else:
             out_of_work = empty
         live = ~sim.done & (sim.err == 0) & ~out_of_work
-        if config.KERNEL_MODE and spec.boundary_pcs:
-            # a lane whose next dispatch is a boundary block freezes in
-            # the chunk; the chunk driver steps it host-side (the XLA
-            # path traces with KERNEL_MODE off and never sees this)
+        if _defers(spec, defer_boundary):
+            # a lane whose next dispatch is a boundary block holds until
+            # the boundary phase steps it (make_boundary_apply)
             live = live & ~sim.boundary_pending
         # horizon: a Sim carrying a per-lane ``t_stop`` leaf (the
         # heterogeneous-wave path) reads it INSTEAD of the static
@@ -2376,6 +2402,113 @@ def make_run(
 # --- chunked dispatch: watchdog-proof runs of any length ---------------------
 
 
+def make_boundary_apply(spec: ModelSpec):
+    """Build ``apply(sims) -> sims`` over a batched Sim (leading lane
+    axis): one full engine step — boundary blocks' bodies included — on
+    exactly the lanes that hold ``boundary_pending``, which it clears;
+    the other lanes pass through unchanged.  The one home of the
+    boundary phase: the XLA chunk (:func:`make_chunk`) runs it inside
+    its program, the kernel driver (``core.pallas_run``) between Pallas
+    chunks.  Since the step is vmapped, a boundary block's bulk work
+    (AWACS's scorer) sees every lane at once."""
+    xstep = jax.vmap(make_step(spec, defer_boundary=False))
+
+    def apply(sims: Sim) -> Sim:
+        with jax.named_scope("runner.boundary"):
+            pending = sims.boundary_pending
+            cleared = sims._replace(
+                boundary_pending=jnp.zeros_like(pending)
+            )
+            stepped = xstep(cleared)
+            return jax.tree.map(
+                lambda a, b: jnp.where(
+                    pending.reshape(pending.shape + (1,) * (a.ndim - 1)),
+                    a,
+                    b,
+                ),
+                stepped,
+                cleared,
+            )
+
+    return apply
+
+
+#: the boundary chunk's counters, in the order of its third output
+BOUNDARY_COUNTERS = (
+    "boundary_rounds",      # boundary phases run
+    "boundary_dispatches",  # boundary-block dispatches (AWACS: dwells)
+    "lane_steps_run",       # lanes x steps of the batched loops
+    "lane_steps_dispatched",  # of those, steps that dispatched an event
+)
+
+
+def _make_boundary_chunk(spec: ModelSpec, t_end, max_steps: int):
+    """:func:`make_chunk` for a model with boundary blocks: rounds of
+    the per-event loop, whose step defers boundary dispatches (the lane
+    holds with ``boundary_pending``), each followed by the boundary
+    phase (:func:`make_boundary_apply`) where some lane is pending.  A
+    round's loop ends when every lane is pending or done, or after
+    ``spec.boundary_round_steps`` steps: in AWACS every lane's dwells
+    fall at the same instants, so a round is one dwell of the wave.
+    Each lane dispatches exactly the events, in exactly the order, of
+    the per-event path.  ``max_steps`` bounds the chunk's steps: the
+    loops' iterations and boundary phases over all its rounds, so no
+    lane dispatches more.  Returns ``chunk(sims) -> (sims, any_live,
+    counters)``, ``counters`` the :data:`BOUNDARY_COUNTERS` of this
+    chunk (config.COUNT)."""
+    lstep = make_step(spec, defer_boundary=True)
+    lcond = make_cond(spec, t_end, defer_boundary=True)
+
+    def lane_run(sim: Sim, kmax):
+        # the per-leaf carry, never the packed one: packing concatenates
+        # and slices the whole state every step, and a boundary model's
+        # few wide tables (AWACS: 100 kB a lane) pay for it (PERF.md,
+        # section 4)
+        return lax.while_loop(
+            lambda kc: lcond(kc[1]) & (kc[0] < kmax),
+            lambda kc: (kc[0] + jnp.asarray(1, _I), lstep(kc[1])),
+            (jnp.zeros((), _I), sim),
+        )
+
+    vcond = jax.vmap(make_cond(spec, t_end, defer_boundary=True))
+    apply = make_boundary_apply(spec)
+    round_steps = spec.boundary_round_steps or max_steps
+
+    def chunk(sims: Sim):
+        n = sims.boundary_pending.shape[0]
+        _C = config.COUNT.dtype  # resolved in this trace's profile
+
+        def rcond(c):
+            used, sims, _ = c
+            return (used < max_steps) & jnp.any(vcond(sims))
+
+        def rbody(c):
+            used, sims, ctr = c
+            limit = jnp.minimum(round_steps, max_steps - used)
+            k, sims = jax.vmap(lane_run, in_axes=(0, None))(sims, limit)
+            pending = sims.boundary_pending
+            some = jnp.any(pending)
+            sims = lax.cond(some, apply, lambda s: s, sims)
+            steps = jnp.max(k) + some.astype(_I)
+            ctr = ctr + jnp.stack([
+                some.astype(_C),
+                jnp.sum(pending, dtype=_C),
+                steps.astype(_C) * n,
+                jnp.zeros((), _C),
+            ])
+            return used + steps, sims, ctr
+
+        ev0 = jnp.sum(sims.n_events, dtype=_C)
+        _, sims, ctr = lax.while_loop(
+            rcond, rbody,
+            (jnp.zeros((), _I), sims, jnp.zeros((4,), _C)),
+        )
+        ctr = ctr.at[3].set(jnp.sum(sims.n_events, dtype=_C) - ev0)
+        return sims, jnp.any(vcond(sims)), ctr
+
+    return chunk
+
+
 def make_chunk(
     spec: ModelSpec,
     t_end: Optional[float] = None,
@@ -2397,7 +2530,25 @@ def make_chunk(
     chunk boundary.  Trace-time gated like the flight recorder:
     ``audit=False`` (the default) takes the historical code path —
     the chunk jaxpr is character-identical to one built before the
-    knob existed (pinned in tests/test_audit.py)."""
+    knob existed (pinned in tests/test_audit.py).
+
+    A model with boundary blocks gets :func:`_make_boundary_chunk`'s
+    program, which ignores ``pack`` (its loops carry the Sim per leaf)
+    and whose outputs carry its counters third, before an audit
+    digest."""
+    if spec.boundary_pcs:
+        bchunk = _make_boundary_chunk(spec, t_end, max_steps)
+        if not audit:
+            return bchunk
+
+        from cimba_tpu.obs import audit as obs_audit
+
+        def audited(sims: Sim):
+            out = bchunk(sims)
+            return out + (obs_audit.sim_digest(out[0]),)
+
+        return audited
+
     bounded = make_run(spec, t_end=t_end, pack=pack, max_steps=max_steps)
     cond = make_cond(spec, t_end)
 
@@ -2497,6 +2648,7 @@ def drive_chunks(
     n0: int = 0,
     on_digest=None,
     on_boundary=None,
+    on_counters=None,
 ) -> Sim:
     """Host loop over a jitted, donated ``chunk(sims) -> (sims,
     any_live)``: re-dispatch until every lane is done.
@@ -2526,6 +2678,10 @@ def drive_chunks(
     chunks after completion still append (their digests repeat the
     settled state — deterministic, so trails stay comparable).
 
+    ``on_counters(n, vec)`` fires per chunk with a boundary chunk's
+    counters (:data:`BOUNDARY_COUNTERS`, a device array; the chunk
+    programs of models with boundary blocks, :func:`make_chunk`).
+
     ``on_boundary(n, sims)`` fires after each chunk with the CURRENT
     batched Sim, before it is donated into the next dispatch — the
     refill hook (docs/22_refill.md): the hook may inspect per-lane
@@ -2547,7 +2703,9 @@ def drive_chunks(
         sims, any_live = out[0], out[1]
         n += 1
         if on_digest is not None and len(out) > 2:
-            on_digest(n, out[2])
+            on_digest(n, out[-1])
+        if on_counters is not None:
+            on_counters(n, out[2])
         if on_chunk is not None:
             on_chunk(n)
         if on_boundary is not None:

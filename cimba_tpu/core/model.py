@@ -146,6 +146,9 @@ class ModelSpec:
     #: pcs of blocks dispatched OUTSIDE the Pallas kernel at chunk
     #: boundaries (see Model.boundary_block); empty for most models
     boundary_pcs: tuple = ()
+    #: steps a lane of the XLA boundary chunk may run in one round before
+    #: the boundary phase (see Model); None: until every lane waits
+    boundary_round_steps: Optional[int] = None
 
     @property
     def n_procs(self) -> int:
@@ -166,8 +169,16 @@ class Model:
         # overflow (capacity is n_procs by construction), so this no
         # longer sizes anything
         max_chain: int = 16,
+        boundary_round_steps: Optional[int] = None,
     ):
+        """``boundary_round_steps`` (models with boundary blocks): the XLA
+        chunk runs the boundary phase once every live lane waits for it
+        or has run this many steps in the round, whichever comes first —
+        a little above the events a lane dispatches between two of its
+        boundary dispatches, so a lane that needs more than usual does
+        not hold the whole wave.  ``None`` waits for every lane."""
         self.name = name
+        self.boundary_round_steps = boundary_round_steps
         self.n_flocals = n_flocals
         self.n_ilocals = n_ilocals
         self.event_cap = event_cap
@@ -394,4 +405,5 @@ class Model:
             user_init=self._user_init,
             user_handlers=list(self._user_handlers),
             boundary_pcs=tuple(self._boundary_pcs),
+            boundary_round_steps=self.boundary_round_steps,
         )

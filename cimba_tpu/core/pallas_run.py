@@ -477,34 +477,17 @@ def make_kernel_run(
                 # host-side application of boundary-block dispatches:
                 # ONE ordinary XLA engine step (KERNEL_MODE off — MXU
                 # matmuls, gathers, everything) on exactly the frozen
-                # lanes, between chunks.  A fresh make_step instance:
-                # the kernel one's handler cache is bound to kernel-mode
-                # tracing.
-                xstep = jax.vmap(cl.make_step(spec))
+                # lanes, between chunks: the boundary phase the XLA
+                # chunk runs in its program (cl.make_boundary_apply)
+                apply = cl.make_boundary_apply(spec)
 
                 def _boundary_apply(*ls):
                     sims = jax.tree.unflatten(
                         treedef, [jnp.moveaxis(l, -1, 0) for l in ls]
                     )
-                    pending = sims.boundary_pending  # [L]
-                    cleared = sims._replace(
-                        boundary_pending=jnp.zeros_like(pending)
-                    )
-                    stepped = xstep(cleared)
-                    out = jax.tree.map(
-                        lambda a, b: jnp.where(
-                            pending.reshape(
-                                pending.shape + (1,) * (a.ndim - 1)
-                            ),
-                            a,
-                            b,
-                        ),
-                        stepped,
-                        cleared,
-                    )
                     return [
                         jnp.moveaxis(l, 0, -1)
-                        for l in jax.tree.leaves(out)
+                        for l in jax.tree.leaves(apply(sims))
                     ]
 
                 pending_any = jax.jit(

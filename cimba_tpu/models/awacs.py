@@ -23,12 +23,12 @@ movement costs nothing between events, exactly like the reference storing
 
 from __future__ import annotations
 
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 import cimba_tpu.random as cr
 from cimba_tpu import config
@@ -44,7 +44,7 @@ ARENA = 100.0          # square arena half-size
 SPEED = 5.0            # target speed
 LEG_MEAN = 4.0         # mean straight-leg duration
 DETECT_RANGE = 40.0    # sensor detection radius
-DWELL = 0.04 * 25      # dwell interval (scaled tut_5 pattern)
+DWELL = 0.04 * 25      # default dwell interval (25x tut_5's 0.04 s)
 
 # --- NN detection scorer (BASELINE configs[4]: "on-device NN scoring",
 # the reference's CUDA physics hook `tutorial/tut_5_3.cu` re-imagined as a
@@ -80,27 +80,32 @@ def _make_nn_weights():
 _NN_WEIGHTS = _make_nn_weights()
 
 
+def _feature_rows(px, py, vx, vy):
+    """The scorer's 8 features and the range gaussian, elementwise over
+    any shape: one home for the formula, shared by the plain path
+    (columns) and the kernel (rows of a lane-dense tile)."""
+    r2 = px * px + py * py
+    g = jnp.exp(-r2 / jnp.float32(DETECT_RANGE**2))
+    radial = (px * vx + py * vy) / jnp.float32(SPEED * DETECT_RANGE)
+    feats = [
+        px / ARENA,
+        py / ARENA,
+        r2 / jnp.float32(ARENA**2),
+        g,
+        vx / SPEED,
+        vy / SPEED,
+        radial,
+        jnp.ones_like(g),
+    ]
+    return feats, g
+
+
 def _nn_features(pos, vel):
     """[N,2],[N,2] -> ([N,F] f32 features, [N] f32 range gaussian)."""
     pos = pos.astype(jnp.float32)
     vel = vel.astype(jnp.float32)
-    r2 = jnp.sum(pos * pos, axis=1)
-    g = jnp.exp(-r2 / jnp.float32(DETECT_RANGE**2))
-    radial = jnp.sum(pos * vel, axis=1) / jnp.float32(SPEED * DETECT_RANGE)
-    feats = jnp.stack(
-        [
-            pos[:, 0] / ARENA,
-            pos[:, 1] / ARENA,
-            r2 / jnp.float32(ARENA**2),
-            g,
-            vel[:, 0] / SPEED,
-            vel[:, 1] / SPEED,
-            radial,
-            jnp.ones_like(g),
-        ],
-        axis=1,
-    )
-    return feats, g
+    feats, g = _feature_rows(pos[:, 0], pos[:, 1], vel[:, 0], vel[:, 1])
+    return jnp.stack(feats, axis=1), g
 
 
 def _nn_forward(feats, g, w1, b1, w2, b2, w3, b3):
@@ -116,55 +121,140 @@ def _nn_forward(feats, g, w1, b1, w2, b2, w3, b3):
     return jax.nn.sigmoid(logit[:, 0])
 
 
-def _nn_kernel(f_ref, g_ref, w1, b1, w2, b2, w3, b3, out_ref):
-    out_ref[...] = _nn_forward(
-        f_ref[...], g_ref[...][0],
-        w1[...], b1[...][0], w2[...], b2[...][0], w3[...], b3[...][0],
-    )[None]
+#: the kernel's name in a device trace (benchmark/metrics reads it)
+KERNEL_NAME = "awacs_nn_score"
+#: rows of one kernel block: targets laid along the 128-wide lane axis
+ROW_BLOCK = 4096
+#: the scorer's matmul precision on the MXU: f32 operands as written,
+#: every pass of the f32 product (PERF.md, section 4)
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _nn_kernel(x_ref, w1t, b1, w2t, b2, w3t, b3, out_ref):
+    """One block of rows: x_ref [4, TB] (pos x, pos y, vel x, vel y)
+    -> out_ref [1, TB] detection probabilities.  Rows ride the lane
+    axis, so every operand and the output are lane-dense; the stack is
+    the transposed form of :func:`_nn_forward`."""
+    x = x_ref[...]
+    feats, g = _feature_rows(x[0:1], x[1:2], x[2:3], x[3:4])
+    f = jnp.concatenate(feats, axis=0)  # [F, TB]
+
+    def dot(w, h):
+        return jnp.dot(w, h, precision=PRECISION,
+                       preferred_element_type=jnp.float32)
+
+    h1 = jax.nn.relu(dot(w1t[...], f) + b1[...])
+    h2 = jax.nn.relu(dot(w2t[...], h1) + b2[...])
+    h2g = jnp.concatenate([h2, g], axis=0)  # [H+1, TB]
+    out_ref[...] = jax.nn.sigmoid(dot(w3t[...], h2g) + b3[...])
+
+
+def _score_rows(x, ws, interpret):
+    """[4, R] rows of (pos x, pos y, vel x, vel y) -> [R] probabilities:
+    ONE pallas_call over every row, tiled in blocks of ROW_BLOCK (fewer
+    rows take one block of the next multiple of 128).  ``ws`` are the
+    kernel's weights (:func:`_kernel_weights`)."""
+    r = x.shape[1]
+    tb = min(ROW_BLOCK, -(-r // 128) * 128)
+    rpad = -(-r // tb) * tb
+    x = jnp.pad(x, ((0, 0), (0, rpad - r)))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda i: (0, 0))
+    # traced with x64 off: index maps that return i64 do not lower
+    with config.x64_scope(False):
+        out = pl.pallas_call(
+            _nn_kernel,
+            out_shape=jax.ShapeDtypeStruct((1, rpad), jnp.float32),
+            grid=(rpad // tb,),
+            in_specs=[pl.BlockSpec((4, tb), lambda i: (0, i))]
+            + [whole(a) for a in ws],
+            out_specs=pl.BlockSpec((1, tb), lambda i: (0, i)),
+            interpret=interpret,
+            name=KERNEL_NAME,
+        )(x, *ws)
+    return out[0, :r]
+
+
+def _kernel_weights():
+    """The weights as the kernel takes them: transposed, biases as
+    columns ([out, in] @ [in, TB])."""
+    w1, b1, w2, b2, w3, b3 = _NN_WEIGHTS
+    return (w1.T, b1[:, None], w2.T, b2[:, None], w3.T, b3[:, None])
+
+
+def _rows(pos, vel):
+    """[..., N, 2] positions and velocities -> [4, prod(...) * N]: the
+    four coordinates stacked whole, so no minor-axis transpose."""
+    x = jnp.stack([pos[..., 0], pos[..., 1], vel[..., 0], vel[..., 1]])
+    return x.astype(jnp.float32).reshape(4, -1)
+
+
+def _pallas_scores(interpret):
+    """The Pallas scorer, batched by hand: under ``vmap`` (the boundary
+    phase steps every lane at once) the lanes' targets become the rows of
+    one call, instead of a grid of one-lane programs.  The weights ride
+    as arguments, not as closed-over constants: a vmapped switch (the
+    block table) broadcasts every operand of its branches over the lanes,
+    and the rule takes lane 0's copy of these fixed weights back."""
+
+    @jax.custom_batching.custom_vmap
+    def scores(pos, vel, *ws):
+        return _score_rows(_rows(pos, vel), ws, interpret)
+
+    @scores.def_vmap
+    def _batched(axis_size, in_batched, pos, vel, *ws):
+        pos, vel = (
+            a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, b in zip((pos, vel), in_batched)
+        )
+        ws = [w[0] if b else w for w, b in zip(ws, in_batched[2:])]
+        out = _score_rows(_rows(pos, vel), ws, interpret)
+        return out.reshape(pos.shape[:-1]), True
+
+    return lambda pos, vel: scores(pos, vel, *_kernel_weights())
 
 
 def nn_scores(pos, vel, *, use_pallas=None, interpret=False):
     """Detection probabilities [N] for all targets — the physics hook.
 
-    ``use_pallas=True`` executes the stack as one Pallas kernel (all
-    operands in VMEM, matmuls on the MXU); ``False`` is the identical
-    plain-jnp trace (the oracle for the equivalence test).  ``None``
-    auto-selects Pallas on TPU.  The kernel is always pure f32 — detection
-    scores need no f64 regardless of the active profile.
+    ``use_pallas=True`` executes the stack as the Pallas kernel
+    ``awacs_nn_score`` (matmuls on the MXU); under ``vmap`` every lane's
+    targets are the rows of one call.  ``False`` is the plain-jnp trace
+    (the oracle for the equivalence test).  ``None`` auto-selects Pallas
+    on TPU.  The kernel is always pure f32 — detection scores need no f64
+    regardless of the active profile.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu" and not config.KERNEL_MODE
+    if use_pallas:
+        return _pallas_scores(interpret)(pos, vel)
     feats, g = _nn_features(pos, vel)
-    w1, b1, w2, b2, w3, b3 = _NN_WEIGHTS
-    if not use_pallas:
-        return _nn_forward(feats, g, w1, b1, w2, b2, w3, b3)
-    n = feats.shape[0]
-    npad = max(128, -(-n // 128) * 128)  # lane-width multiple; pad rows
-    feats = jnp.pad(feats, ((0, npad - n), (0, 0)))
-    g = jnp.pad(g, (0, npad - n))
-    # rank-2 at the kernel boundary (1D vectors ride as [1, k]).  Traced
-    # with x64 off: under vmap the batching rule adds a grid whose index
-    # maps would otherwise return i64, which Mosaic cannot lower.
-    with config.x64_scope(False):
-        out = pl.pallas_call(
-            _nn_kernel,
-            out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 8,
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            interpret=interpret,
-        )(feats, g[None], w1, b1[None], w2, b2[None], w3, b3[None])
-    return out[0, :n]
+    return _nn_forward(feats, g, *_NN_WEIGHTS)
 
 
-def build(n_targets: int, scoring: str = "nn"):
+def detections_path(sims):
+    """The pooled statistic of an AWACS experiment: detections per dwell
+    (``run_experiment_stream(..., summary_path=awacs.detections_path)``).
+    Module level, so its identity keys the fold program."""
+    return sims.user["detections"]
+
+
+def build(n_targets: int, scoring: str = "nn", dwell: float = DWELL):
     """``scoring="nn"`` (default) runs the Pallas/MLP detection scorer —
     the reference's GPU physics hook (`tut_5_3.cu`) as a TPU matmul stack;
     ``"threshold"`` keeps the closed-form linear-falloff score (the
-    tut_5_1 CPU model and the legacy behavior)."""
+    tut_5_1 CPU model and the legacy behavior).  ``dwell`` is the
+    sensor's dwell interval: the published 0.04 s, or the default
+    ``DWELL`` (25x it, the scaled pattern the examples run)."""
     if scoring not in ("nn", "threshold"):
         raise ValueError(f"scoring must be 'nn' or 'threshold': {scoring}")
+    # a lane dispatches about n_targets * dwell / LEG_MEAN leg ends
+    # between two dwells (10 at the published sizes); a boundary round
+    # lets it run 30% more, and the step that defers its dwell
+    # (PERF.md, section 4: 14 measured against waiting for every lane)
+    legs = n_targets * dwell / LEG_MEAN
     m = Model(
         "awacs",
+        boundary_round_steps=math.ceil(1.3 * legs) + 1,
         # the general event table holds only timers/user events (process
         # holds and resumes live in the dense per-pid wake table) and
         # this model schedules neither — a token capacity suffices where
@@ -258,12 +348,13 @@ def build(n_targets: int, scoring: str = "nn"):
         """One radar dwell: vectorized detection over ALL targets — the
         physics hook (CUDA kernel in the reference, jax/Pallas here).
 
-        A BOUNDARY block: on the kernel path this dispatch runs host-side
-        between Pallas chunks as plain XLA, so the [N,32] NN stack rides
-        the MXU batched over lanes instead of executing masked on every
-        kernel event (it is only needed once per dwell — ~1 in 2N
-        events).  Entered only via hold resumes and process entry, as
-        the boundary contract requires."""
+        A BOUNDARY block: its dispatch runs in the boundary phase
+        (``loop.make_boundary_apply``) — inside the XLA chunk program,
+        or between Pallas chunks on the kernel path — so the scorer runs
+        once per dwell over every lane's targets instead of executing
+        masked at every event (it is needed at about 1 event in 11 at
+        the published dwell).  Entered only via hold resumes and process
+        entry, as the boundary contract requires."""
         pos = _current_positions(sim)
         # detection scores for every target, plus one uniform draw for the
         # whole dwell (scan noise)
@@ -289,7 +380,7 @@ def build(n_targets: int, scoring: str = "nn"):
         done = sim.clock >= sim.user["t_end"]
         sim = api.stop(sim, done)
         return sim, cmd.select(
-            done, cmd.exit_(), cmd.hold(DWELL, next_pc=sensor_dwell.pc)
+            done, cmd.exit_(), cmd.hold(dwell, next_pc=sensor_dwell.pc)
         )
 
     m.process("target", entry=tgt_leg, count=n_targets)  # pids 0..N-1

@@ -73,6 +73,9 @@ class StreamResult(NamedTuple):
     n_regrows: int             # wave-granular capacity regrows performed
     metrics: Any = None        # pooled obs.metrics registry when enabled
     audit: Any = None          # run card (docs/18_audit.md) when audited
+    #: models with boundary blocks: {name: i64 total} over every chunk,
+    #: names ``core.loop.BOUNDARY_COUNTERS``; None for the others
+    boundary: Any = None
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -290,8 +293,12 @@ def _chunk_program(
     the chunk runs per-shard with the liveness flag psum-reduced over
     ICI, so the host polls one replicated scalar.
 
+    A model with boundary blocks adds the chunk's boundary counters
+    (``core.loop.BOUNDARY_COUNTERS``) as the third output, summed over
+    the shards under a mesh.
+
     ``audit=True`` (docs/18_audit.md) appends the per-wave carry-class
-    digest vector as a third output.  Under a mesh the digest is
+    digest vector as the last output.  Under a mesh the digest is
     computed per shard with GLOBAL lane offsets (``axis_index x local
     lanes``) and psum-combined — integer sums mod 2^64 are exact and
     commutative, so the combined digest equals the single-device digest
@@ -306,7 +313,12 @@ def _chunk_program(
         chunk_local = make_chunk(
             spec, t_end=t_end, pack=pack, max_steps=chunk_steps
         )
-        out_specs = (P(REP_AXIS), P()) + ((P(),) if audit else ())
+        counted = bool(spec.boundary_pcs)
+        out_specs = (
+            (P(REP_AXIS), P())
+            + ((P(),) if counted else ())
+            + ((P(),) if audit else ())
+        )
 
         @partial(
             shard_map,
@@ -316,11 +328,15 @@ def _chunk_program(
             check_vma=False,
         )
         def chunk(sims):
-            sims, live_local = chunk_local(sims)
+            local = chunk_local(sims)
+            sims, live_local = local[0], local[1]
             n_live = jax.lax.psum(
                 live_local.astype(jnp.int32), REP_AXIS
             )
             out = (sims, n_live > 0)
+            if counted:
+                # each shard ran its own rounds: the counters add up
+                out = out + (jax.lax.psum(local[2], REP_AXIS),)
             if audit:
                 from cimba_tpu.obs import audit as _obs_audit
 
@@ -738,13 +754,14 @@ def run_experiment_stream(
     if isinstance(program_cache, _pcache_r.ProgramCache):
         # respect an explicitly opted-out cache (store=False)
         store = program_cache._store
-    with span("runner.stream", spec=spec.name, R=int(n_replications)):
+    with span("runner.stream", spec=spec.name,
+              R=int(n_replications)) as sp:
         rs = _tune_reg.resolve_entry(
             spec, n_replications, schedule=schedule, pack=pack,
             chunk_steps=chunk_steps, wave_size=wave_size, store=store,
         )
         with rs.scope():
-            return _stream_impl(
+            result = _stream_impl(
                 spec, params, n_replications,
                 wave_size=rs.wave_size, seed=seed, mesh=mesh, t_end=t_end,
                 pack=rs.pack, chunk_steps=rs.chunk_steps,
@@ -754,6 +771,12 @@ def run_experiment_stream(
                 program_cache=program_cache, audit=audit,
                 sched_block=rs.block(),
             )
+        if result.boundary is not None:
+            # read back inside the span, which then ends with the device
+            sp.attrs.update(
+                {k: int(v) for k, v in result.boundary.items()}
+            )
+        return result
 
 
 def _stream_impl(
@@ -844,6 +867,15 @@ def _stream_impl(
 
     on_wave, on_chunk = _tel_hooks(telemetry, "stream", on_wave, on_chunk)
     rec = telemetry.spans if telemetry is not None else None
+    # a boundary chunk's counters, added up on the device chunk by chunk
+    bctr = None
+    on_counters = None
+    if spec.boundary_pcs:
+        bctr = [jnp.zeros((len(_cl.BOUNDARY_COUNTERS),), jnp.int64)]
+
+        def on_counters(c, vec):
+            bctr[0] = bctr[0] + vec
+
     trace = None
     if rec is not None:
         trace = rec.new_trace()
@@ -880,6 +912,7 @@ def _stream_impl(
                     sims = drive_chunks(
                         chunk_j, sims, poll_every=poll_every,
                         on_chunk=on_chunk, on_digest=on_digest,
+                        on_counters=on_counters,
                     )
                 if n_regrows >= max_regrows:
                     break
@@ -925,6 +958,10 @@ def _stream_impl(
         n_waves=n_waves,
         n_regrows=n_regrows,
         metrics=acc[3] if with_metrics else None,
+        boundary=(
+            None if bctr is None
+            else dict(zip(_cl.BOUNDARY_COUNTERS, bctr[0]))
+        ),
     )
     if use_audit:
         from cimba_tpu import config as _config

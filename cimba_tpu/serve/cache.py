@@ -273,6 +273,8 @@ def spec_fingerprint(spec) -> tuple:
         tuple(id(h) for h in spec.user_handlers),
         tuple(spec.boundary_pcs),
     )
+    if spec.boundary_round_steps is not None:
+        fp = fp + (spec.boundary_round_steps,)
     try:
         object.__setattr__(spec, "_cimba_fingerprint", fp)
     except (AttributeError, TypeError):

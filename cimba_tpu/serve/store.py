@@ -301,6 +301,8 @@ def stable_spec_fingerprint(spec) -> tuple:
         tuple(_stable_callable(h, seen) for h in spec.user_handlers),
         tuple(spec.boundary_pcs),
     )
+    if spec.boundary_round_steps is not None:
+        fp = fp + (spec.boundary_round_steps,)
     try:
         object.__setattr__(spec, "_cimba_stable_fingerprint", fp)
     except (AttributeError, TypeError):

@@ -269,16 +269,20 @@ def test_mm1_chunk_compiles(one_chip, packed):
 
 
 def test_awacs_scorer_compiles_at_1000_targets(one_chip):
-    """The AWACS detection scorer at the benchmark's 1000 targets
-    (1024 padded rows): three MXU matmuls with every operand in VMEM."""
+    """The AWACS detection scorer as the boundary phase runs it: vmapped
+    over a 4096-lane wave of 1000 targets, ONE ``awacs_nn_score`` call
+    over the 4,096,000 rows, tiled in lane-dense row blocks."""
     from cimba_tpu.models import awacs
 
-    pos = jax.ShapeDtypeStruct((1000, 2), jnp.float32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((4096, 1000, 2), jnp.float32,
+                               sharding=one_chip)
     with jax.enable_x64(False):
-        compiled = jax.jit(
+        compiled = jax.jit(jax.vmap(
             lambda p, v: awacs.nn_scores(p, v, use_pallas=True)
-        ).lower(pos, pos).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        )).lower(pos, pos).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert awacs.KERNEL_NAME in text
 
 
 def test_random_blocks_compile(one_chip):

@@ -61,8 +61,11 @@ def _chunk_leaves(spec, *, lanes=4, max_steps=64, seed=2026):
     sims = jax.vmap(
         lambda r: cl.init_sim(spec, seed, r, (2.0,))
     )(jnp.arange(lanes))
-    out, live = jax.jit(cl.make_chunk(spec, max_steps=max_steps))(sims)
-    return jax.tree.leaves(out) + [live]
+    # every output: the Sim, the liveness flag, and a boundary model's
+    # counters
+    return jax.tree.leaves(
+        jax.jit(cl.make_chunk(spec, max_steps=max_steps))(sims)
+    )
 
 
 def _chunk_jaxpr_text(spec, *, lanes=2, max_steps=32, seed=2026):
