@@ -86,7 +86,7 @@ def _time_vmapped(spec, init_one, R, warm_args, real_args, pack=None):
     ``config.profile`` the spec was built under — dtypes bind at trace
     time, which happens inside this function.  ``pack`` selects the
     while-loop carry layout (see loop.make_run; None = backend auto)."""
-    run = cl.make_run(spec, pack=pack)
+    run = cl.make_run(spec, pack=pack, batched=True)
 
     def experiment(args):
         def one(rep):
@@ -499,7 +499,7 @@ def _measure_dispatch_arms(spec_of, init_one_of, R, warm_args, real_args,
             with _cfg.profile(prof), _dispatch_arm(arm):
                 spec = spec_of()
                 init_one = init_one_of(spec)
-                run = cl.make_run(spec)
+                run = cl.make_run(spec, batched=True)
 
                 def experiment(args):
                     def one(rep):
@@ -789,7 +789,7 @@ def _mm1_stream_arm(R, N, prof, mono_rate):
         crun = cl.make_chunked_run(
             spec, chunk_steps=chunk, poll_every=4, on_chunk=_heartbeat
         )
-        mrun = jax.jit(jax.vmap(cl.make_run(spec)))
+        mrun = jax.jit(jax.vmap(cl.make_run(spec, batched=True)))
         ijit = jax.jit(
             jax.vmap(
                 lambda r, n: cl.init_sim(spec, 2026, r, mm1.params(n)),

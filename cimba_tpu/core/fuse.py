@@ -13,9 +13,10 @@ compatible-shape member specs into one **superspec** whose block table
 is the concatenation of the members' tables, each member's entry pcs
 rebased by its table offset.  The chunk program built from the
 superspec is the ordinary :func:`cimba_tpu.core.loop.make_chunk` —
-block dispatch is already a per-lane ``lax.switch`` on ``procs.pc``,
-so once a lane's pcs live in member k's slice of the merged table, the
-existing dispatch IS the per-lane model switch.  Only *initialisation*
+block dispatch is already a per-lane branch on ``procs.pc`` (the
+batched step's ``loop._vswitch``), so once a lane's pcs live in member
+k's slice of the merged table, the existing dispatch IS the per-lane
+model switch.  Only *initialisation*
 needs an explicit branch: :func:`make_fused_init` switches each lane's
 ``init_sim`` through its member's own process table / ``user_init`` on
 a per-lane ``spec_id`` column, and :func:`make_fused_refill` does the
@@ -23,7 +24,7 @@ same for mid-wave lane splices (docs/22_refill.md).
 
 Why lanes stay BITWISE equal to their solo runs (docs/26_wave_fusion.md):
 
-* dispatch is value-exact: ``lax.switch`` under ``vmap`` computes every
+* dispatch is value-exact: the batched block dispatch computes every
   branch and *selects* per lane, and selection never perturbs the
   selected values — a member lane runs exactly its own block functions
   (member 0's table entries are the original function objects; other

@@ -385,11 +385,13 @@ def _gated(fn):
     return fn
 
 
-def _vswitch(idx, branches, *args):
+def _vswitch(idx, branches, *args, batched: bool = False):
     """``lax.switch`` for the vmapped interpreter.  Under vmap a
-    lax.switch executes every traced branch anyway, but lowers to an
-    N-ary ``select_n`` which Mosaic rejects (only 2-way selects).  Two
-    strategies replace the merge:
+    ``lax.switch`` with a batched index executes every traced branch
+    anyway and then ``select_n``-merges EVERY output leaf — the whole
+    per-lane Sim, once per dispatch level (event kind, block, command
+    handler) — and Mosaic rejects the N-ary select outright (only
+    2-way selects).  Two strategies replace the merge:
 
     * branches marked with :func:`_gated` (the internal command
       handlers) are composed SEQUENTIALLY, each fully pred-gated by its
@@ -403,13 +405,22 @@ def _vswitch(idx, branches, *args):
       that actually changed each leaf (identity test).  A table is
       either all-gated or all-ungated; mixing raises.
 
-    The branch predicates are exclusive and exhaustive (every caller
-    clips or LUT-maps ``idx`` into range).  Outside kernel mode the real
-    lax.switch is kept: an *unbatched* run then executes only the
-    selected branch (side effects like debug callbacks fire once, and
-    scalar oracle runs stay cheap; gated handlers see gate=True there).
+    Aliased branches (the same callable at several indices) are traced
+    once.  The branch predicates are exclusive and exhaustive (every
+    caller clips or LUT-maps ``idx`` into range).
+
+    This merge-free form is taken wherever the trace is batched: in
+    the kernel trace (``config.KERNEL_MODE``) and when the function
+    that builds and vmaps the step says so (``batched=True``, threaded
+    from :func:`make_step`).  A batched index cannot be detected here —
+    jax traces a while body with per-lane avals before batching it — so
+    the caller has to say it.  An unbatched trace keeps the real
+    ``lax.switch``: a scalar run then executes only the selected branch
+    (side effects like debug callbacks fire once, and scalar oracle runs
+    stay cheap; gated handlers see gate=True there).  Both forms give
+    bitwise the same lanes (tests/test_batched_dispatch.py).
     """
-    if not config.KERNEL_MODE:
+    if not (batched or config.KERNEL_MODE):
         return lax.switch(idx, branches, *args)
     # dedupe identical branch callables: the dispatch table aliases the
     # same handler at several indices (K_PROC and K_TIMER both run
@@ -513,7 +524,11 @@ def _check_gated_noop(name: str, h, sim: Sim, tag: int) -> None:
     invariant :func:`_vswitch`'s zero-merge sequential composition rests
     on.  A handler with one ungated write corrupts *other lanes'* state
     only under vmap, far from the cause; this fails loudly at the
-    handler, by name and leaf path."""
+    handler, by name and leaf path.
+
+    A Sim with a leading lane axis is checked as the batched step runs
+    the handler: under ``jax.vmap``, its gate true on the even lanes and
+    false on the odd ones, which must come out bitwise untouched."""
     import numpy as np
 
     cmd = pr.Command(
@@ -524,15 +539,24 @@ def _check_gated_noop(name: str, h, sim: Sim, tag: int) -> None:
         jnp.zeros((), _I),
         jnp.zeros((), _I),
     )
-    out = h(
-        sim, jnp.zeros((), _I), cmd, jnp.asarray(False),
-        gate=jnp.zeros((), jnp.bool_),
-    )
-    sim2 = out[0] if isinstance(out, tuple) else out
+
+    def run(sim, gate):
+        out = h(sim, jnp.zeros((), _I), cmd, jnp.asarray(False), gate=gate)
+        return out[0] if isinstance(out, tuple) else out
+
+    lanes = jnp.shape(sim.clock)[:1]
+    if lanes:
+        sim2 = jax.vmap(run)(sim, jnp.arange(lanes[0]) % 2 == 0)
+
+        def off(x):
+            return np.asarray(x)[1::2]
+    else:
+        sim2 = run(sim, jnp.zeros((), jnp.bool_))
+        off = np.asarray
     flat, _ = jax.tree_util.tree_flatten_with_path(sim)
     flat2 = jax.tree.leaves(sim2)
     for (path, a), b in zip(flat, flat2):
-        a, b = np.asarray(a), np.asarray(b)
+        a, b = off(a), off(b)
         same = (
             np.array_equal(a, b, equal_nan=True)
             if np.issubdtype(a.dtype, np.inexact)
@@ -550,10 +574,15 @@ def _check_gated_noop(name: str, h, sim: Sim, tag: int) -> None:
 def validate_gated_handlers(spec: ModelSpec, sim: Sim) -> None:
     """Debug-tier structural check over the full handler table: every
     self-gated command handler must leave a concrete Sim bitwise
-    untouched under ``gate=False``.  Traced nowhere — runs eagerly on
-    one per-lane Sim, once per kernel build (pallas_run wires it behind
-    the dbc debug tier), so the invariant the fuzz battery only samples
-    is enforced structurally.
+    untouched under ``gate=False``.  Traced nowhere — runs eagerly,
+    so the invariant the fuzz battery only samples is enforced
+    structurally.  Every batched step rests on it: the kernel's and
+    the vmapped XLA step's (``make_step(batched=True)``, which every
+    chunk program runs).  Given one per-lane Sim it checks the handlers
+    as traced per lane (pallas_run wires that behind the dbc debug
+    tier, once per kernel build); given a batched Sim (two lanes or
+    more), as vmap batches them, gate false on every other lane
+    (tests/test_gated_invariant.py runs it for the benchmark's models).
 
     Checked once per DISPATCH SLOT, not per unique handler: an aliased
     handler (h_queue at put/get/put_hold/get_hold, h_buffer at both
@@ -1296,7 +1325,7 @@ def _may_pend(spec: ModelSpec, sim: Sim) -> bool:
     return used is None or bool(_PENDING_TAGS & set(used))
 
 
-def _make_apply(spec: ModelSpec, used_tags=None):
+def _make_apply(spec: ModelSpec, used_tags=None, batched: bool = False):
     q_cap = _ConstTable([q.capacity for q in spec.queues] or [1], _I)
     q_front = _ConstTable([q.front_guard for q in spec.queues] or [0], _I)
     q_rear = _ConstTable([q.rear_guard for q in spec.queues] or [0], _I)
@@ -1874,7 +1903,7 @@ def _make_apply(spec: ModelSpec, used_tags=None):
         def apply_command(sim: Sim, p, cmd: pr.Command, is_retry=False):
             return _vswitch(
                 jnp.clip(cmd.tag, 0, pr.N_COMMANDS - 1), handlers, sim, p,
-                cmd, jnp.asarray(is_retry),
+                cmd, jnp.asarray(is_retry), batched=batched,
             )
         apply_command.handler_items = list(enumerate(handlers))
         return apply_command
@@ -1893,7 +1922,7 @@ def _make_apply(spec: ModelSpec, used_tags=None):
     def apply_command(sim: Sim, p, cmd: pr.Command, is_retry=False):
         idx = lut[jnp.clip(cmd.tag, 0, pr.N_COMMANDS - 1)]
         return _vswitch(
-            idx, table, sim, p, cmd, jnp.asarray(is_retry),
+            idx, table, sim, p, cmd, jnp.asarray(is_retry), batched=batched,
         )
 
     apply_command.handler_items = list(enumerate(handlers))
@@ -1913,14 +1942,26 @@ def _defers(spec: ModelSpec, defer_boundary: Optional[bool]) -> bool:
     return defer_boundary
 
 
-def make_step(spec: ModelSpec, defer_boundary: Optional[bool] = None):
+def make_step(
+    spec: ModelSpec,
+    defer_boundary: Optional[bool] = None,
+    batched: bool = False,
+):
     """Build ``step(sim) -> sim`` dispatching exactly one event.
 
     ``defer_boundary`` (models with boundary blocks only): a resume whose
     target block is a boundary block is not dispatched; the lane raises
     ``boundary_pending`` and the block's body is a stub in this trace,
     for :func:`make_boundary_apply` to run the dispatch batched.  ``None``
-    defers exactly in the kernel trace (``config.KERNEL_MODE``)."""
+    defers exactly in the kernel trace (``config.KERNEL_MODE``).
+
+    ``batched``: the caller vmaps this step (directly or as the body of
+    a vmapped while loop).  Its three dispatch levels — event kind,
+    block, command handler — then take :func:`_vswitch`'s merge-free
+    form instead of a ``lax.switch`` that select-merges every Sim leaf
+    per level; the lanes are bitwise the same either way.  Leave it
+    False for a scalar (unbatched) run, which then executes one branch
+    per level."""
     blocks = list(spec.blocks)
 
     def _defer():
@@ -1934,7 +1975,9 @@ def make_step(spec: ModelSpec, defer_boundary: Optional[bool] = None):
 
     def apply_command(sim: Sim, p, cmd: pr.Command, is_retry=False):
         if "apply" not in _cache:
-            _cache["apply"] = _make_apply(spec, _used_tags_for(spec, sim))
+            _cache["apply"] = _make_apply(
+                spec, _used_tags_for(spec, sim), batched
+            )
         return _cache["apply"](sim, p, cmd, is_retry)
 
     def _boundary_stub(sim, p, sig):
@@ -1958,6 +2001,7 @@ def make_step(spec: ModelSpec, defer_boundary: Optional[bool] = None):
             sim,
             p,
             sig,
+            batched=batched,
         )
 
     def resume(sim: Sim, p, sig, gate=True):
@@ -2249,6 +2293,7 @@ def make_step(spec: ModelSpec, defer_boundary: Optional[bool] = None):
             event.subj,
             event.arg,
             event.found,
+            batched=batched,
         )
 
     return step
@@ -2302,6 +2347,7 @@ def make_run(
     t_end: Optional[float] = None,
     pack: Optional[bool] = None,
     max_steps: Optional[int] = None,
+    batched: bool = False,
 ):
     """Build ``run(sim) -> sim``: dispatch events until the model stops
     (api.stop), fails, runs out of events, or passes ``t_end``
@@ -2326,8 +2372,11 @@ def make_run(
     sequence the unbounded loop would, merely split across invocations
     — chunked trajectories are bitwise the monolithic ones (pinned by
     tests/test_stream.py).  ``None`` (the default) keeps today's
-    unbounded loop, jaxpr-identical to before this knob existed."""
-    step = make_step(spec)
+    unbounded loop, jaxpr-identical to before this knob existed.
+
+    ``batched=True`` when the caller vmaps ``run`` (the step's merge-free
+    dispatch, :func:`make_step`); a scalar run keeps ``lax.switch``."""
+    step = make_step(spec, batched=batched)
     cond = make_cond(spec, t_end)
     if pack is None:
         pack = config.xla_pack_enabled()
@@ -2411,7 +2460,7 @@ def make_boundary_apply(spec: ModelSpec):
     its program, the kernel driver (``core.pallas_run``) between Pallas
     chunks.  Since the step is vmapped, a boundary block's bulk work
     (AWACS's scorer) sees every lane at once."""
-    xstep = jax.vmap(make_step(spec, defer_boundary=False))
+    xstep = jax.vmap(make_step(spec, defer_boundary=False, batched=True))
 
     def apply(sims: Sim) -> Sim:
         with jax.named_scope("runner.boundary"):
@@ -2456,7 +2505,7 @@ def _make_boundary_chunk(spec: ModelSpec, t_end, max_steps: int):
     lane dispatches more.  Returns ``chunk(sims) -> (sims, any_live,
     counters)``, ``counters`` the :data:`BOUNDARY_COUNTERS` of this
     chunk (config.COUNT)."""
-    lstep = make_step(spec, defer_boundary=True)
+    lstep = make_step(spec, defer_boundary=True, batched=True)
     lcond = make_cond(spec, t_end, defer_boundary=True)
 
     def lane_run(sim: Sim, kmax):
@@ -2549,7 +2598,9 @@ def make_chunk(
 
         return audited
 
-    bounded = make_run(spec, t_end=t_end, pack=pack, max_steps=max_steps)
+    bounded = make_run(
+        spec, t_end=t_end, pack=pack, max_steps=max_steps, batched=True
+    )
     cond = make_cond(spec, t_end)
 
     if not audit:

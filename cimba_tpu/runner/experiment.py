@@ -157,7 +157,7 @@ def run_experiment(
     ``CIMBA_XLA_PACK``/backend auto default) — trajectory-identical
     either way, bench.py measures both arms through this knob.
     """
-    run = make_run(spec, t_end=t_end, pack=pack)
+    run = make_run(spec, t_end=t_end, pack=pack, batched=True)
     pb = _broadcast_params(params, n_replications)
     reps = jnp.arange(n_replications)
 
@@ -1031,7 +1031,7 @@ def make_sharded_experiment(
     """
     from cimba_tpu.obs import metrics as _metrics
 
-    run = make_run(spec, t_end=t_end, pack=pack)
+    run = make_run(spec, t_end=t_end, pack=pack, batched=True)
     reps = jnp.arange(n_replications)
     with_metrics = _metrics.enabled()
 

@@ -2,7 +2,7 @@
 the plain reference (``benchmark/refs/awacs.py``), the boundary phase of
 the XLA chunk against the per-event path, the batched scorer, the
 boundary counters, and the programs of the models without boundary
-blocks, pinned to what they were before the boundary phase existed."""
+blocks, pinned by digest."""
 
 import functools
 import hashlib
@@ -190,15 +190,18 @@ def test_dwell_is_a_build_parameter():
         assert int(sim.user["dwells"]) == dwells
 
 
-# sha256 of each program's jaxpr text before the boundary phase existed
-# (mm1 with a FIFO of 128, mg1 of 512; 8 lanes, chunk_steps 16, horizon
-# column 50; fold over the default summary path)
+# sha256 of each program's jaxpr text (mm1 with a FIFO of 128, mg1 of
+# 512; 8 lanes, chunk_steps 16, horizon column 50; fold over the default
+# summary path): init and fold as before the boundary phase existed, the
+# chunk programs as the batched step's merge-free dispatch traces them
+# (their lanes pinned bitwise the lax.switch form's by
+# tests/test_batched_dispatch.py)
 PARENT_SHA256 = {
     ('mg1', 'f32', 'chunk'): (
-        "1abc088a53135e5931c2619c12fbfb79ed89d99d89d536569cb7aaf7267a79ce"
+        "6bbc6ef46d65be06aa5ddb9ca5d3e3228bcd56cabf01858b60dbeb94d825be6d"
     ),
     ('mg1', 'f32', 'chunk_packed'): (
-        "2c20da452230b61c152773c17808a1b47c7f306d60b914ca17ff02e98cc27091"
+        "a41df60e71cbd905b3edc4f817c802f5f58c513daee9386c8f9c77f3fa8ed3cb"
     ),
     ('mg1', 'f32', 'fold'): (
         "3fb9aa6f030ef6666c746cc15cc0e69e93b7a2b2b9265406e9b533ffd6a0f68b"
@@ -207,10 +210,10 @@ PARENT_SHA256 = {
         "ab5b51ef2941702aeb459d7e2066820fabd3705dc7a7610b23b3fac718cbacac"
     ),
     ('mg1', 'f64', 'chunk'): (
-        "ac198868564dd6a4e8da7614cc14fe62cb38f5806964a02d983793b1d1cdbdc4"
+        "9aa609b02918e5e8c044427fa0c9e01e546fbacf90ac4e9f491ca42ff48719eb"
     ),
     ('mg1', 'f64', 'chunk_packed'): (
-        "c5bb07accec3b50eeeec91b629ff12f37a082c27bde56bf07b2d0571a6362c23"
+        "6dd40d101032154442411d92a6039a8fdfba39123202f0e22c8368f878a25c72"
     ),
     ('mg1', 'f64', 'fold'): (
         "f05b06ed07c5fe26b8f4c734186230eac5f293b78b92a2fe9604a6bb3ddc1157"
@@ -219,10 +222,10 @@ PARENT_SHA256 = {
         "7fbc8bcd7c4f84dd1fd3ac7e9f6989c8fcbbf92a6ffb4881b37118be3fa702e8"
     ),
     ('mm1', 'f32', 'chunk'): (
-        "069bcda1e85043aa14cf9d0fa4aabb2fd24f7d57b262ffc6f6268cba5669dc2c"
+        "f6e6ee2d67ee018a6c3aeec94f8806ab734d1ffdc74014ae5087d6fc9dc416c2"
     ),
     ('mm1', 'f32', 'chunk_packed'): (
-        "5e473a2904fff65603abd3890b1917632b91e46e75cfa0c720979e40837fcd3d"
+        "8f828a50aa21460cc22dafc57b278d0bf0408164a174f27e36cbd8e6c4055b23"
     ),
     ('mm1', 'f32', 'fold'): (
         "18571c07247feaf6aef88328af2110e19e2ee1b9612becd2fb858544b581e4fe"
@@ -231,10 +234,10 @@ PARENT_SHA256 = {
         "1effc0a98b36a1cf043c8f47f7e4b44b7d5a7c10444e121cfb9f9f4fcf74037f"
     ),
     ('mm1', 'f64', 'chunk'): (
-        "d8404c681e23e75b2bf247bb9003a51c5de335fddd9cffb6c2f0e18dd2b61fd8"
+        "0063c979d12e1db5567fa6c525612b9f5ba7861c1fcc469f891ec90b851e67a7"
     ),
     ('mm1', 'f64', 'chunk_packed'): (
-        "29b4e27be0b67be14c6a0c1e8d8fe8517914eb76a6a561b516d106c7ddea3c3e"
+        "202ab6ac442670c8106c50b1267c0d9df8a9af9ba30f21f2d4c976e8997fa961"
     ),
     ('mm1', 'f64', 'fold'): (
         "2badfe19d0059a738d9932ea5bd3bd574c7fbc4a30187c5e0c36a38a1f580ec7"

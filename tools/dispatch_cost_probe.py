@@ -58,7 +58,7 @@ def step_probe(hier, per_resume, R, cap, fill, iters):
     config.EVENTSET_HIER = hier
     try:
         spec = _timer_spec(cap, per_resume, rearm_spread=1793)
-        step = jax.vmap(cl.make_step(spec))
+        step = jax.vmap(cl.make_step(spec, batched=True))
 
         def warmed(sims, k):
             return jax.lax.fori_loop(0, k, lambda i, s: step(s), sims)
@@ -92,7 +92,7 @@ def arms_probe(R, N):
         config.XLA_PACK, config.EVENTSET_HIER = pack, hier
         try:
             spec, _ = mm1.build(record=False)
-            run = cl.make_run(spec)
+            run = cl.make_run(spec, batched=True)
 
             def experiment(n):
                 sims = jax.vmap(

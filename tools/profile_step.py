@@ -42,7 +42,7 @@ def build_model(name: str):
 
 
 def profile(spec, params, r: int, iters: int):
-    step = jax.vmap(cl.make_step(spec))
+    step = jax.vmap(cl.make_step(spec, batched=True))
 
     def init(rep):
         return cl.init_sim(spec, 2026, rep, params)

@@ -37,7 +37,7 @@ def log(**kw):
 def xla_scaling(N=500):
     log(phase="xla_scaling_start", backend=jax.default_backend(), N=N)
     spec, _ = mm1.build(record=False)
-    run = cl.make_run(spec)
+    run = cl.make_run(spec, batched=True)
 
     for R in (4096, 8192, 16384, 32768):
         def experiment(n):
@@ -93,7 +93,7 @@ def awacs_scaling(t_end=40.0):
     log(phase="awacs_scaling_start", backend=jax.default_backend(),
         t_end=t_end)
     spec, _ = awacs.build(1000)
-    run = cl.make_run(spec)
+    run = cl.make_run(spec, batched=True)
     for R in (64, 256):
         def experiment(t):
             def one(rep):

@@ -28,7 +28,7 @@ def log(**kw):
 def cell(tag, R, N, cap=128, prof="f32"):
     with config.profile(prof):
         spec, _ = mm1.build(queue_cap=cap, record=False)
-        run = cl.make_run(spec)
+        run = cl.make_run(spec, batched=True)
 
         def experiment(n):
             def one(rep):
