@@ -17,7 +17,8 @@ executes:
   of chunk-program carries, JXL002 hot-path purity (no callbacks, no
   gathers), JXL003 weak-type hygiene of the packed carry.
 * :mod:`cimba_tpu.check.gates` — the trace-time feature-gate registry:
-  every gate (trace, metrics, audit, pack, hier eventset) registers
+  every gate (trace, metrics, audit, table scan, tune and the serving
+  plane's host-side policies) registers
   once and the sweep auto-generates its off == baseline jaxpr-identity
   check under both dtype profiles, replacing N hand-written pins.
 

@@ -3,33 +3,33 @@
 Every trace-time feature gate this repo ships — the flight recorder
 (:mod:`obs.trace`), the metrics registry (:mod:`obs.metrics`), the
 determinism-audit chunk arm (:mod:`obs.audit` via ``make_chunk``), the
-packed XLA carry (``CIMBA_XLA_PACK``), and the hierarchical event set
-(``CIMBA_EVENTSET_HIER``/``_BLOCK``) — carries the same contract: its
-OFF state must trace a program jaxpr-identical to the baseline, under
-both dtype profiles, and ambient environment state must never leak into
-a traced program except through the gate's documented resolution point.
+table-scan dispatch (``CIMBA_TABLE_SCAN``/``_BLOCK``), tuned-schedule
+resolution and the serving plane's host-side policies — carries the same
+contract: its OFF state must trace a program jaxpr-identical to the
+baseline, under both dtype profiles, and ambient environment state must
+never leak into a traced program except through the gate's documented
+resolution point.
 
 Historically each gate pinned that contract with its own hand-written
-test (test_trace / test_xla_pack / test_audit), which a NEW gate could
-simply forget.  This registry inverts the burden: a gate registers once
-as a :class:`Gate` and :func:`sweep` auto-generates its identity checks;
-the completeness test in tests/test_check.py fails if a trace-gate env
-knob exists in ``config.ENV_KNOBS`` but no gate here claims it — so
+test (test_trace / test_audit), which a NEW gate could simply forget.
+This registry inverts the burden: a gate registers once as a
+:class:`Gate` and :func:`sweep` auto-generates its identity checks; the
+completeness test in tests/test_check.py fails if a trace-gate env knob
+exists in ``config.ENV_KNOBS`` but no gate here claims it — so
 forgetting is now a test failure, not a latent soundness hole.
 
 Checks per gate, per dtype profile (``f64`` and ``f32``):
 
 1. **off == baseline** — the program with the gate explicitly OFF is
-   character-identical to the default-state program (or, for gates
-   whose default resolves ON on this backend, to the explicit-ON one).
+   character-identical to the default-state program.
 2. **ambient inertness** — for gates whose env knob must NOT bind at
    trace time (``ambient_env``): the default program with the env var
    set is still the OFF program.
 3. **env off-state** — for gates whose env knob IS the resolution point
    (``off_env``): the env-disabled default reproduces the OFF program.
 4. **the knob is live** — the explicit-ON program differs (skipped for
-   structurally-inert gates like the hierarchical event set at shipped
-   model capacities).
+   structurally-inert gates like the table scan at small table
+   heights).
 
 The sweep traces jaxprs only (``jax.make_jaxpr`` — nothing compiles or
 executes), restores every global it touches, and memoizes identical
@@ -92,21 +92,6 @@ def _metrics_state(enabled: bool):
 
 
 @contextlib.contextmanager
-def _hier_state(hier: Optional[bool], block: Optional[int] = None):
-    from cimba_tpu import config
-
-    prev_h, prev_b = config.EVENTSET_HIER, config.EVENTSET_BLOCK
-    try:
-        config.EVENTSET_HIER = hier
-        if block is not None:
-            config.EVENTSET_BLOCK = block
-        yield
-    finally:
-        config.EVENTSET_HIER = prev_h
-        config.EVENTSET_BLOCK = prev_b
-
-
-@contextlib.contextmanager
 def _noop_state():
     yield
 
@@ -131,12 +116,14 @@ def _tune_state(on: bool):
     """The tune gate's arms: a resolved :class:`~cimba_tpu.tune.space.
     Schedule` binds through its ``scope()`` (the config tri-states) —
     the ON arm applies a schedule whose knob provably changes the
-    traced program (the pack arm OPPOSITE to this backend's default),
-    the OFF arm applies the empty default schedule (which must be the
-    baseline)."""
+    traced program (the table scan at a one-row block, below every
+    sweep model's tallest table), the OFF arm applies the empty default
+    schedule (which must be the baseline)."""
     from cimba_tpu.tune.space import Schedule
 
-    sched = Schedule(pack=_pack_default_is_off()) if on else Schedule()
+    sched = (
+        Schedule(table_scan=True, table_block=1) if on else Schedule()
+    )
     with sched.scope():
         yield
 
@@ -151,11 +138,8 @@ class Gate:
     ``program`` picks the traced program ("run" = ``make_run`` on an
     mm1 Sim, "chunk" = ``make_chunk`` on a vmapped wave).  The OFF/ON
     arms are forced either through builder kwargs (explicit program
-    arguments like ``pack=``/``audit=``) or a state context manager
-    (module globals like the recorder's enable flag); ``extra_arms``
-    are additional named states that must ALSO trace the off program
-    (e.g. a different hierarchical block size below the inertness
-    threshold)."""
+    arguments like ``audit=``) or a state context manager (module
+    globals like the recorder's enable flag)."""
 
     name: str
     env: Tuple[str, ...]          # ENV_KNOBS names this gate claims
@@ -167,15 +151,6 @@ class Gate:
     ambient_env: dict = dataclasses.field(default_factory=dict)
     off_env: dict = dataclasses.field(default_factory=dict)
     on_differs: bool = True
-    #: None = default always resolves OFF; else a predicate (the packed
-    #: carry defaults ON on accelerator backends)
-    default_is_off: Optional[Callable[[], bool]] = None
-
-
-def _pack_default_is_off() -> bool:
-    import jax
-
-    return jax.default_backend() == "cpu"
 
 
 GATES: Tuple[Gate, ...] = (
@@ -192,27 +167,6 @@ GATES: Tuple[Gate, ...] = (
         program="run",
         off_ctx=lambda: _metrics_state(False),
         on_ctx=lambda: _metrics_state(True),
-    ),
-    Gate(
-        name="pack",
-        env=("CIMBA_XLA_PACK",),
-        program="run",
-        off_kwargs={"pack": False},
-        on_kwargs={"pack": True},
-        off_env={"CIMBA_XLA_PACK": "0"},
-        default_is_off=_pack_default_is_off,
-    ),
-    Gate(
-        name="eventset_hier",
-        env=("CIMBA_EVENTSET_HIER", "CIMBA_EVENTSET_BLOCK"),
-        program="run",
-        off_ctx=lambda: _hier_state(False),
-        on_ctx=lambda: _hier_state(True),
-        off_env={"CIMBA_EVENTSET_HIER": "0"},
-        # structurally inert below the 2x-block capacity threshold —
-        # which every shipped model is; the ON arm must therefore trace
-        # the SAME program (that inertness is itself the pinned claim)
-        on_differs=False,
     ),
     Gate(
         name="table_scan",
@@ -336,11 +290,11 @@ def claimed_env_knobs() -> set:
 
 
 def _tiny_spec():
-    """A minimal 1-process hold/exit model: every gate's code path
-    (dispatch site, carry layout, event-set minima, chunk digest) with
-    a ~7x cheaper trace than mm1 — the tier-1 sweep model.  Its
-    ``event_cap=1`` sits below every hierarchy threshold, which is
-    exactly what the eventset gate's inertness arms require."""
+    """A minimal hold/exit model: every gate's code path (dispatch site,
+    carry layout, event-set minima, chunk digest) with a ~7x cheaper
+    trace than mm1 — the tier-1 sweep model.  Two processes, so its
+    process tables are two rows tall and the tune gate's one-row table
+    scan engages."""
     from cimba_tpu.core import api, cmd
     from cimba_tpu.core.model import Model
 
@@ -354,6 +308,7 @@ def _tiny_spec():
         )
 
     m.process("w", entry=work)
+    m.process("w2", entry=work)
     return m.build(), ()
 
 
@@ -478,22 +433,13 @@ def sweep(profiles=PROFILES, gates=None, model="mm1") -> Tuple[list, dict]:
                         gate.on_kwargs or {},
                         gate.on_ctx or _noop_state, {},
                     )
-                if gate.default_is_off is None or gate.default_is_off():
-                    ran.append("off==baseline")
-                    if off != baseline:
-                        fail(
-                            "explicit-off program differs from the "
-                            "default-state program — the gate's off "
-                            "state is not the baseline"
-                        )
-                elif on is not None:
-                    ran.append("on==baseline(default-on backend)")
-                    if on != baseline:
-                        fail(
-                            "default resolves ON on this backend but "
-                            "the explicit-on program differs from the "
-                            "default program"
-                        )
+                ran.append("off==baseline")
+                if off != baseline:
+                    fail(
+                        "explicit-off program differs from the "
+                        "default-state program — the gate's off "
+                        "state is not the baseline"
+                    )
                 if gate.ambient_env:
                     ran.append("ambient-inert")
                     ambient = build(
@@ -508,8 +454,7 @@ def sweep(profiles=PROFILES, gates=None, model="mm1") -> Tuple[list, dict]:
                         )
                 if gate.off_env:
                     # CIMBA_<GATE>=0 must reproduce the explicit-off
-                    # program on EVERY backend (pack's auto-on default
-                    # included: "=0 always reproduces per-leaf")
+                    # program on EVERY backend
                     ran.append("env-off==off")
                     env_off = build(
                         profile, gate, "default", {}, _noop_state,
@@ -535,20 +480,6 @@ def sweep(profiles=PROFILES, gates=None, model="mm1") -> Tuple[list, dict]:
                             "traced program for a model whose every "
                             "table axis fits one block (small-table "
                             "structural inertness broken)"
-                        )
-                if gate.name == "eventset_hier":
-                    # block-size inertness below the capacity threshold
-                    ran.append("block-inert")
-                    blocked = build(
-                        profile, gate, "hier:block64", {},
-                        lambda: _hier_state(True, 64), {},
-                    )
-                    if blocked != off:
-                        fail(
-                            "EVENTSET_BLOCK=64 changed the traced "
-                            "program for a model below the hierarchy "
-                            "capacity threshold (structural inertness "
-                            "broken)"
                         )
                 if on is not None:
                     if gate.on_differs:

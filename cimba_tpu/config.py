@@ -145,18 +145,6 @@ _ACTIVE_PROFILE = "f64"
 
 ENV_KNOBS = {
     # trace-time program gates (registry-swept in check/gates.py)
-    "CIMBA_EVENTSET_HIER": dict(
-        default="1", trace_gate=True,
-        doc="hierarchical event-set minima (core/eventset.py)",
-    ),
-    "CIMBA_EVENTSET_BLOCK": dict(
-        default="128", trace_gate=True,
-        doc="event-set block size for the hierarchical minima",
-    ),
-    "CIMBA_XLA_PACK": dict(
-        default="", trace_gate=True,
-        doc="packed XLA while-loop carry (core/carry.py)",
-    ),
     "CIMBA_AUDIT": dict(
         default="", trace_gate=True,
         doc="determinism audit collection (obs/audit.py; the chunk "
@@ -350,31 +338,12 @@ def env_raw(name: str, default=None) -> str:
 #: batched (vector) loop condition.
 KERNEL_MODE = False
 
-# --- dispatch-cost levers (docs/11_dispatch_cost.md) -------------------------
+# --- table dispatch levers (docs/25_compile_wall.md) ------------------------
 #
 # Both tri-state: ``None`` defers to the environment variable (and its
-# default), ``True``/``False`` override it programmatically — bench.py
-# flips these to measure the packed+hierarchical and flat arms in one
-# process.  Both bind at TRACE time (like the dtype profile): arrays and
-# jaxprs already built keep their layout.
-
-#: Hierarchical (two-level tournament) event-set minima.  ``None`` ->
-#: ``CIMBA_EVENTSET_HIER`` (default on — structurally inert unless the
-#: event capacity is a >= 2x multiple of the block size, which no
-#: shipped model's is); ``False`` is the flat-scan oracle.
-EVENTSET_HIER = None
-
-#: Event-set block size for the hierarchical minima.  ``None`` ->
-#: ``CIMBA_EVENTSET_BLOCK`` (default 128, a lane-friendly multiple).
-EVENTSET_BLOCK = None
-
-#: Packed XLA while-loop carry (core/carry.py).  ``None`` ->
-#: ``CIMBA_XLA_PACK``; unset environment auto-selects: packed on
-#: accelerator backends (where the per-leaf carry cost is measured),
-#: per-leaf on CPU (today's jaxpr).  ``CIMBA_XLA_PACK=0`` / ``False``
-#: always reproduces the current per-leaf jaxpr bitwise.
-XLA_PACK = None
-
+# default), ``True``/``False`` override it programmatically.  Both bind
+# at TRACE time (like the dtype profile): arrays and jaxprs already
+# built keep their layout.
 
 #: Scan-over-rows table dispatch (core/dyn.py).  ``None`` ->
 #: ``CIMBA_TABLE_SCAN`` (default off — dense one-hot dispatch, today's
@@ -398,30 +367,6 @@ def table_scan_block() -> int:
     if TABLE_SCAN_BLOCK is not None:
         return int(TABLE_SCAN_BLOCK)
     return int(env_raw("CIMBA_TABLE_SCAN_BLOCK"))
-
-
-def eventset_hier_enabled() -> bool:
-    if EVENTSET_HIER is not None:
-        return bool(EVENTSET_HIER)
-    return env_raw("CIMBA_EVENTSET_HIER") != "0"
-
-
-def eventset_block() -> int:
-    if EVENTSET_BLOCK is not None:
-        return int(EVENTSET_BLOCK)
-    return int(env_raw("CIMBA_EVENTSET_BLOCK"))
-
-
-def xla_pack_enabled() -> bool:
-    if XLA_PACK is not None:
-        return bool(XLA_PACK)
-    raw = env_raw("CIMBA_XLA_PACK").strip()
-    if raw:
-        return raw != "0"
-    # auto: the wide-carry cost this packs away is the accelerator
-    # while-loop's (BENCH_NOTES round 5 floor probes); CPU keeps the
-    # per-leaf carry it has always run
-    return jax.default_backend() != "cpu"
 
 
 def active_profile() -> str:

@@ -11,9 +11,9 @@ One plan serves both hot paths:
 
 * the Pallas chunk kernel (``core/pallas_run.py``, lane-LAST leaves
   ``[comp..., L]`` -> ``[rows, L]`` buffers — ``CIMBA_KERNEL_PACK``);
-* the XLA while-loop path (``core/loop.make_run``, per-replication
-  leaves ``[comp...]`` -> ``[rows]`` buffers, vmapped after —
-  ``CIMBA_XLA_PACK``; see docs/11_dispatch_cost.md).
+* the XLA while-loop path on accelerator backends
+  (``core/loop.make_run``, per-replication leaves ``[comp...]`` ->
+  ``[rows]`` buffers, vmapped after; see docs/11_dispatch_cost.md).
 
 Same-width leaves share one buffer per dtype class: f32; i32 with u32
 rows riding along via same-width bitcast (bitcast is bitwise, selects

@@ -27,7 +27,7 @@ All functions are scalar-style (one replication); the framework vmaps.
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple
+from typing import NamedTuple
 
 import jax.numpy as jnp
 from jax import lax
@@ -35,7 +35,7 @@ from jax import lax
 from cimba_tpu import config
 from cimba_tpu.core import dyn
 from cimba_tpu.config import INDEX_DTYPE
-from cimba_tpu.config import argmax32 as _argmax32, argmin32 as _argmin32
+from cimba_tpu.config import argmax32 as _argmax32
 
 _T = config.TIME
 _I = INDEX_DTYPE
@@ -47,31 +47,6 @@ NULL_HANDLE = jnp.int32(-1)
 
 _GEN_SHIFT = 16
 _SLOT_MASK = (1 << _GEN_SHIFT) - 1
-
-
-class BlockMin(NamedTuple):
-    """Per-block packed minima: the two-level tournament's upper level.
-
-    Each of the CAP/B blocks summarizes its B slots' lexicographic
-    (time asc, prio DESC, seq asc) winner — the full popped payload, so
-    ``peek_merged`` reduces over NB = CAP/B rows instead of CAP slots
-    (the "schedule the reduction, don't re-scan" move; see
-    docs/11_dispatch_cost.md).  Maintained incrementally: every
-    single-slot mutation refreshes exactly the covering block
-    (recompute-from-table, so a masked-off write is automatically a
-    summary no-op); mass cancels rebuild all rows in one reshape pass.
-    An empty block carries the same fold identities ``_lexmin`` uses
-    (+inf / int32 min / int32 max), so the upper-level lexmin needs no
-    special casing."""
-
-    time: jnp.ndarray  # [NB] _T, block winner's time (+inf = empty)
-    prio: jnp.ndarray  # [NB] i32, winner's priority (int32 min = empty)
-    seq: jnp.ndarray   # [NB] i32, winner's seq (int32 max = empty)
-    slot: jnp.ndarray  # [NB] i32, winner's ABSOLUTE slot index
-    kind: jnp.ndarray  # [NB] i32, winner's dispatch kind
-    subj: jnp.ndarray  # [NB] i32, winner's subject
-    arg: jnp.ndarray   # [NB] i32, winner's payload
-    gen: jnp.ndarray   # [NB] i32, winner's slot generation
 
 
 class EventSet(NamedTuple):
@@ -86,10 +61,6 @@ class EventSet(NamedTuple):
     gen: jnp.ndarray    # [CAP] i32, slot generation (ABA-safe handles)
     next_seq: jnp.ndarray  # i32, next sequence number
     overflow: jnp.ndarray  # bool, a schedule was dropped
-    #: hierarchical block minima (BlockMin) or None — None prunes the
-    #: leaves from the pytree, so the flat-scan oracle's EventSet is
-    #: structurally identical to the historical one
-    blk: Any = None
 
 
 class Event(NamedTuple):
@@ -104,39 +75,9 @@ class Event(NamedTuple):
     handle: jnp.ndarray  # the event's (pre-pop) handle; NULL_HANDLE if none
 
 
-def hier_block(capacity: int):
-    """Block size for the hierarchical minima at this capacity, or None
-    for the flat layout.  Hierarchy pays only when there are at least two
-    blocks to tournament over; capacities that don't tile evenly (or the
-    flat-oracle flag) keep the flat scan."""
-    if not config.eventset_hier_enabled():
-        return None
-    b = config.eventset_block()
-    if b < 2 or capacity % b or capacity // b < 2:
-        return None
-    return b
-
-
 def create(capacity: int) -> EventSet:
     if capacity > _SLOT_MASK + 1:
         raise ValueError(f"event capacity {capacity} exceeds {_SLOT_MASK + 1}")
-    b = hier_block(capacity)
-    blk = None
-    if b is not None:
-        nb = capacity // b
-        # empty-table summary == what _refresh_* computes on an empty
-        # block: the _lexmin fold identities, winner slot defaulting to
-        # the block base (argmax over an all-false mask picks index 0)
-        blk = BlockMin(
-            time=jnp.full((nb,), NEVER, _T),
-            prio=jnp.full((nb,), jnp.iinfo(jnp.int32).min, _I),
-            seq=jnp.full((nb,), jnp.iinfo(jnp.int32).max, _I),
-            slot=jnp.arange(nb, dtype=_I) * b,
-            kind=jnp.zeros((nb,), _I),
-            subj=jnp.zeros((nb,), _I),
-            arg=jnp.zeros((nb,), _I),
-            gen=jnp.zeros((nb,), _I),
-        )
     return EventSet(
         time=jnp.full((capacity,), NEVER, _T),
         prio=jnp.zeros((capacity,), _I),
@@ -147,7 +88,6 @@ def create(capacity: int) -> EventSet:
         gen=jnp.zeros((capacity,), _I),
         next_seq=jnp.zeros((), _I),
         overflow=jnp.asarray(False),
-        blk=blk,
     )
 
 
@@ -188,7 +128,6 @@ def schedule(es: EventSet, t, prio, kind, subj, arg):
         next_seq=es.next_seq + jnp.where(ok, 1, 0).astype(_I),
         overflow=es.overflow | ~ok,
     )
-    es2 = _touch(es2, slot)
     handle = jnp.where(
         ok, _handle(slot, dyn._reduce_pick(m, es.gen)), NULL_HANDLE
     )
@@ -254,11 +193,10 @@ def cancel(es: EventSet, handle):
     """Remove by handle; returns (es, existed).  O(1) scatter — the
     capability the reference needed the whole hash map for."""
     m, ok = _handle_mask(es, handle)
-    es2 = es._replace(
+    return es._replace(
         time=jnp.where(m, _T(NEVER), es.time),
         gen=es.gen + m.astype(_I),
-    )
-    return _touch(es2, _slot_of(jnp.maximum(handle, 0))), ok
+    ), ok
 
 
 def reschedule(es: EventSet, handle, new_t):
@@ -267,17 +205,15 @@ def reschedule(es: EventSet, handle, new_t):
     new_t = jnp.asarray(new_t, _T)
     m, ok = _handle_mask(es, handle)
     fin = jnp.isfinite(new_t)
-    es2 = es._replace(time=jnp.where(m & fin, new_t, es.time))
-    return _touch(es2, _slot_of(jnp.maximum(handle, 0))), ok & fin
+    return es._replace(time=jnp.where(m & fin, new_t, es.time)), ok & fin
 
 
 def reprioritize(es: EventSet, handle, new_prio):
     """Parity: ``cmb_event_reprioritize``.  Returns (es, existed)."""
     m, ok = _handle_mask(es, handle)
-    es2 = es._replace(
+    return es._replace(
         prio=jnp.where(m, jnp.asarray(new_prio, _I), es.prio)
-    )
-    return _touch(es2, _slot_of(jnp.maximum(handle, 0))), ok
+    ), ok
 
 
 def _lexmin(time, prio, seq):
@@ -298,155 +234,6 @@ def _lexmin(time, prio, seq):
     return m3, found, t_min, p_max, s_min
 
 
-# --- hierarchical block minima (the two-level tournament) -----------------
-#
-# Upper level: BlockMin, one lexmin winner per B-slot block.  The global
-# winner is the lexmin over block winners (the tournament property of a
-# total order), and live slots carry globally unique seq values, so the
-# two-level pick is BITWISE the flat scan's pick — pinned by
-# tests/test_eventset_hier.py across both dtype profiles and under vmap.
-# XLA-path only: the per-block refresh lowers to gathers under vmap,
-# which Mosaic has no rule for, so kernel-mode tracing over a
-# hierarchical EventSet raises loudly at build time (the obs/trace
-# precedent) instead of miscompiling.
-
-
-def _no_kernel():
-    if config.KERNEL_MODE:
-        raise ValueError(
-            "hierarchical event-set minima are XLA-path only (the block "
-            "refresh lowers to gathers Mosaic has no rule for) — build "
-            "kernel-path Sims under config.EVENTSET_HIER=False / "
-            "CIMBA_EVENTSET_HIER=0"
-        )
-
-
-def _blk_geometry(es: EventSet):
-    nb = es.blk.time.shape[0]
-    return nb, es.time.shape[0] // nb
-
-
-def _lexmin_rows(time, prio, seq):
-    """Row-wise :func:`_lexmin` over ``[NB, B]`` block views: returns
-    per-row (mask, found, t_min, p_max, s_min), same fold identities."""
-    t_min = jnp.min(time, axis=1)
-    found = jnp.isfinite(t_min)
-    m1 = (time == t_min[:, None]) & found[:, None]
-    p_max = jnp.max(
-        jnp.where(m1, prio, jnp.iinfo(jnp.int32).min), axis=1
-    )
-    m2 = m1 & (prio == p_max[:, None])
-    s_min = jnp.min(
-        jnp.where(m2, seq, jnp.iinfo(jnp.int32).max), axis=1
-    )
-    m3 = m2 & (seq == s_min[:, None])
-    return m3, found, t_min, p_max, s_min
-
-
-def _refresh_all(es: EventSet) -> BlockMin:
-    """Rebuild every block summary from the table in one reshape pass —
-    the mass-mutation (pattern_cancel) and regrow-rebuild path."""
-    _no_kernel()
-    nb, b = _blk_geometry(es)
-
-    def rs(a):
-        return lax.reshape(a, (nb, b))
-
-    m3, found, t_min, p_max, s_min = _lexmin_rows(
-        rs(es.time), rs(es.prio), rs(es.seq)
-    )
-    j = _argmax32(m3, axis=1).astype(_I)
-
-    def pick(a):
-        return jnp.sum(
-            jnp.where(m3, rs(a), jnp.zeros((), a.dtype)),
-            axis=1, dtype=a.dtype,
-        )
-
-    return BlockMin(
-        time=t_min,
-        prio=p_max,
-        seq=s_min,
-        slot=jnp.arange(nb, dtype=_I) * b + j,
-        kind=pick(es.kind),
-        subj=pick(es.subj),
-        arg=pick(es.arg),
-        gen=pick(es.gen),
-    )
-
-
-def _refresh_slot(es: EventSet, slot) -> BlockMin:
-    """Recompute the one block summary covering ``slot`` (O(B) slice +
-    O(NB) row write).  Out-of-range slots (a full-table schedule, a
-    garbage handle) write no row: the dynamic_slice clamps and the dset
-    matches nothing — and since the table write was masked off in those
-    same cases, no-write is exactly right."""
-    _no_kernel()
-    nb, b = _blk_geometry(es)
-    blkid = jnp.asarray(slot, _I) // b
-    start = blkid * b
-
-    def seg(a):
-        return lax.dynamic_slice(a, (start,), (b,))
-
-    m3, found, t_min, p_max, s_min = _lexmin(
-        seg(es.time), seg(es.prio), seg(es.seq)
-    )
-    new = BlockMin(
-        time=t_min,
-        prio=p_max,
-        seq=s_min,
-        slot=start + _argmax32(m3).astype(_I),
-        kind=dyn._reduce_pick(m3, seg(es.kind)),
-        subj=dyn._reduce_pick(m3, seg(es.subj)),
-        arg=dyn._reduce_pick(m3, seg(es.arg)),
-        gen=dyn._reduce_pick(m3, seg(es.gen)),
-    )
-    return BlockMin(
-        *(dyn.dset(a, blkid, v) for a, v in zip(es.blk, new))
-    )
-
-
-def _touch(es: EventSet, slot) -> EventSet:
-    """Refresh the block summary covering ``slot`` after a single-slot
-    table write.  Recompute-from-table: safe even when the write was
-    pred-gated off (the recomputed row equals the old one)."""
-    if es.blk is None:
-        return es
-    return es._replace(blk=_refresh_slot(es, slot))
-
-
-def _touch_all(es: EventSet) -> EventSet:
-    if es.blk is None:
-        return es
-    return es._replace(blk=_refresh_all(es))
-
-
-def _hier_next(es: EventSet):
-    """Two-level pick: (found, slot, time, prio, kind, subj, arg, gen,
-    take_mask[CAP]) from the NB block winners — bitwise the flat scan's
-    answer (tournament over a total order; unique seqs kill ties)."""
-    _no_kernel()
-    m_b, found, t_min, p_max, _ = _lexmin(
-        es.blk.time, es.blk.prio, es.blk.seq
-    )
-    slot = dyn._reduce_pick(m_b, es.blk.slot)
-    take = (
-        lax.broadcasted_iota(jnp.int32, es.time.shape, 0) == slot
-    ) & found
-    return (
-        found,
-        slot,
-        dyn._reduce_pick(m_b, es.blk.time),
-        dyn._reduce_pick(m_b, es.blk.prio),
-        dyn._reduce_pick(m_b, es.blk.kind),
-        dyn._reduce_pick(m_b, es.blk.subj),
-        dyn._reduce_pick(m_b, es.blk.arg),
-        dyn._reduce_pick(m_b, es.blk.gen),
-        take,
-    )
-
-
 def _argnext(es: EventSet):
     """Index of the next event: min time, then max prio, then min seq —
     three masked reductions, no data-dependent control flow."""
@@ -457,11 +244,7 @@ def _argnext(es: EventSet):
 
 def _next_parts(es: EventSet):
     """(found, slot, time, prio, kind, subj, arg, gen, take[CAP]) of the
-    next event — the flat scan or the two-level tournament, bitwise
-    interchangeable.  Not-found fields are the all-false-mask picks
-    (zeros), matching the flat reductions exactly."""
-    if es.blk is not None:
-        return _hier_next(es)
+    next event.  Not-found fields are the all-false-mask picks (zeros)."""
     slot, m, found = _argnext(es)
     return (
         found,
@@ -507,24 +290,18 @@ def pop(es: EventSet):
     )
     # m already folds `found` (all-false on an empty set), so the consume
     # writes need no extra gating
-    es2 = es._replace(
+    return es._replace(
         time=jnp.where(m, _T(NEVER), es.time),
         gen=es.gen + m.astype(_I),
-    )
-    return _touch(es2, slot), ev
+    ), ev
 
 
 def is_empty(es: EventSet):
-    if es.blk is not None:
-        return ~jnp.any(jnp.isfinite(es.blk.time))
     return ~jnp.any(jnp.isfinite(es.time))
 
 
 def min_time(es: EventSet):
-    """Soonest live time (+inf when empty) — O(NB) under the hierarchy
-    (the t_end horizon check in loop.make_cond runs this every step)."""
-    if es.blk is not None:
-        return jnp.min(es.blk.time)
+    """Soonest live time (+inf when empty)."""
     return jnp.min(es.time)
 
 
@@ -556,12 +333,10 @@ def pattern_cancel(es: EventSet, kind=WILDCARD, subj=WILDCARD, pred=True):
     gates the cancellation (n_cancelled still reports the match count)."""
     m = _match(es, kind, subj)
     mw = m if pred is True else (m & pred)
-    es2 = es._replace(
+    return es._replace(
         time=jnp.where(mw, NEVER, es.time),
         gen=es.gen + mw.astype(_I),
-    )
-    # mass mutation can touch any block: rebuild all rows in one pass
-    return _touch_all(es2), jnp.sum(m.astype(_I))
+    ), jnp.sum(m.astype(_I))
 
 
 def pattern_find(es: EventSet, kind=WILDCARD, subj=WILDCARD):
@@ -653,34 +428,12 @@ def peek_merged(es: EventSet, wk: Wakes, prio, wake_kind):
     ``handle=NULL_HANDLE`` — wake events are unaddressable, so the
     wait_event machinery (which only ever holds general-table handles)
     never matches them."""
-    if es.blk is not None:
-        # two-level tournament: the general arm reduces over the NB
-        # block winners (docs/11_dispatch_cost.md) — same values, fewer
-        # elements.  t_e/p_e/s_e keep the _lexmin fold identities for
-        # the empty case (the wake_first compare below relies on them) —
-        # which is why this branch is NOT _next_parts: that helper's
-        # empty-case fields are the all-false-mask picks (zeros), the
-        # contract peek/pop share with the historical flat reductions.
-        # The ordering itself has one home either way: _lexmin.
-        m_b, found_e, t_e, p_e, s_e = _lexmin(
-            es.blk.time, es.blk.prio, es.blk.seq
-        )
-        slot_e = dyn._reduce_pick(m_b, es.blk.slot)
-        kind_e = dyn._reduce_pick(m_b, es.blk.kind)
-        subj_e = dyn._reduce_pick(m_b, es.blk.subj)
-        arg_e = dyn._reduce_pick(m_b, es.blk.arg)
-        gen_e = dyn._reduce_pick(m_b, es.blk.gen)
-        take_e = (
-            lax.broadcasted_iota(jnp.int32, es.time.shape, 0) == slot_e
-        ) & found_e
-    else:
-        m_e, found_e, t_e, p_e, s_e = _lexmin(es.time, es.prio, es.seq)
-        slot_e = _argmax32(m_e).astype(_I)
-        kind_e = dyn._reduce_pick(m_e, es.kind)
-        subj_e = dyn._reduce_pick(m_e, es.subj)
-        arg_e = dyn._reduce_pick(m_e, es.arg)
-        gen_e = dyn._reduce_pick(m_e, es.gen)
-        take_e = m_e
+    m_e, found_e, t_e, p_e, s_e = _lexmin(es.time, es.prio, es.seq)
+    slot_e = _argmax32(m_e).astype(_I)
+    kind_e = dyn._reduce_pick(m_e, es.kind)
+    subj_e = dyn._reduce_pick(m_e, es.subj)
+    arg_e = dyn._reduce_pick(m_e, es.arg)
+    gen_e = dyn._reduce_pick(m_e, es.gen)
     m_w, found_w, t_w, p_w, s_w = _lexmin(wk.time, prio, wk.seq)
 
     wake_first = found_w & (
@@ -708,7 +461,7 @@ def peek_merged(es: EventSet, wk: Wakes, prio, wake_kind):
             NULL_HANDLE,
         ).astype(_I),
     )
-    return event, take_e & ~wake_first, m_w & wake_first
+    return event, m_e & ~wake_first, m_w & wake_first
 
 
 def consume_merged(es: EventSet, wk: Wakes, take_e, take_w, pred=True):
@@ -722,10 +475,6 @@ def consume_merged(es: EventSet, wk: Wakes, take_e, take_w, pred=True):
         time=jnp.where(take_e, _T(NEVER), es.time),
         gen=es.gen + take_e.astype(_I),
     )
-    if es.blk is not None:
-        # single-slot consume: refresh only the covering block (an
-        # all-false take yields an out-of-range slot -> refresh no-op)
-        es2 = _touch(es2, dyn.first_true32(take_e))
     wk2 = wk._replace(time=jnp.where(take_w, _T(NEVER), wk.time))
     return es2, wk2
 

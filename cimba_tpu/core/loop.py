@@ -2342,10 +2342,17 @@ def make_cond(spec: ModelSpec, t_end: Optional[float] = None,
     return cond
 
 
+def _packed_carry() -> bool:
+    """The while loop's carry layout: packed on accelerator backends,
+    where the per-leaf carry's cost was measured (BENCH_NOTES round 5
+    floor probes), per leaf on the CPU.  Both layouts run the same
+    step and cond, so trajectories are bitwise identical either way."""
+    return jax.default_backend() != "cpu"
+
+
 def make_run(
     spec: ModelSpec,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
     max_steps: Optional[int] = None,
     batched: bool = False,
 ):
@@ -2354,14 +2361,13 @@ def make_run(
     (parity: cmb_event_queue_execute; t_end plays the role of the
     user-scheduled end event).
 
-    ``pack`` selects the while-loop carry layout (None defers to
-    ``config.xla_pack_enabled()`` — ``CIMBA_XLA_PACK``, auto-on for
-    accelerator backends): packed runs the SAME step/cond on a carry of
-    a few wide per-dtype buffers instead of the Sim's ~50 narrow leaves
-    (core/carry.py, the same packing the Pallas chunk loop uses under
-    ``CIMBA_KERNEL_PACK``).  Pack/unpack are bitwise-lossless structural
-    ops, so trajectories are identical; ``pack=False`` reproduces
-    today's per-leaf jaxpr exactly.  See docs/11_dispatch_cost.md.
+    The while-loop carry layout follows the backend
+    (:func:`_packed_carry`): on an accelerator the loop runs the SAME
+    step/cond on a carry of a few wide per-dtype buffers instead of the
+    Sim's ~50 narrow leaves (core/carry.py, the same packing the Pallas
+    chunk loop uses under ``CIMBA_KERNEL_PACK``).  Pack/unpack are
+    bitwise-lossless structural ops, so trajectories are identical.
+    See docs/11_dispatch_cost.md.
 
     ``max_steps`` bounds one invocation to at most that many dispatches
     (the bounded-chunk variant, docs/12_streaming.md): the loop carries
@@ -2378,11 +2384,9 @@ def make_run(
     dispatch, :func:`make_step`); a scalar run keeps ``lax.switch``."""
     step = make_step(spec, batched=batched)
     cond = make_cond(spec, t_end)
-    if pack is None:
-        pack = config.xla_pack_enabled()
     if max_steps is not None and max_steps <= 0:
         raise ValueError(f"max_steps must be positive, got {max_steps}")
-    if not pack:
+    if not _packed_carry():
         if max_steps is None:
             def run(sim: Sim) -> Sim:
                 return lax.while_loop(cond, step, sim)
@@ -2561,7 +2565,6 @@ def _make_boundary_chunk(spec: ModelSpec, t_end, max_steps: int):
 def make_chunk(
     spec: ModelSpec,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
     max_steps: int = 1024,
     audit: bool = False,
 ):
@@ -2582,8 +2585,8 @@ def make_chunk(
     knob existed (pinned in tests/test_audit.py).
 
     A model with boundary blocks gets :func:`_make_boundary_chunk`'s
-    program, which ignores ``pack`` (its loops carry the Sim per leaf)
-    and whose outputs carry its counters third, before an audit
+    program, whose loops carry the Sim per leaf on every backend, and
+    whose outputs carry its counters third, before an audit
     digest."""
     if spec.boundary_pcs:
         bchunk = _make_boundary_chunk(spec, t_end, max_steps)
@@ -2599,7 +2602,7 @@ def make_chunk(
         return audited
 
     bounded = make_run(
-        spec, t_end=t_end, pack=pack, max_steps=max_steps, batched=True
+        spec, t_end=t_end, max_steps=max_steps, batched=True
     )
     cond = make_cond(spec, t_end)
 
@@ -2640,8 +2643,8 @@ def make_refill(spec: ModelSpec):
     lane-local under vmap; chunk phase is trajectory-invariant).  Works
     on either carry layout: the batched Sim BETWEEN chunks is always
     the plain per-leaf pytree (packing lives inside the while-loop
-    carry), so one refill program serves ``pack=True`` and
-    ``pack=False`` chunk programs alike, under both dtype profiles.
+    carry), so one refill program serves packed and per-leaf chunk
+    programs alike, under both dtype profiles.
 
     The wave must carry the per-lane ``t_stop`` leaf (refill waves
     always do — lane death and reclamation are horizon-driven); a
@@ -2712,8 +2715,8 @@ def drive_chunks(
     while loop exits at iteration 0, and donation aliases the buffers
     straight through), so late polling never perturbs the result.
 
-    ``on_chunk(n)`` fires after each dispatch — bench.py refreshes its
-    watchdog heartbeat here.  ``on_state(sims, n)`` fires every
+    ``on_chunk(n)`` fires after each dispatch (a caller's progress or
+    watchdog heartbeat).  ``on_state(sims, n)`` fires every
     ``on_state_every`` chunks with the CURRENT batched Sim, *before* it
     is donated into the next chunk — the checkpoint hook (chunk
     boundaries are the natural checkpoints; ``runner.checkpoint``
@@ -2780,7 +2783,6 @@ def drive_chunks(
 def make_chunked_run(
     spec: ModelSpec,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
     chunk_steps: int = 1024,
     poll_every: int = 4,
     donate: bool = True,
@@ -2805,7 +2807,7 @@ def make_chunked_run(
     donation) and compiles ONCE per batch shape — warm re-runs reuse it.
     """
     chunk = jax.jit(
-        make_chunk(spec, t_end=t_end, pack=pack, max_steps=chunk_steps),
+        make_chunk(spec, t_end=t_end, max_steps=chunk_steps),
         donate_argnums=(0,) if donate else (),
     )
 

@@ -494,7 +494,7 @@ def diff_trails(a_rows: List[dict], b_rows: List[dict]) -> Optional[dict]:
 #: there is geometry drift, not a determinism regression)
 _GEOMETRY_KEYS = (
     "R", "wave_size", "chunk_steps", "poll_every", "t_end", "profile",
-    "pack", "mesh", "with_metrics",
+    "mesh", "with_metrics",
 )
 
 #: geometry keys that are SCHEDULE knobs with bitwise-invariant results
@@ -504,7 +504,7 @@ _GEOMETRY_KEYS = (
 #: skipped, but the result digests must still be equal.  ``wave_size``
 #: stays a hard geometry key: the pooled summary's merge order follows
 #: the wave partition, so cross-wave-size results legitimately differ.
-_SCHEDULE_GEOMETRY_KEYS = ("chunk_steps", "pack")
+_SCHEDULE_GEOMETRY_KEYS = ("chunk_steps",)
 
 
 # cimba-check: content-path

@@ -2,13 +2,12 @@
 
 On TPU the wall time of an experiment is dominated by two very different
 costs — tracing+XLA compilation (host, once per (spec, shape)) and device
-execution (the thing bench.py measures) — and conflating them is the
+execution (the thing a benchmark measures) — and conflating them is the
 single most common profiling mistake with jit code.  :func:`profiled_call`
 splits them with the AOT API (``lower``/``compile``), and
 :class:`RunReport` packages the split with device memory stats and a
 metrics snapshot: the run's whole observability story in one JSON-able
-object, surfaced by ``run_experiment(..., with_report=True)`` and the
-bench battery's metrics section.
+object, surfaced by ``run_experiment(..., with_report=True)``.
 """
 
 from __future__ import annotations

@@ -18,8 +18,7 @@ never executes — and reports
 * ``trace_s`` / ``lower_s`` — wall seconds for the two stages.
 
 Surfaces: ``tools/program_size.py`` (CLI), ``tune/measure.py`` arm
-reports, the serve/store manifest (next to ``footprint_bytes``), and
-``bench.py --config compile_wall``.
+reports, and the serve/store manifest (next to ``footprint_bytes``).
 """
 
 from __future__ import annotations

@@ -19,7 +19,7 @@ how often do deadlines miss, is the dispatcher thread alive.  Three parts:
 * :class:`Telemetry` — the plane itself: a background **sampler** thread
   that scrapes ``Service.stats()`` / ``ProgramCache.stats()`` (store
   counters included) into the registry on an interval, heartbeats for
-  liveness (the watchdog primitive ``bench.py`` rides), and the
+  liveness (the watchdog primitive an operator's harness rides), and the
   ``healthz()``/``varz()`` snapshots the exposition server serves.
 
 Beside the plane, and with no switch: the process's **program spans**
@@ -360,7 +360,7 @@ class Registry:
 
     def get_sample(self, name: str, **labels) -> Optional[float]:
         """The current value of one series (None when absent) —
-        convenience for tests and the bench snapshot."""
+        convenience for tests and report snapshots."""
         with self._lock:
             fam = self._families.get(name)
             if fam is None:
@@ -1175,9 +1175,8 @@ class Telemetry:
         return out
 
     def snapshot(self) -> dict:
-        """A compact dict for embedding in reports (the bench JSON's
-        per-battery telemetry section): tick counters, heartbeat ages,
-        span counters — no history rings."""
+        """A compact dict for embedding in reports: tick counters,
+        heartbeat ages, span counters — no history rings."""
         now = time.monotonic()
         with self._lock:
             hb = {k: round(now - t, 3) for k, t in self._hb.items()}

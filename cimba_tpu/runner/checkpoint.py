@@ -121,8 +121,8 @@ def run_tag(
     under a different seed, ``t_end``, or swept parameters would
     silently continue/hybridize the OLD run — the shapes all match — so
     the runner fingerprints every trajectory-changing knob and a
-    mismatched resume fails loudly instead (chunk_steps/pack are
-    trajectory-neutral and stay out of the tag)."""
+    mismatched resume fails loudly instead (chunk_steps and the carry
+    layout are trajectory-neutral and stay out of the tag)."""
     import hashlib
 
     base = json.loads(spec_tag(spec))

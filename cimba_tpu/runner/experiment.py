@@ -136,7 +136,6 @@ def run_experiment(
     t_end: Optional[float] = None,
     with_report: bool = False,
     profile_dir: Optional[str] = None,
-    pack: Optional[bool] = None,
 ):
     """Run ``n_replications`` independent replications of ``spec``.
 
@@ -151,13 +150,8 @@ def run_experiment(
     when the metrics registry is enabled — the pooled metrics snapshot.
     ``profile_dir`` additionally wraps the execute leg in a
     ``jax.profiler.trace`` context writing there.
-
-    ``pack`` selects the while-loop carry layout (see
-    :func:`cimba_tpu.core.loop.make_run`; None = the
-    ``CIMBA_XLA_PACK``/backend auto default) — trajectory-identical
-    either way, bench.py measures both arms through this knob.
     """
-    run = make_run(spec, t_end=t_end, pack=pack, batched=True)
+    run = make_run(spec, t_end=t_end, batched=True)
     pb = _broadcast_params(params, n_replications)
     reps = jnp.arange(n_replications)
 
@@ -231,7 +225,6 @@ def run_experiment_regrow(
     mesh: Optional[Mesh] = None,
     t_end: Optional[float] = None,
     max_regrows: int = 4,
-    pack: Optional[bool] = None,
 ):
     """``run_experiment`` with the capacity escape hatch: if any
     replication died with ``ERR_EVENT_OVERFLOW``/``ERR_GUARD_OVERFLOW``,
@@ -260,7 +253,7 @@ def run_experiment_regrow(
     for n_regrows in range(max_regrows + 1):
         result = run_experiment(
             spec, params, n_replications, seed=seed, mesh=mesh,
-            t_end=t_end, pack=pack,
+            t_end=t_end,
         )
         err = np.asarray(result.sims.err)
         if not np.isin(err, grow_errs).any():
@@ -280,7 +273,6 @@ def run_experiment_regrow(
 def _chunk_program(
     spec: ModelSpec,
     t_end,
-    pack,
     chunk_steps: int,
     mesh: Optional[Mesh],
     donate: bool = True,
@@ -306,13 +298,10 @@ def _chunk_program(
     jaxpr-identical (pinned in tests/test_audit.py)."""
     if mesh is None:
         chunk = make_chunk(
-            spec, t_end=t_end, pack=pack, max_steps=chunk_steps,
-            audit=audit,
+            spec, t_end=t_end, max_steps=chunk_steps, audit=audit,
         )
     else:
-        chunk_local = make_chunk(
-            spec, t_end=t_end, pack=pack, max_steps=chunk_steps
-        )
+        chunk_local = make_chunk(spec, t_end=t_end, max_steps=chunk_steps)
         counted = bool(spec.boundary_pcs)
         out_specs = (
             (P(REP_AXIS), P())
@@ -508,7 +497,7 @@ def _tel_hooks(telemetry, kind: str, on_wave, on_chunk):
     telemetry ticks (docs/17_telemetry.md): with a
     :class:`cimba_tpu.obs.telemetry.Telemetry` plane attached, each
     wave/chunk boundary ticks its counter and refreshes the liveness
-    heartbeat (the watchdog primitive ``bench.py`` reads), THEN calls
+    heartbeat (the watchdog primitive an operator's harness reads), THEN calls
     the user hook.  ``telemetry=None`` returns the hooks untouched —
     the zero-overhead default (no wrapper closures, no allocations on
     the drive loop)."""
@@ -536,7 +525,6 @@ def run_experiment_chunked(
     seed: int = 0,
     mesh: Optional[Mesh] = None,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
     chunk_steps: int = 1024,
     poll_every: int = 4,
     donate: bool = True,
@@ -616,7 +604,7 @@ def run_experiment_chunked(
             )
 
     _, on_chunk = _tel_hooks(telemetry, "chunked", None, on_chunk)
-    chunk = _chunk_program(spec, t_end, pack, chunk_steps, mesh, donate)
+    chunk = _chunk_program(spec, t_end, chunk_steps, mesh, donate)
     sims = drive_chunks(
         chunk, sims, poll_every=poll_every, on_chunk=on_chunk,
         on_state=on_state, on_state_every=checkpoint_every, n0=n0,
@@ -637,7 +625,6 @@ def run_experiment_stream(
     seed: int = 0,
     mesh: Optional[Mesh] = None,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
     chunk_steps: Optional[int] = None,
     poll_every: int = 4,
     summary_path=default_summary_path,
@@ -676,7 +663,7 @@ def run_experiment_stream(
     programs at the remainder shape (one extra compile).
 
     ``on_wave(n_waves, lanes_done)`` and ``on_chunk(n)`` are progress
-    hooks (bench.py refreshes its watchdog heartbeat there).
+    hooks (a caller's progress or watchdog heartbeat).
     ``telemetry`` generalizes them: a
     :class:`cimba_tpu.obs.telemetry.Telemetry` plane gets a tick
     (counter + liveness heartbeat) per wave and per chunk, and — with
@@ -686,14 +673,14 @@ def run_experiment_stream(
     with or without it.
 
     ``program_cache``: pass the SAME mapping to repeated calls to reuse
-    the compiled init/chunk/fold programs across calls (bench.py's
+    the compiled init/chunk/fold programs across calls (a benchmark's
     warm-then-time protocol; a service shares one cache across every
     request).  Every setting a program bakes in — the spec's structural
     fingerprint, the dtype profile, the ``obs.metrics`` and
-    ``obs.trace`` states, the event-set layout flags, the resolved
-    ``pack`` arm, ``chunk_steps``, ``mesh``, and ``summary_path``
-    identity — is part of the cache key, so a mismatched call
-    recompiles rather than replaying stale programs.  ``seed`` and
+    ``obs.trace`` states, the backend (which picks the loop's carry
+    layout), ``chunk_steps``, ``mesh``, and ``summary_path`` identity —
+    is part of the cache key, so a mismatched call recompiles rather
+    than replaying stale programs.  ``seed`` and
     ``t_end`` are NOT program constants on this path: they ride as
     per-lane data columns (bit-identical trajectories), so calls
     differing only in them — and structurally identical spec twins from
@@ -732,10 +719,10 @@ def run_experiment_stream(
     first (wave, chunk, carry-class).
 
     ``schedule`` / tuned resolution (docs/21_autotune.md): the
-    dispatch knobs left unset here — ``pack``, ``chunk_steps``
-    (default 1024), ``wave_size``, and the trace-time event-set
-    layout — resolve through :func:`cimba_tpu.tune.registry.
-    resolve_entry` at program-build time: an explicit
+    dispatch knobs left unset here — ``chunk_steps`` (default 1024),
+    ``wave_size``, and the trace-time table-scan layout — resolve
+    through :func:`cimba_tpu.tune.registry.resolve_entry` at
+    program-build time: an explicit
     ``schedule=``:class:`~cimba_tpu.tune.space.Schedule` binds exactly
     that schedule (the search harness's arm dispatch); otherwise, with
     ``CIMBA_TUNE`` on (the default) and a program store in reach
@@ -757,14 +744,14 @@ def run_experiment_stream(
     with span("runner.stream", spec=spec.name,
               R=int(n_replications)) as sp:
         rs = _tune_reg.resolve_entry(
-            spec, n_replications, schedule=schedule, pack=pack,
+            spec, n_replications, schedule=schedule,
             chunk_steps=chunk_steps, wave_size=wave_size, store=store,
         )
         with rs.scope():
             result = _stream_impl(
                 spec, params, n_replications,
                 wave_size=rs.wave_size, seed=seed, mesh=mesh, t_end=t_end,
-                pack=rs.pack, chunk_steps=rs.chunk_steps,
+                chunk_steps=rs.chunk_steps,
                 poll_every=poll_every, summary_path=summary_path,
                 max_regrows=max_regrows, on_wave=on_wave,
                 on_chunk=on_chunk, telemetry=telemetry,
@@ -788,7 +775,6 @@ def _stream_impl(
     seed: int = 0,
     mesh: Optional[Mesh] = None,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
     chunk_steps: int = 1024,
     poll_every: int = 4,
     summary_path=default_summary_path,
@@ -854,7 +840,7 @@ def _stream_impl(
         # event_cap, which changes the structural fingerprint, so
         # grown capacities get their own programs as before.
         return _pcache.get_programs(
-            programs, spec, mesh=mesh, pack=pack,
+            programs, spec, mesh=mesh,
             chunk_steps=chunk_steps, with_metrics=with_metrics,
             audit=use_audit,
         )
@@ -969,8 +955,7 @@ def _stream_impl(
 
         try:
             pkey = _pstore.store_key(
-                spec0, with_metrics, mesh=mesh, pack=pack,
-                chunk_steps=chunk_steps,
+                spec0, with_metrics, mesh=mesh, chunk_steps=chunk_steps,
             )
         except Exception:
             pkey = None  # unstable spec: the card's spec block says why
@@ -984,10 +969,6 @@ def _stream_impl(
                 "chunk_steps": chunk_steps,
                 "poll_every": poll_every,
                 "t_end": t_end,
-                "pack": bool(
-                    pack if pack is not None
-                    else _config.xla_pack_enabled()
-                ),
                 "profile": _config.active_profile(),
                 "with_metrics": with_metrics,
                 "mesh": _pstore._mesh_descriptor(mesh),
@@ -1014,7 +995,6 @@ def make_sharded_experiment(
     spec: ModelSpec, n_replications: int, mesh: Mesh, *,
     summary_path=default_summary_path,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
 ):
     """Build the fully-fused multi-chip experiment step: run all local
     replications AND reduce statistics over the mesh inside one jitted
@@ -1031,7 +1011,7 @@ def make_sharded_experiment(
     """
     from cimba_tpu.obs import metrics as _metrics
 
-    run = make_run(spec, t_end=t_end, pack=pack, batched=True)
+    run = make_run(spec, t_end=t_end, batched=True)
     reps = jnp.arange(n_replications)
     with_metrics = _metrics.enabled()
 

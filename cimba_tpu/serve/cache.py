@@ -21,8 +21,7 @@ thread-safe LRU** (:class:`ProgramCache`) plus the key builders both
   constants, so they appear in NEITHER key — the heterogeneous-wave
   contract of docs/14_wave_packing.md;
 * hit/miss/eviction counters make cache health observable
-  (:meth:`ProgramCache.stats`, surfaced by ``Service.stats()`` and the
-  bench serve arm).
+  (:meth:`ProgramCache.stats`, surfaced by ``Service.stats()``).
 
 Entry-pinning invariant: every key that embeds object identities (the
 structural fingerprint's block/handler/predicate function ids) stores
@@ -283,13 +282,13 @@ def spec_fingerprint(spec) -> tuple:
 
 
 # cimba-check: content-path
-def program_class_key(spec, with_metrics: bool, *, mesh, pack) -> tuple:
+def program_class_key(spec, with_metrics: bool, *, mesh) -> tuple:
     """The Tier-A **compatibility class**: everything a compiled chunk
     program bakes in EXCEPT ``chunk_steps`` — the spec's structural
     fingerprint, the dtype profile, the ``obs.metrics``/``obs.trace``
-    flags, the event-set layout, the resolved ``pack`` arm, and the
-    mesh — with the trace-time globals resolved NOW so a flag flip
-    between calls misses the cache rather than replaying the stale arm.
+    flags, and the mesh — with the trace-time globals resolved NOW so a
+    flag flip between calls misses the cache rather than replaying the
+    stale arm.
 
     Seed, horizon (``t_end``), params values, R, and priority are all
     per-lane DATA on this path (``runner.experiment._init_program``'s
@@ -308,26 +307,19 @@ def program_class_key(spec, with_metrics: bool, *, mesh, pack) -> tuple:
         spec_fingerprint(spec),
         _config.active_profile(),
         bool(with_metrics),
-        pack if pack is not None else _config.xla_pack_enabled(),
         _trace.enabled(),
-        _config.eventset_hier_enabled(),
-        _config.eventset_block(),
         mesh,
     )
 
 
 # cimba-check: content-path
-def program_key(
-    spec, with_metrics: bool, *, mesh, pack, chunk_steps: int,
-) -> tuple:
+def program_key(spec, with_metrics: bool, *, mesh, chunk_steps: int) -> tuple:
     """The full key of one compiled ``(init, chunk)`` program pair:
     the compatibility class plus the chunk budget the program bakes in.
     Any component silently replaying stale would return a DIFFERENT
     model's trajectories with no error — which is why the trace-time
     globals resolve into the class at key-build time."""
-    return program_class_key(
-        spec, with_metrics, mesh=mesh, pack=pack,
-    ) + (chunk_steps,)
+    return program_class_key(spec, with_metrics, mesh=mesh) + (chunk_steps,)
 
 
 def get_programs(
@@ -335,10 +327,10 @@ def get_programs(
     spec,
     *,
     mesh,
-    pack,
     chunk_steps: int,
     with_metrics: bool,
     audit: bool = False,
+    pack=None,
 ):
     """The stream runner's ``get_programs``, shared with the service:
     one compiled ``(init, chunk)`` pair per :func:`program_key` point
@@ -360,12 +352,21 @@ def get_programs(
     third digest output per chunk, docs/18_audit.md): its key gets a
     distinct suffix — ``audit=False`` keys are byte-identical to the
     historical ones — and store hydration is skipped, because stored
-    artifacts are always the unaudited two-output program."""
+    artifacts are always the unaudited two-output program.
+
+    ``pack`` is accepted only as ``None``: the loop's carry layout
+    follows the backend (``core.loop._packed_carry``), and the keyword
+    stays for the benchmark's offline compile test, which still passes
+    it."""
     from cimba_tpu.serve import store as _pstore
 
-    key = program_key(
-        spec, with_metrics, mesh=mesh, pack=pack, chunk_steps=chunk_steps,
-    )
+    if pack is not None:
+        raise TypeError(
+            "get_programs: the carry layout follows the backend; pass "
+            "no pack"
+        )
+
+    key = program_key(spec, with_metrics, mesh=mesh, chunk_steps=chunk_steps)
     if audit:
         key = key + ("audit",)
 
@@ -382,7 +383,7 @@ def get_programs(
         if st is not None:
             try:
                 hyd = st.hydrate(
-                    spec, mesh=mesh, pack=pack, chunk_steps=chunk_steps,
+                    spec, mesh=mesh, chunk_steps=chunk_steps,
                     with_metrics=with_metrics,
                 )
             except Exception as e:  # a store bug must never block serving
@@ -397,7 +398,7 @@ def get_programs(
         return (
             ex._init_program(spec, mesh),
             ex._chunk_program(
-                spec, None, pack, chunk_steps, mesh, audit=audit
+                spec, None, chunk_steps, mesh, audit=audit
             ),
             spec,  # pins the fingerprint's function ids while cached
         )
@@ -410,7 +411,6 @@ def get_refill_programs(
     spec,
     *,
     mesh,
-    pack,
     with_metrics: bool,
 ):
     """The refill plane's compiled pair for one compatibility class:
@@ -418,14 +418,12 @@ def get_refill_programs(
     per-lane liveness readback (docs/22_refill.md).  Keyed by the SAME
     compatibility class the chunk program keys by (the Sim pytree a
     splice must reproduce is the class's — profile, metrics/trace
-    leaves, event-set layout), so a refill can never splice rows laid
+    leaves), so a refill can never splice rows laid
     out for a different program.  No store hydration: both programs
     are small host compiles (the chunk program dominates cold start),
     though jax's persistent compilation cache, where an entry point
     turned it on, still softens them to disk hits."""
-    key = ("refill",) + program_class_key(
-        spec, with_metrics, mesh=mesh, pack=pack,
-    )
+    key = ("refill",) + program_class_key(spec, with_metrics, mesh=mesh)
 
     def build():
         from cimba_tpu.runner import experiment as ex
@@ -486,7 +484,6 @@ def sim_structure_sig(
     with_metrics: bool,
     *,
     mesh,
-    pack,
 ) -> tuple:
     """The full Sim STRUCTURE signature of one lane of this request —
     treedef plus per-leaf (lane-row shape, dtype) from ``eval_shape``
@@ -496,7 +493,7 @@ def sim_structure_sig(
     class instead of exploding inside ``lax.switch`` at trace time
     (docs/26_wave_fusion.md).  Memoized beside the programs it guards."""
     key = ("sim_sig",) + program_class_key(
-        spec, with_metrics, mesh=mesh, pack=pack,
+        spec, with_metrics, mesh=mesh,
     ) + (_params_sig(params, n_replications),)
 
     def build():
@@ -570,7 +567,6 @@ def get_fused_wave_programs(
     fused,
     *,
     mesh,
-    pack,
     chunk_steps: int,
     with_metrics: bool,
 ):
@@ -582,7 +578,7 @@ def get_fused_wave_programs(
     is fused (``runner.experiment._fused_init_program`` — the per-lane
     spec-id switch, docs/26_wave_fusion.md)."""
     init_key = ("fused_init",) + program_class_key(
-        fused.spec, with_metrics, mesh=mesh, pack=pack,
+        fused.spec, with_metrics, mesh=mesh,
     )
 
     def build():
@@ -595,7 +591,7 @@ def get_fused_wave_programs(
 
     finit_j = _get_or_create(programs, init_key, build)[0]
     _, chunk_j = get_programs(
-        programs, fused.spec, mesh=mesh, pack=pack,
+        programs, fused.spec, mesh=mesh,
         chunk_steps=chunk_steps, with_metrics=with_metrics,
     )
     return finit_j, chunk_j
@@ -606,7 +602,6 @@ def get_fused_refill_programs(
     fused,
     *,
     mesh,
-    pack,
     with_metrics: bool,
 ):
     """The fused refill plane's compiled pair: ``(frefill_j, live_j)``
@@ -615,7 +610,7 @@ def get_fused_refill_programs(
     horizon/done/err, never the block table), so the merged spec's
     ordinary live program serves every member's lanes."""
     key = ("fused_refill",) + program_class_key(
-        fused.spec, with_metrics, mesh=mesh, pack=pack,
+        fused.spec, with_metrics, mesh=mesh,
     )
 
     def build():
@@ -642,7 +637,6 @@ def wave_footprint_bytes(
     spec,
     *,
     mesh,
-    pack,
     chunk_steps: int,
     with_metrics: bool,
     lanes: int,
@@ -682,7 +676,7 @@ def wave_footprint_bytes(
         ),
     )
     key = ("footprint",) + program_key(
-        spec, with_metrics, mesh=mesh, pack=pack,
+        spec, with_metrics, mesh=mesh,
         chunk_steps=chunk_steps,
     ) + (int(lanes), psig)
 
@@ -691,7 +685,7 @@ def wave_footprint_bytes(
         import numpy as np
 
         init_j, chunk_j = get_programs(
-            programs, spec, mesh=mesh, pack=pack,
+            programs, spec, mesh=mesh,
             chunk_steps=chunk_steps, with_metrics=with_metrics,
         )
         L = int(lanes)
@@ -862,7 +856,7 @@ def preflight_summary_path(
     model's Sim fails here with the knob named, not as an opaque
     KeyError from inside the fold after a full wave of compute.  Cached
     by the spec's structural fingerprint (twin specs share the check)
-    so a warmed cache skips the re-trace inside bench's timed region
+    so a warmed cache skips the re-trace inside a benchmark's timed region
     (the entry pins spec, keeping the fingerprint's ids valid)."""
     key = (
         "preflight", spec_fingerprint(spec), summary_path, with_metrics,
@@ -958,17 +952,16 @@ def warm(
         # service's stats) hit the same instance/counters the warm did
         cache._store = st
     mesh = stream_kwargs.pop("mesh", None)
-    pack = stream_kwargs.pop("pack", None)
     chunk_steps = stream_kwargs.pop("chunk_steps", None)
     summary_path = stream_kwargs.pop("summary_path", None)
     n_replications = stream_kwargs.pop("n_replications", None)
     if stream_kwargs:
         raise TypeError(
             "serve.warm(manifest=...): unsupported kwargs in AOT mode: "
-            f"{sorted(stream_kwargs)} (only mesh/pack/chunk_steps/"
+            f"{sorted(stream_kwargs)} (only mesh/chunk_steps/"
             "summary_path/n_replications select a program)"
         )
-    if chunk_steps is None or pack is None:
+    if chunk_steps is None:
         # tuned-schedule resolution at program-build time
         # (docs/21_autotune.md): the hydrated program key must be the
         # one the service will actually dispatch, and the service
@@ -979,20 +972,18 @@ def warm(
         from cimba_tpu.tune import registry as _tune_reg
 
         rs = _tune_reg.resolve_entry(
-            spec, int(n_replications or wave_size or 0), pack=pack,
+            spec, int(n_replications or wave_size or 0),
             chunk_steps=chunk_steps, store=st,
         )
-        chunk_steps, pack = rs.chunk_steps, rs.pack
+        chunk_steps = rs.chunk_steps
     if summary_path is None:
         summary_path = ex.default_summary_path
     with_metrics = _metrics.enabled()
-    key = program_key(
-        spec, with_metrics, mesh=mesh, pack=pack, chunk_steps=chunk_steps,
-    )
+    key = program_key(spec, with_metrics, mesh=mesh, chunk_steps=chunk_steps)
     folds: dict = {}
     if key not in cache:
         hyd = st.hydrate(
-            spec, mesh=mesh, pack=pack, chunk_steps=chunk_steps,
+            spec, mesh=mesh, chunk_steps=chunk_steps,
             with_metrics=with_metrics,
         )
         if hyd is None:

@@ -5,7 +5,7 @@ distribution under concurrent load: N client threads submitting
 requests against the bounded queue, open-loop (arrivals on a fixed
 schedule, independent of completions — the shape that exposes queueing
 collapse) or as a burst.  This module is the shared driver behind
-``examples/serve_mm1.py``, the bench serve arm, and the many-client
+``examples/serve_mm1.py`` and the many-client
 soak test — host-side threading only, no jax.
 """
 
@@ -361,8 +361,7 @@ def run_mixed_load(
     completions by template name (and :meth:`LoadReport.per_tenant` by
     tenant id when templates carry tenants — the QoS fairness view).
     Occupancy/padding live in
-    ``service.stats()`` (``batch_occupancy``, ``lane_occupancy``) —
-    the bench ``serve_mixed`` arm reads both."""
+    ``service.stats()`` (``batch_occupancy``, ``lane_occupancy``)."""
     requests, names = mixed_requests(templates, n_requests)
     report = run_load(service, requests, **run_load_kwargs)
     report.template_names = names

@@ -358,7 +358,7 @@ class DeviceScheduler:
             lanes = n
         req = entry.request
         return _pcache.wave_footprint_bytes(
-            svc.cache, req.spec, mesh=svc.mesh, pack=req.pack,
+            svc.cache, req.spec, mesh=svc.mesh,
             chunk_steps=req.chunk_steps,
             with_metrics=entry.with_metrics, lanes=lanes,
             params=req.params, n_replications=req.n_replications,
@@ -379,16 +379,14 @@ class DeviceScheduler:
         try:
             cls_now = _pcache.program_class_key(
                 req.spec, _metrics.enabled(), mesh=svc.mesh,
-                pack=req.pack,
             )
             if cls_now != lead.cls[0]:
                 raise ValueError(
-                    "serve: a trace-time global (dtype profile, "
-                    "obs.metrics/obs.trace state, eventset layout, or "
-                    "the pack default) changed between this request's "
-                    "submit and its dispatch — the compatibility key "
-                    "binds at submit time; resubmit after settling "
-                    "the globals"
+                    "serve: a trace-time global (dtype profile or "
+                    "obs.metrics/obs.trace state) changed between this "
+                    "request's submit and its dispatch — the "
+                    "compatibility key binds at submit time; resubmit "
+                    "after settling the globals"
                 )
             wave = svc._pack_refill(lead)
             sims = svc._init_refill_wave(wave)

@@ -12,8 +12,8 @@ already-warm programs:
   threads only enqueue and wait on futures).
 * **Compatibility-class packing** (docs/14_wave_packing.md): queued
   requests of the same *compatibility class* — the spec's structural
-  fingerprint, dtype profile, metrics/trace/eventset flags, resolved
-  pack arm, mesh (`serve.cache.program_class_key`), the params tree
+  fingerprint, dtype profile, metrics/trace flags, mesh
+  (`serve.cache.program_class_key`), the params tree
   signature, and the horizon bucket — are packed into ONE wave of the
   shared compiled chunk program, and the pooled results are sliced
   back per request.  Seed, parameter VALUES, R, priority, horizon
@@ -102,7 +102,7 @@ def request_class_key(request, with_metrics: bool, *, mesh,
                       horizon_bucket) -> tuple:
     """What may share a wave — the compatibility CLASS of one
     :class:`Request`: the compiled-program class (spec structural
-    fingerprint, profile, flags, pack arm, mesh —
+    fingerprint, profile, flags, mesh —
     ``serve.cache.program_class_key``), the params tree signature
     (slices of both requests' params must concatenate), and the horizon
     bucket.  Seed, param VALUES, R, priority, the exact ``t_end``, and
@@ -115,9 +115,7 @@ def request_class_key(request, with_metrics: bool, *, mesh,
 
     from cimba_tpu.runner import experiment as ex
 
-    pck = _pcache.program_class_key(
-        request.spec, with_metrics, mesh=mesh, pack=request.pack,
-    )
+    pck = _pcache.program_class_key(request.spec, with_metrics, mesh=mesh)
     shapes = jax.eval_shape(
         lambda: ex._slice_params(
             request.params, request.n_replications, 0, 1
@@ -154,16 +152,13 @@ def fusion_class_key(request, with_metrics: bool, *, cache, mesh,
     shape = _fuse.fusion_shape_key(request.spec)
     sim_sig = _pcache.sim_structure_sig(
         cache, request.spec, request.params, request.n_replications,
-        with_metrics, mesh=mesh, pack=request.pack,
+        with_metrics, mesh=mesh,
     )
     psig = _pcache._params_sig(request.params, request.n_replications)
     return (
         shape, sim_sig, psig,
         _config.active_profile(), bool(with_metrics),
-        request.pack if request.pack is not None
-        else _config.xla_pack_enabled(),
         _trace.enabled(),
-        _config.eventset_hier_enabled(), _config.eventset_block(),
         mesh, horizon_bucket_of(request.t_end, horizon_bucket),
     )
 
@@ -192,14 +187,14 @@ class Request:
     flips ``/healthz`` to degraded — determinism regressions surface
     in the fleet's monitoring, not just in pytest.
 
-    ``chunk_steps=None`` / ``pack=None`` (the defaults) resolve
+    ``chunk_steps=None`` (the default) resolves
     through the tuned-schedule registry at submit time
     (docs/21_autotune.md): with ``CIMBA_TUNE`` on and the service's
     program store carrying a searched winner for this (spec, backend,
     workload bucket), the winner's argument knobs fill in; otherwise
-    the historical defaults (``chunk_steps=1024``, backend-auto pack)
-    run unchanged.  Explicit values always win, and the resolution
-    source (tuned/default/override) surfaces per class in
+    the historical default (``chunk_steps=1024``) runs unchanged.
+    Explicit values always win, and the resolution source
+    (tuned/default/override) surfaces per class in
     ``Service.stats()["schedule"]`` and ``/varz``."""
 
     spec: Any
@@ -207,7 +202,6 @@ class Request:
     n_replications: int
     seed: int = 0
     t_end: Optional[float] = None
-    pack: Optional[bool] = None
     chunk_steps: Optional[int] = None
     wave_size: Optional[int] = None
     summary_path: Optional[Callable] = None
@@ -452,7 +446,7 @@ class Service:
     (one is created if not given — pass your own to share warm programs
     with direct `run_experiment_stream` calls or across services);
     ``max_retries``/``backoff`` govern dispatch-failure retries;
-    ``on_chunk`` is a per-chunk progress hook (bench.py's watchdog
+    ``on_chunk`` is a per-chunk progress hook (a caller's watchdog
     heartbeat).  Use as a context manager for a graceful shutdown.
 
     Packing policy knobs (docs/14_wave_packing.md):
@@ -823,8 +817,8 @@ class Service:
         # Request's None has always meant "the service's max_wave", an
         # explicit policy, not an unset knob — a tuned wave_size never
         # applies here and never claims the 'tuned' source).  Trace-
-        # time knobs — event-set layout — are process-level on the
-        # serve path: set the CIMBA_EVENTSET_* env/config state the
+        # time knobs — the table-scan layout — are process-level on the
+        # serve path: set the CIMBA_TABLE_SCAN* env/config state the
         # tuner recommends; the dispatcher never flips globals under
         # concurrent traffic.  Explicit values always win.
         import dataclasses as _dc
@@ -836,16 +830,14 @@ class Service:
             if isinstance(self.cache, _pcache.ProgramCache) else None
         )
         rs = _tune_reg.resolve_entry(
-            request.spec, R, pack=request.pack,
+            request.spec, R,
             chunk_steps=request.chunk_steps, wave_size=eff_wave,
             store=_store,
         )
-        if (request.chunk_steps, request.pack) != (
-            rs.chunk_steps, rs.pack
-        ):
+        if request.chunk_steps != rs.chunk_steps:
             # normalize a COPY — the caller's Request is never mutated
             request = _dc.replace(
-                request, chunk_steps=rs.chunk_steps, pack=rs.pack,
+                request, chunk_steps=rs.chunk_steps,
             )
         if eff_wave <= 0:
             raise ValueError(
@@ -1815,33 +1807,33 @@ class Service:
             lead = slots[0][0]
             req = lead.request
             cls_now = _pcache.program_class_key(
-                req.spec, _metrics.enabled(), mesh=self.mesh, pack=req.pack,
+                req.spec, _metrics.enabled(), mesh=self.mesh,
             )
             if cls_now != lead.cls[0]:
                 # the program CLASS (dtype profile, obs.metrics/trace
-                # flags, eventset layout, the pack default...) was frozen
+                # flags...) was frozen
                 # into the compatibility key at submit; tracing now under
                 # drifted globals would cache a program whose behavior
                 # contradicts that key (and silently serve it to every
                 # later request at this key).  ValueError = permanent:
                 # fail the request loudly instead.
                 raise ValueError(
-                    "serve: a trace-time global (dtype profile, "
-                    "obs.metrics/obs.trace state, eventset layout, or the "
-                    "pack default) changed between this request's submit "
-                    "and its dispatch — the compatibility key binds at "
-                    "submit time; resubmit after settling the globals"
+                    "serve: a trace-time global (dtype profile or "
+                    "obs.metrics/obs.trace state) changed between this "
+                    "request's submit and its dispatch — the "
+                    "compatibility key binds at submit time; resubmit "
+                    "after settling the globals"
                 )
             if fused is None:
                 init_j, chunk_j = _pcache.get_programs(
-                    self.cache, req.spec, mesh=self.mesh, pack=req.pack,
+                    self.cache, req.spec, mesh=self.mesh,
                     chunk_steps=req.chunk_steps,
                     with_metrics=lead.with_metrics,
                 )
                 sid_of = None
             else:
                 init_j, chunk_j = _pcache.get_fused_wave_programs(
-                    self.cache, fused, mesh=self.mesh, pack=req.pack,
+                    self.cache, fused, mesh=self.mesh,
                     chunk_steps=req.chunk_steps,
                     with_metrics=lead.with_metrics,
                 )
@@ -2037,16 +2029,14 @@ class Service:
         try:
             cls_now = _pcache.program_class_key(
                 req.spec, _metrics.enabled(), mesh=self.mesh,
-                pack=req.pack,
             )
             if cls_now != lead.cls[0]:
                 raise ValueError(
-                    "serve: a trace-time global (dtype profile, "
-                    "obs.metrics/obs.trace state, eventset layout, or "
-                    "the pack default) changed between this request's "
-                    "submit and its dispatch — the compatibility key "
-                    "binds at submit time; resubmit after settling "
-                    "the globals"
+                    "serve: a trace-time global (dtype profile or "
+                    "obs.metrics/obs.trace state) changed between this "
+                    "request's submit and its dispatch — the "
+                    "compatibility key binds at submit time; resubmit "
+                    "after settling the globals"
                 )
             wave = self._pack_refill(lead)
             sims = self._init_refill_wave(wave)
@@ -2419,12 +2409,12 @@ class Service:
         req = lead.request
         if wave.fused is None:
             wave.init_j, wave.chunk_j = _pcache.get_programs(
-                self.cache, req.spec, mesh=self.mesh, pack=req.pack,
+                self.cache, req.spec, mesh=self.mesh,
                 chunk_steps=req.chunk_steps,
                 with_metrics=lead.with_metrics,
             )
             wave.refill_j, wave.live_j = _pcache.get_refill_programs(
-                self.cache, req.spec, mesh=self.mesh, pack=req.pack,
+                self.cache, req.spec, mesh=self.mesh,
                 with_metrics=lead.with_metrics,
             )
         else:
@@ -2433,14 +2423,14 @@ class Service:
             # chunk/live programs — one compiled set per fusion class,
             # shared by every member
             wave.init_j, wave.chunk_j = _pcache.get_fused_wave_programs(
-                self.cache, wave.fused, mesh=self.mesh, pack=req.pack,
+                self.cache, wave.fused, mesh=self.mesh,
                 chunk_steps=req.chunk_steps,
                 with_metrics=lead.with_metrics,
             )
             wave.refill_j, wave.live_j = (
                 _pcache.get_fused_refill_programs(
                     self.cache, wave.fused, mesh=self.mesh,
-                    pack=req.pack, with_metrics=lead.with_metrics,
+                    with_metrics=lead.with_metrics,
                 )
             )
         for s in wave.slots:

@@ -11,7 +11,7 @@ without ever invoking XLA.  Two mechanisms, layered
 (docs/15_program_store.md):
 
 (a) **JAX's persistent compilation cache** — an entry point
-    (``chip_smoke.py``, ``bench.py``) calls :func:`maybe_enable_
+    (``chip_smoke.py``, ``benchmark/run.py``) calls :func:`maybe_enable_
     persistent_cache` once at start, so *every* jit in that process
     becomes a disk hit on recompile.  The cache lives where
     ``JAX_COMPILATION_CACHE_DIR`` says, or at the fixed
@@ -338,9 +338,7 @@ def _mesh_descriptor(mesh) -> Optional[tuple]:
 
 
 # cimba-check: content-path
-def store_key(
-    spec, with_metrics: bool, *, mesh, pack, chunk_steps: int,
-) -> str:
+def store_key(spec, with_metrics: bool, *, mesh, chunk_steps: int) -> str:
     """The persistent program key: sha256 hex over the stable spec
     fingerprint plus every trace-time setting a compiled program bakes
     in — the value-based image of ``cache.program_key`` (same field
@@ -355,10 +353,7 @@ def store_key(
         stable_spec_fingerprint(spec),
         _config.active_profile(),
         bool(with_metrics),
-        bool(pack if pack is not None else _config.xla_pack_enabled()),
         _trace.enabled(),
-        _config.eventset_hier_enabled(),
-        _config.eventset_block(),
         _mesh_descriptor(mesh),
         int(chunk_steps),
     )
@@ -888,7 +883,6 @@ class ProgramStore:
         *,
         wave_sizes,
         mesh=None,
-        pack=None,
         chunk_steps: int = 1024,
         with_metrics: Optional[bool] = None,
         horizon_modes=("none", "column"),
@@ -923,11 +917,11 @@ class ProgramStore:
         if with_metrics is None:
             with_metrics = _metrics.enabled()
         key = store_key(
-            spec, with_metrics, mesh=mesh, pack=pack,
+            spec, with_metrics, mesh=mesh,
             chunk_steps=chunk_steps,
         )
         init_j = ex._init_program(spec, mesh)
-        chunk_j = ex._chunk_program(spec, None, pack, chunk_steps, mesh)
+        chunk_j = ex._chunk_program(spec, None, chunk_steps, mesh)
 
         programs = []
         downgrades = []
@@ -1118,7 +1112,6 @@ class ProgramStore:
         spec,
         *,
         mesh=None,
-        pack=None,
         chunk_steps: int = 1024,
         with_metrics: bool = False,
     ):
@@ -1138,7 +1131,7 @@ class ProgramStore:
 
         try:
             key = store_key(
-                spec, with_metrics, mesh=mesh, pack=pack,
+                spec, with_metrics, mesh=mesh,
                 chunk_steps=chunk_steps,
             )
         except UnstableStoreKey:
@@ -1200,7 +1193,7 @@ class ProgramStore:
             return None
         self._count("hits")
         init_j = ex._init_program(spec, mesh)
-        chunk_j = ex._chunk_program(spec, None, pack, chunk_steps, mesh)
+        chunk_j = ex._chunk_program(spec, None, chunk_steps, mesh)
         return HydratedPrograms(
             _HydratedProgram(init_j, tables["init"], self, "init",
                              footprints["init"]),
@@ -1210,7 +1203,7 @@ class ProgramStore:
         )
 
     def covered(
-        self, spec, *, mesh=None, pack=None, chunk_steps: int = 1024,
+        self, spec, *, mesh=None, chunk_steps: int = 1024,
         with_metrics: bool = False,
     ) -> bool:
         """True when a valid-looking manifest entry exists for this
@@ -1218,7 +1211,7 @@ class ProgramStore:
         verified at :meth:`hydrate` time)."""
         try:
             key = store_key(
-                spec, with_metrics, mesh=mesh, pack=pack,
+                spec, with_metrics, mesh=mesh,
                 chunk_steps=chunk_steps,
             )
         except UnstableStoreKey:

@@ -222,7 +222,6 @@ def run_sweep(
     cell_wave: Optional[int] = None,
     max_wave: int = 4096,
     t_end: Optional[float] = None,
-    pack: Optional[bool] = None,
     chunk_steps: Optional[int] = None,
     poll_every: int = 4,
     mesh=None,
@@ -263,7 +262,7 @@ def run_sweep(
     then belong to the service).  ``pad_waves`` quantizes direct-mode
     wave shapes with dead ``t_stop=-inf`` lanes (bitwise-inert; a mesh
     always pads to its device-count multiple).  ``on_round(round,
-    n_live, reps_total)`` is the progress hook (bench.py's watchdog
+    n_live, reps_total)`` is the progress hook (a caller's watchdog
     heartbeat ticks there).  ``telemetry`` attaches the host-side
     telemetry plane (docs/17_telemetry.md): per-round/per-chunk ticks
     (counter + liveness heartbeat) and — with spans enabled — one
@@ -272,7 +271,7 @@ def run_sweep(
     request spans per (cell, round).  Host-side only: results are
     bitwise identical with or without it.
 
-    ``chunk_steps=None`` / ``pack=None`` (the defaults) resolve
+    ``chunk_steps=None`` (the default) resolves
     through the tuned-schedule registry for the per-cell workload
     bucket at program-build time (docs/21_autotune.md) — explicit
     kwargs always win, ``CIMBA_TUNE=0`` restores the hand-frozen
@@ -315,9 +314,9 @@ def run_sweep(
             if hasattr(program_cache, "_store") else None
         )
         rs = _tune_reg.resolve_entry(
-            spec, R0, pack=pack, chunk_steps=chunk_steps, store=_store,
+            spec, R0, chunk_steps=chunk_steps, store=_store,
         )
-        pack, chunk_steps = rs.pack, rs.chunk_steps
+        chunk_steps = rs.chunk_steps
         sched_block = rs.block()
     else:
         # serve-backed: chunk_steps=None flows into each Request and
@@ -373,7 +372,7 @@ def run_sweep(
             else _pcache.ProgramCache()
         )
         init_j, chunk_j = _pcache.get_programs(
-            programs, spec, mesh=mesh, pack=pack,
+            programs, spec, mesh=mesh,
             chunk_steps=chunk_steps, with_metrics=with_metrics,
         )
         _pcache.preflight_summary_path(
@@ -499,7 +498,7 @@ def run_sweep(
         handles = []
         for ci, sd, reps in jobs:
             handles.append((ci, service.submit(Request(
-                spec, rows[ci], reps, seed=sd, t_end=t_end, pack=pack,
+                spec, rows[ci], reps, seed=sd, t_end=t_end,
                 chunk_steps=chunk_steps,
                 wave_size=min(cell_wave, reps),
                 summary_path=summary_path,

@@ -1,15 +1,12 @@
 """cimba_tpu.tune — the schedule autotuner (docs/21_autotune.md).
 
-Every dispatch knob on the hot path — the hierarchical event-set
-minima (``CIMBA_EVENTSET_HIER`` / ``CIMBA_EVENTSET_BLOCK``), the
-packed XLA while-loop carry (``CIMBA_XLA_PACK``), the chunk budget
-(``chunk_steps``), the wave quantum (``wave_size``), and the Pallas
-lane-block grid where the kernel path is live — is a *schedule*: it
-changes how fast a program runs, never what it computes (the
-per-knob bitwise pins of docs/11/12/14).  BENCH_NOTES round 6 proved
-the right setting flips by workload (the hierarchical min wins on
-pop-dominated event sets and loses on mutation-bursty ones; the
-packed carry wins on mm1/mg1 CPU arms), so hand-frozen defaults are
+Every dispatch knob on the hot path — the chunk budget
+(``chunk_steps``), the wave quantum (``wave_size``), the table-scan
+layout (``CIMBA_TABLE_SCAN`` / ``_BLOCK``), the Pallas lane-block grid
+where the kernel path is live, and the serving plane's scheduler and
+fusion policy — is a *schedule*: it changes how fast a program runs,
+never what it computes (the per-knob bitwise pins of docs/12/14/25).
+The right setting can flip by workload, so hand-frozen defaults may be
 wrong for someone.  This package searches the schedule space per
 (program, backend, workload bucket), pins every candidate bitwise
 against the default schedule, persists the winner in the PR 6
@@ -21,11 +18,10 @@ fleet slices — resolve the tuned schedule at program-build time
 Submodules: :mod:`~cimba_tpu.tune.space` (the declarative
 ``ScheduleSpace`` and the ``Schedule`` record),
 :mod:`~cimba_tpu.tune.measure` (the interleaved best-of-k measurement
-harness — the ONE timing implementation bench.py's arm batteries now
-ride), :mod:`~cimba_tpu.tune.search` (budgeted search emitting a
+harness), :mod:`~cimba_tpu.tune.search` (budgeted search emitting a
 crash-atomic ``TuneReport`` JSON), :mod:`~cimba_tpu.tune.registry`
-(store persistence + resolution), :mod:`~cimba_tpu.tune.probe` (the
-step-probe workload whose default schedule round 6 proved wrong).
+(store persistence + resolution), :mod:`~cimba_tpu.tune.probe` (a
+big-table ticker workload for the search's tests).
 """
 
 from cimba_tpu.tune.space import Schedule, ScheduleSpace, default_space

@@ -1,14 +1,10 @@
 """The measurement harness: interleaved best-of-k twin-arm timing.
 
-``bench.py`` grew this idiom three times (the chunked-vs-monolithic
-stream arm, the telemetry-overhead arm, the dispatch-arm batteries):
-measure every arm at the SAME operating point, interleave the rounds so
+Measure every arm at the SAME operating point, interleave the rounds so
 machine drift on a noisy shared host lands on every arm equally, take
 the best-of-k wall per arm, and never compare numbers measured at
-different moments of the battery.  This module is that idiom factored
-into ONE implementation — :func:`measure_arms` — which bench.py now
-rides (the deduplication satellite of docs/21_autotune.md) and the
-schedule search builds on.
+different moments of the battery.  :func:`measure_arms` is that idiom,
+and the schedule search builds on it.
 
 Contract:
 
@@ -162,7 +158,7 @@ def measure_arms(
     best-of-``repeats`` at one operating point.  ``baseline`` indexes
     the incumbent arm (run twice per round when ``noise_twin`` — its
     twin's gap is the noise floor).  ``on_round(r)`` is the per-round
-    progress hook (bench.py's watchdog heartbeat).  Budgets are wall
+    progress hook (a caller's watchdog heartbeat).  Budgets are wall
     seconds over the whole call; blowing one records SKIPPED arms /
     truncated rounds, never a silent drop."""
     arms = list(arms)

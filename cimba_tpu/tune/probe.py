@@ -1,16 +1,9 @@
-"""The step-probe workload: the model whose default schedule is wrong.
+"""The step-probe workload: a timer-heavy model for the schedule search.
 
-BENCH_NOTES round 6 (tools/dispatch_cost_probe.py) measured the
-hierarchical event-set losing on mutation-bursty timer workloads — the
-per-mutation block refresh costs more than the saved scan when every
-resume re-arms a burst of timers — while the shipped default leaves the
-hierarchy ON.  This module packages that adversarial shape as a
-searchable model (a big-table ticker: each resume re-arms
-``per_resume`` timers spread over a large event table), so
-``bench.py --config tune`` and the tune tests can demonstrate the
-autotuner finding a real, noise-floor-clearing win over the default on
-at least one shipped workload (the acceptance bar of
-docs/21_autotune.md).
+A big-table ticker: each resume re-arms ``per_resume`` timers spread
+over a large event table, so every step pays the full event-set scan
+and a burst of schedules.  The tune tests search, pin and persist
+schedules on it (docs/21_autotune.md).
 
 Unlike the raw ``make_step`` microprobe in tools/, this spec runs
 through the ordinary chunked stream path (``t_end`` bounds the run),
@@ -44,8 +37,7 @@ def build(event_cap: int = 2048, per_resume: int = 16,
     """The mutation-bursty ticker spec: one process holding ``hold``
     per resume and re-arming ``per_resume`` far-future timers (spread
     over ``rearm_spread`` distinct times so pattern-cancel never
-    collapses them) into an ``event_cap``-slot table — the ``sched``
-    shape of tools/dispatch_cost_probe.py as a whole-Sim model.
+    collapses them) into an ``event_cap``-slot table.
     ``event_cap`` must hold the burst: with ``t_end`` T, a lane
     schedules ``~T/hold * per_resume`` timers (all far-future), so size
     the horizon accordingly.  Returns ``(spec, ())`` in the model

@@ -289,34 +289,17 @@ class ResolvedEntry:
     schedule: Optional[Schedule]
     source: str
     tune_digest: Optional[str]
-    pack: Optional[bool]
     chunk_steps: int
     wave_size: Optional[int]
     applied: dict
 
     def scope(self):
-        """Context manager binding the resolved TRACE-time knobs
-        (event-set layout, kernel lane block) for a dispatch region.
-        The argument knobs (pack/chunk/wave) ride kwargs instead, and
-        an ambient programmatic override (``config.EVENTSET_HIER``
-        et al. already set — the bench ``_dispatch_arm`` idiom) is
-        never clobbered: explicit wins over tuned, tuned over
-        default."""
+        """Context manager binding the resolved TRACE-time knob (the
+        kernel lane block) for a dispatch region.  The argument knobs
+        (chunk/wave) ride kwargs instead."""
         if self.schedule is None:
             return contextlib.nullcontext()
-        from cimba_tpu import config
-
-        sub = Schedule(
-            eventset_hier=(
-                self.schedule.eventset_hier
-                if config.EVENTSET_HIER is None else None
-            ),
-            eventset_block=(
-                self.schedule.eventset_block
-                if config.EVENTSET_BLOCK is None else None
-            ),
-            lane_block=self.schedule.lane_block,
-        )
+        sub = Schedule(lane_block=self.schedule.lane_block)
         if sub.is_default():
             return contextlib.nullcontext()
         return sub.scope()
@@ -326,13 +309,11 @@ class ResolvedEntry:
         resolution source + tuned-entry digest — what run cards carry
         so every bitwise claim names the schedule it ran under."""
         knobs = {
-            "pack": self.pack,
             "chunk_steps": self.chunk_steps,
             "wave_size": self.wave_size,
         }
         if self.schedule is not None:
-            for f in ("eventset_hier", "eventset_block", "lane_block",
-                      "waves_per_device", "preempt_quantum",
+            for f in ("lane_block", "waves_per_device", "preempt_quantum",
                       "mem_fraction", "fuse", "fuse_max_specs"):
                 v = getattr(self.schedule, f)
                 if v is not None:
@@ -349,7 +330,6 @@ def resolve_entry(
     n_replications: int,
     *,
     schedule: Optional[Schedule] = None,
-    pack: Optional[bool] = None,
     chunk_steps: Optional[int] = None,
     wave_size: Optional[int] = None,
     store=None,
@@ -369,10 +349,6 @@ def resolve_entry(
             spec, n_replications, store=store,
         )
     applied: dict = {}
-    eff_pack = pack
-    if eff_pack is None and sched is not None and sched.pack is not None:
-        eff_pack = bool(sched.pack)
-        applied["pack"] = eff_pack
     eff_chunk = chunk_steps
     if eff_chunk is None:
         if sched is not None and sched.chunk_steps is not None:
@@ -386,14 +362,6 @@ def resolve_entry(
         eff_wave = int(sched.wave_size)
         applied["wave_size"] = eff_wave
     if sched is not None:
-        from cimba_tpu import config
-
-        if sched.eventset_hier is not None \
-                and config.EVENTSET_HIER is None:
-            applied["eventset_hier"] = bool(sched.eventset_hier)
-        if sched.eventset_block is not None \
-                and config.EVENTSET_BLOCK is None:
-            applied["eventset_block"] = int(sched.eventset_block)
         if sched.lane_block is not None:
             applied["lane_block"] = int(sched.lane_block)
         # device-scheduler policy knobs (docs/24_device_scheduler.md):
@@ -421,7 +389,6 @@ def resolve_entry(
         schedule=sched,
         source=source,
         tune_digest=dig,
-        pack=eff_pack,
         chunk_steps=int(eff_chunk),
         wave_size=eff_wave,
         applied=applied,

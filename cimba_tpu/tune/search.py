@@ -18,7 +18,7 @@ searched, docs/21_autotune.md), through the real
 
 **Bitwise pinning**: a candidate is eligible to win only if its
 result digest equals the default schedule's at the candidate's own
-wave geometry — dispatch knobs (event-set layout, packed carry) and
+wave geometry — dispatch knobs (table-scan layout) and
 ``chunk_steps`` are bitwise-invariant, so same-``wave_size`` arms must
 reproduce the baseline digest exactly; a candidate that changes
 ``wave_size`` is pinned against an untimed default-knob twin at that

@@ -4,15 +4,11 @@ A :class:`Schedule` is a record of the knobs one run binds; a
 :class:`ScheduleSpace` is the declarative grid of candidate schedules
 the search measures.  Nothing here is new mechanism — every field maps
 onto a knob the runner/serve/sweep entry points already accept
-(``pack=``, ``chunk_steps=``, ``wave_size=``) or a trace-time tri-state
-``cimba_tpu.config`` already exposes (``EVENTSET_HIER`` /
-``EVENTSET_BLOCK`` / ``XLA_PACK`` — the ``bench.py _dispatch_arm``
-idiom, made a first-class object).  Schedules never change results,
-only speed:
+(``chunk_steps=``, ``wave_size=``) or a trace-time tri-state
+``cimba_tpu.config`` already exposes (``TABLE_SCAN`` /
+``TABLE_SCAN_BLOCK``), made a first-class object.  Schedules never
+change results, only speed:
 
-* ``eventset_hier`` / ``eventset_block`` — bitwise the flat scan's
-  pick (tests/test_eventset_hier.py);
-* ``pack`` — trajectory-identical carry layout (tests/test_xla_pack.py);
 * ``chunk_steps`` — chunked trajectories ARE the monolithic ones
   bitwise, and folds happen per wave, not per chunk (docs/12);
 * ``wave_size`` — per-lane trajectories and the exact counters are
@@ -27,12 +23,12 @@ only speed:
   (tests/test_table_scan.py), trades per-access work for O(1)-in-P
   program text — a compile-time/run-time dial, not a results knob.
 
-Validity predicates prune instead of measuring: the hierarchical
-event-set is structurally inert whenever the model's event capacity is
-not a >= 2x multiple of the block size (the PR 2 inertness contract) —
-for such a model every ``eventset_hier``/``eventset_block`` setting
-traces the SAME program, so :meth:`ScheduleSpace.candidates`
-canonicalizes those axes away rather than timing identical arms.
+Validity predicates prune instead of measuring: the table scan is
+structurally inert whenever no table axis of the model is taller than
+the block (the core/dyn.py small-P inertness contract) — for such a
+model every ``table_scan``/``table_block`` setting traces the SAME
+program, so :meth:`ScheduleSpace.candidates` canonicalizes those axes
+away rather than timing identical arms.
 """
 
 from __future__ import annotations
@@ -51,12 +47,13 @@ __all__ = ["Schedule", "ScheduleSpace", "default_space"]
 #: (``waves_per_device`` / ``preempt_quantum`` / ``mem_fraction``).
 #: 3: the table-scan dispatch knobs (``table_scan`` / ``table_block``
 #: — docs/25_compile_wall.md).  4: the wave-fusion knobs (``fuse`` /
-#: ``fuse_max_specs`` — docs/26_wave_fusion.md).
-SCHEDULE_FORMAT = 4
+#: ``fuse_max_specs`` — docs/26_wave_fusion.md).  5: the carry layout
+#: and the hierarchical event set left the space (``pack`` /
+#: ``eventset_hier`` / ``eventset_block``).
+SCHEDULE_FORMAT = 5
 
 #: the knob fields, in canonical order (the JSON/digest field set)
 _FIELDS = (
-    "eventset_hier", "eventset_block", "pack",
     "chunk_steps", "wave_size", "lane_block",
     "table_scan", "table_block",
     "waves_per_device", "preempt_quantum", "mem_fraction",
@@ -96,18 +93,15 @@ class Schedule:
 
     Delivery is two-channel, matching how the knobs already bind:
 
-    * :meth:`stream_kwargs` — the argument knobs (``pack``,
-      ``chunk_steps``, ``wave_size``) as kwargs for
-      ``run_experiment_stream`` / ``Request`` / ``run_sweep``;
-    * :meth:`scope` — the trace-time knobs (event-set layout, the
-      ambient pack default, the kernel lane block) as a context
-      manager over the ``cimba_tpu.config`` tri-states, restoring the
-      previous state on exit (the ``_dispatch_arm`` idiom).
+    * :meth:`stream_kwargs` — the argument knobs (``chunk_steps``,
+      ``wave_size``) as kwargs for ``run_experiment_stream`` /
+      ``Request`` / ``run_sweep``;
+    * :meth:`scope` — the trace-time knobs (table-scan layout, the
+      kernel lane block) as a context manager over the
+      ``cimba_tpu.config`` tri-states, restoring the previous state on
+      exit.
     """
 
-    eventset_hier: Optional[bool] = None
-    eventset_block: Optional[int] = None
-    pack: Optional[bool] = None
     chunk_steps: Optional[int] = None
     wave_size: Optional[int] = None
     lane_block: Optional[int] = None
@@ -151,8 +145,6 @@ class Schedule:
         """The argument-knob subset: kwargs every stream-shaped entry
         point accepts (only knobs this schedule binds appear)."""
         out = {}
-        if self.pack is not None:
-            out["pack"] = bool(self.pack)
         if self.chunk_steps is not None:
             out["chunk_steps"] = int(self.chunk_steps)
         if self.wave_size is not None:
@@ -162,9 +154,8 @@ class Schedule:
     @contextlib.contextmanager
     def scope(self):
         """Bind the trace-time knobs for the duration: the
-        ``config.EVENTSET_HIER`` / ``EVENTSET_BLOCK`` / ``XLA_PACK`` /
-        ``TABLE_SCAN`` / ``TABLE_SCAN_BLOCK`` tri-states (set only for
-        the fields this schedule carries)
+        ``config.TABLE_SCAN`` / ``TABLE_SCAN_BLOCK`` tri-states (set
+        only for the fields this schedule carries)
         plus ``CIMBA_KERNEL_LANE_BLOCK`` for the kernel grid.  Restores
         the previous state on exit.  Like the dtype profile, these bind
         at TRACE time: programs already compiled keep their layout, and
@@ -175,21 +166,13 @@ class Schedule:
 
         from cimba_tpu import config
 
-        prev = (config.EVENTSET_HIER, config.EVENTSET_BLOCK,
-                config.XLA_PACK, config.TABLE_SCAN,
-                config.TABLE_SCAN_BLOCK)
+        prev = (config.TABLE_SCAN, config.TABLE_SCAN_BLOCK)
         # the lane-block knob has no config tri-state — its documented
         # binding point IS the env var (core/pallas_run.py reads it via
         # env_raw), so this scope writes/restores the var itself; the
         # suppressions below mark the one sanctioned non-env_raw site
         prev_lane = os.environ.get("CIMBA_KERNEL_LANE_BLOCK")  # cimba: noqa(CHK005) — save/restore, not a knob read
         try:
-            if self.eventset_hier is not None:
-                config.EVENTSET_HIER = bool(self.eventset_hier)
-            if self.eventset_block is not None:
-                config.EVENTSET_BLOCK = int(self.eventset_block)
-            if self.pack is not None:
-                config.XLA_PACK = bool(self.pack)
             if self.table_scan is not None:
                 config.TABLE_SCAN = bool(self.table_scan)
             if self.table_block is not None:
@@ -200,9 +183,7 @@ class Schedule:
                 )
             yield self
         finally:
-            (config.EVENTSET_HIER, config.EVENTSET_BLOCK,
-             config.XLA_PACK, config.TABLE_SCAN,
-             config.TABLE_SCAN_BLOCK) = prev
+            config.TABLE_SCAN, config.TABLE_SCAN_BLOCK = prev
             if self.lane_block is not None:
                 if prev_lane is None:
                     os.environ.pop("CIMBA_KERNEL_LANE_BLOCK", None)
@@ -217,14 +198,8 @@ class Schedule:
         (prune, don't measure — docs/21_autotune.md).  Rules:
 
         * a knob explicitly set to what the ambient default already
-          resolves to (hier=True under the default-on env, pack
-          matching this backend's auto, the default block size, the
-          entry points' ``chunk_steps=1024``) is the default arm;
-        * ``eventset_block`` is dead when the hierarchy resolves off;
-        * the PR 2 inertness contract: the hierarchy is structurally
-          inert unless ``event_cap`` is a >= 2x multiple of the block
-          size — below that, both event-set knobs are dead for this
-          ``spec``;
+          resolves to (the default block size, the entry points'
+          ``chunk_steps=1024``) is the default arm;
         * ``table_block`` is dead when the table scan resolves off,
           and both table-scan knobs are dead when no dyn-accessed
           table axis of ``spec`` exceeds the effective block (the
@@ -233,38 +208,9 @@ class Schedule:
         """
         from cimba_tpu import config
 
-        hier, block = self.eventset_hier, self.eventset_block
-        pack, chunk = self.pack, self.chunk_steps
-        if pack is not None and bool(pack) == config.xla_pack_enabled():
-            pack = None
+        chunk = self.chunk_steps
         if chunk is not None and int(chunk) == 1024:
             chunk = None
-        if hier is not None and (
-            bool(hier) == config.eventset_hier_enabled()
-        ):
-            hier = None
-        if block is not None and (
-            int(block) == config.eventset_block()
-        ):
-            block = None
-        eff_hier = (
-            bool(hier) if hier is not None
-            else config.eventset_hier_enabled()
-        )
-        if not eff_hier:
-            block = None
-        if spec is not None:
-            cap = int(getattr(spec, "event_cap", 0) or 0)
-            eff_block = (
-                int(block) if block is not None
-                else config.eventset_block()
-            )
-            # the hierarchy only materializes summary rows when the
-            # cap holds at least two full blocks (core/eventset.py) —
-            # below that every hier/block setting traces the flat
-            # program
-            if cap < 2 * eff_block:
-                hier, block = None, None
         tscan, tblock = self.table_scan, self.table_block
         if tscan is not None and (
             bool(tscan) == config.table_scan_enabled()
@@ -323,8 +269,7 @@ class Schedule:
         elif fmax is not None and int(fmax) == DEFAULT_FUSE_MAX_SPECS:
             fmax = None
         return dataclasses.replace(
-            self, eventset_hier=hier, eventset_block=block,
-            pack=pack, chunk_steps=chunk, table_scan=tscan,
+            self, chunk_steps=chunk, table_scan=tscan,
             table_block=tblock, waves_per_device=wpd,
             preempt_quantum=quantum, mem_fraction=memf,
             fuse=fuse, fuse_max_specs=fmax,
@@ -349,8 +294,7 @@ class Schedule:
         for f in _FIELDS:
             v = doc.get(f)
             if v is not None:
-                if f in ("eventset_hier", "pack", "table_scan",
-                         "fuse"):
+                if f in ("table_scan", "fuse"):
                     v = bool(v)
                 elif f == "mem_fraction":
                     v = float(v)
@@ -383,9 +327,6 @@ class ScheduleSpace:
     knob" — every space implicitly contains the all-default schedule
     even when no axis lists ``None``."""
 
-    eventset_hier: Tuple = ()
-    eventset_block: Tuple = ()
-    pack: Tuple = ()
     chunk_steps: Tuple = ()
     wave_size: Tuple = ()
     lane_block: Tuple = ()
@@ -445,9 +386,8 @@ def default_space(
     fuse: bool = False,
 ) -> ScheduleSpace:
     """The stock search space over the dispatch knobs of
-    docs/11_dispatch_cost.md: hierarchical event-set on/off with a
-    pow2 block grid, packed carry on/off, and a small ``chunk_steps``
-    grid around the entry points' default.  ``wave_size`` is not
+    docs/11_dispatch_cost.md: a small ``chunk_steps`` grid around the
+    entry points' default.  ``wave_size`` is not
     searched by default (its pooled summary is merge-order-sensitive —
     opt in explicitly when counts-exact statistics are what you
     serve); ``lane_block`` joins only with ``kernel=True`` (the Pallas
@@ -465,9 +405,6 @@ def default_space(
     that are structurally inert for ``spec`` cost nothing:
     :meth:`ScheduleSpace.candidates` collapses them."""
     space = ScheduleSpace(
-        eventset_hier=(True, False),
-        eventset_block=(64, 128, 256),
-        pack=(True, False),
         chunk_steps=(256, 1024, 4096),
         table_scan=(True, False),
         table_block=(64, 128, 256),

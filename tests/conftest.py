@@ -8,6 +8,8 @@ jax initializes its backends, hence before any cimba_tpu import.
 
 import os
 
+import pytest
+
 if os.environ.get("CIMBA_ON_DEVICE") != "1":  # see test_kernel_fuzz.py
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 flags = os.environ.get("XLA_FLAGS", "")
@@ -23,3 +25,17 @@ import cimba_tpu  # noqa: E402, F401  (enables x64)
 
 def pytest_report_header(config):
     return f"jax {jax.__version__} devices={jax.device_count()} backend={jax.default_backend()}"
+
+
+@pytest.fixture
+def carry_layout(monkeypatch):
+    """``carry_layout(packed)`` sets the while-loop carry layout the next
+    ``make_run`` / ``make_chunk`` builds: the backend rule
+    (``core.loop._packed_carry``) overridden, so both layouts run here
+    whatever the backend."""
+    from cimba_tpu.core import loop
+
+    def set_layout(packed):
+        monkeypatch.setattr(loop, "_packed_carry", lambda: bool(packed))
+
+    return set_layout

@@ -33,4 +33,4 @@ def non_cimba_is_fine():
 def proxied_is_fine():
     from cimba_tpu import config
 
-    return config.env_raw("CIMBA_XLA_PACK")
+    return config.env_raw("CIMBA_TABLE_SCAN")

@@ -52,13 +52,12 @@ def test_program_matches_the_reference(seed):
     np.testing.assert_allclose(prog.sd, want.sd, rtol=1e-6)
 
 
-def _per_event_and_chunked(n_targets, lanes=8, horizon=2.0, max_steps=64,
-                           pack=None):
+def _per_event_and_chunked(n_targets, lanes=8, horizon=2.0, max_steps=64):
     spec, _ = awacs.build(n_targets, dwell=DWELL)
     sims = jax.jit(jax.vmap(
         lambda r: cl.init_sim(spec, 5, r, awacs.params(horizon))
     ))(jnp.arange(lanes))
-    per_event = jax.jit(jax.vmap(cl.make_run(spec, pack=pack)))(sims)
+    per_event = jax.jit(jax.vmap(cl.make_run(spec)))(sims)
     chunk = jax.jit(cl.make_chunk(spec, max_steps=max_steps))
     total = np.zeros(len(cl.BOUNDARY_COUNTERS), np.int64)
     while True:
@@ -69,8 +68,9 @@ def _per_event_and_chunked(n_targets, lanes=8, horizon=2.0, max_steps=64,
 
 
 @pytest.mark.parametrize("pack", [False, True])
-def test_boundary_phase_is_bitwise_the_per_event_path(pack):
-    per_event, chunked, _ = _per_event_and_chunked(16, pack=pack)
+def test_boundary_phase_is_bitwise_the_per_event_path(pack, carry_layout):
+    carry_layout(pack)  # the per-event path's layout
+    per_event, chunked, _ = _per_event_and_chunked(16)
     assert int(jnp.sum(chunked.user["dwells"])) > 8 * 40
     a, b = jax.tree.leaves(per_event), jax.tree.leaves(chunked)
     assert len(a) == len(b)
@@ -163,23 +163,21 @@ def test_batched_scorer_matches_the_forward_pass():
                                    rtol=1e-6, atol=1e-6)
 
 
-def test_boundary_chunk_runs_the_per_leaf_loop(monkeypatch):
-    """A boundary model's chunk carries its Sim per leaf whatever ``pack``
-    says, the backend's default included (an accelerator packs)."""
-    monkeypatch.setattr(config, "xla_pack_enabled", lambda: True)
+def test_boundary_chunk_runs_the_per_leaf_loop(carry_layout):
+    """A boundary model's chunk carries its Sim per leaf whatever the
+    backend's carry rule says (an accelerator packs)."""
 
-    def chunk_text(n_targets, pack):
+    def chunk_text(n_targets, packed):
+        carry_layout(packed)
         spec, _ = awacs.build(n_targets, dwell=DWELL)
         sims = jax.eval_shape(jax.vmap(
             lambda r: cl.init_sim(spec, 1, r, awacs.params(1.0))
         ), jnp.arange(2))
-        return str(jax.make_jaxpr(
-            cl.make_chunk(spec, pack=pack, max_steps=8))(sims))
+        return str(jax.make_jaxpr(cl.make_chunk(spec, max_steps=8))(sims))
 
     with config.profile("f32"):
         for n in (8, 1000):
-            plain = chunk_text(n, False)
-            assert chunk_text(n, None) == chunk_text(n, True) == plain
+            assert chunk_text(n, True) == chunk_text(n, False)
 
 
 def test_dwell_is_a_build_parameter():
@@ -195,8 +193,17 @@ def test_dwell_is_a_build_parameter():
 # summary path): init and fold as before the boundary phase existed, the
 # chunk programs as the batched step's merge-free dispatch traces them
 # (their lanes pinned bitwise the lax.switch form's by
-# tests/test_batched_dispatch.py)
+# tests/test_batched_dispatch.py); 'chunk' the per-leaf carry (the CPU's),
+# 'chunk_packed' the packed one (an accelerator's).  AWACS (8 targets,
+# the same lanes, chunk steps and horizon): its boundary chunk, per leaf
+# on every backend, as it was when the carry layout was still an option.
 PARENT_SHA256 = {
+    ('awacs', 'f32', 'chunk'): (
+        "ad47541079da8e345f4635d4bdcbb213c77e6bde654a8884d2d5683d6eb37f16"
+    ),
+    ('awacs', 'f64', 'chunk'): (
+        "bfa1441bd980b8f8670940e612d59ce6b0101291818f8c2f46a4f5be7eba0791"
+    ),
     ('mg1', 'f32', 'chunk'): (
         "6bbc6ef46d65be06aa5ddb9ca5d3e3228bcd56cabf01858b60dbeb94d825be6d"
     ),
@@ -249,33 +256,72 @@ PARENT_SHA256 = {
 
 
 def _programs(name, profile, pack):
+    """The programs' jaxpr texts; ``pack`` names the chunk's layout,
+    which the caller has set."""
     with config.profile(profile):
         if name == "mm1":
             spec, params = mm1.build(queue_cap=128, record=False)[0], mm1.params(10)
-        else:
+        elif name == "mg1":
             spec, params = mg1.build(queue_cap=512)[0], (1.25, 1.0, 0.5, 10)
+        else:
+            spec, params = awacs.build(8, dwell=DWELL)[0], awacs.params(50.0)
         cache = serve.ProgramCache(store=False)
         init_j, chunk_j = pc.get_programs(
-            cache, spec, mesh=None, pack=pack, chunk_steps=16,
-            with_metrics=False)
+            cache, spec, mesh=None, chunk_steps=16, with_metrics=False)
         lanes = 8
         args = (jnp.arange(lanes), ex._seed_column(1, lanes),
                 ex._horizon_column(50.0, lanes),
                 ex._slice_params(params, lanes, 0, lanes))
         sims = jax.eval_shape(init_j, *args)
-        fold_j = pc.get_fold(cache, False, ex.default_summary_path)
-        return {
+        out = {
             "init": str(jax.make_jaxpr(init_j)(*args)),
             "chunk_packed" if pack else "chunk":
                 str(jax.make_jaxpr(chunk_j)(sims)),
-            "fold": str(jax.make_jaxpr(fold_j)(pc.stream_acc(spec, False), sims)),
         }
+        if name != "awacs":  # AWACS folds another statistic
+            fold_j = pc.get_fold(cache, False, ex.default_summary_path)
+            out["fold"] = str(
+                jax.make_jaxpr(fold_j)(pc.stream_acc(spec, False), sims))
+        return out
 
 
 @pytest.mark.parametrize("pack", [True, False])
 @pytest.mark.parametrize("profile", ["f32", "f64"])
 @pytest.mark.parametrize("name", ["mm1", "mg1"])
-def test_programs_without_boundary_blocks_are_unchanged(name, profile, pack):
+def test_programs_without_boundary_blocks_are_unchanged(
+    name, profile, pack, carry_layout,
+):
+    carry_layout(pack)
     for kind, text in _programs(name, profile, pack).items():
         digest = hashlib.sha256(text.encode()).hexdigest()
         assert digest == PARENT_SHA256[(name, profile, kind)], kind
+
+
+def _digest(text):
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("profile", ["f32", "f64"])
+def test_awacs_chunk_program_is_unchanged(profile):
+    text = _programs("awacs", profile, False)["chunk"]
+    assert _digest(text) == PARENT_SHA256[("awacs", profile, "chunk")]
+
+
+@pytest.mark.parametrize("backend,kind", [("cpu", "chunk"),
+                                          ("tpu", "chunk_packed")])
+def test_backend_picks_the_carry(backend, kind, monkeypatch):
+    """The carry rule reads the backend: the CPU's mm1 chunk is the
+    per-leaf program, the chip's the packed one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with config.profile("f32"):
+        spec = mm1.build(queue_cap=128, record=False)[0]
+        init_j, chunk_j = pc.get_programs(
+            serve.ProgramCache(store=False), spec, mesh=None,
+            chunk_steps=16, with_metrics=False)
+        lanes = 8
+        sims = jax.eval_shape(
+            init_j, jnp.arange(lanes), ex._seed_column(1, lanes),
+            ex._horizon_column(50.0, lanes),
+            ex._slice_params(mm1.params(10), lanes, 0, lanes))
+        text = str(jax.make_jaxpr(chunk_j)(sims))
+    assert _digest(text) == PARENT_SHA256[("mm1", "f32", kind)]

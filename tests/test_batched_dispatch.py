@@ -48,16 +48,18 @@ def _drive(chunk, sims):
     [("mm1", False), ("mm1", True), ("mg1", False), ("mg1", True),
      ("awacs", None)],
 )
-def test_chunk_is_bitwise_the_switch_form(name, pack, profile):
+def test_chunk_is_bitwise_the_switch_form(name, pack, profile,
+                                          carry_layout):
     """``make_chunk`` driven to the end against ``jax.vmap`` of the
-    unbatched ``make_run``, every Sim leaf equal."""
+    unbatched ``make_run``, every Sim leaf equal, under each carry
+    layout (``None``: the backend's)."""
+    if pack is not None:
+        carry_layout(pack)
     with config.profile(profile):
         spec, params = _model(name)
         sims = jax.jit(lambda: _sims(spec, params, 8))()
-        want = jax.jit(jax.vmap(cl.make_run(spec, pack=pack)))(sims)
-        got = _drive(
-            jax.jit(cl.make_chunk(spec, pack=pack, max_steps=32)), sims
-        )
+        want = jax.jit(jax.vmap(cl.make_run(spec)))(sims)
+        got = _drive(jax.jit(cl.make_chunk(spec, max_steps=32)), sims)
     assert int(jnp.sum(got.n_events)) > 8 * 40
     assert int(jnp.sum(got.err != 0)) == 0
     a, b = jax.tree.leaves(want), jax.tree.leaves(got)

@@ -16,7 +16,7 @@ Contracts pinned here:
   the registry without failing here.
 * **the registry sweep holds**: off == baseline jaxpr identity for
   every registered gate under BOTH dtype profiles (this sweep replaces
-  the retired per-gate pins of test_trace/test_xla_pack/test_audit;
+  the retired per-gate pins of test_trace/test_audit;
   one sentinel each remains there).
 * **program lints**: donation/purity/weak-type clean on the shipped
   model, and each fires on a seeded-bad program.
@@ -155,18 +155,19 @@ def test_gate_registry_completeness():
     )
     # the issue's gate list is the floor, not the ceiling
     names = {g.name for g in gates.GATES}
-    assert {"trace", "metrics", "audit", "pack", "eventset_hier"} <= names
+    assert {"trace", "metrics", "audit", "table_scan", "tune"} <= names
+    assert not {"pack", "eventset_hier"} & names
 
 
 def test_env_raw_registry():
     from cimba_tpu import config
 
-    assert config.env_raw("CIMBA_EVENTSET_BLOCK") == "128"
-    os.environ["CIMBA_EVENTSET_BLOCK"] = "64"
+    assert config.env_raw("CIMBA_TABLE_SCAN_BLOCK") == "128"
+    os.environ["CIMBA_TABLE_SCAN_BLOCK"] = "64"
     try:
-        assert config.env_raw("CIMBA_EVENTSET_BLOCK") == "64"
+        assert config.env_raw("CIMBA_TABLE_SCAN_BLOCK") == "64"
     finally:
-        del os.environ["CIMBA_EVENTSET_BLOCK"]
+        del os.environ["CIMBA_TABLE_SCAN_BLOCK"]
     with pytest.raises(KeyError, match="not a registered"):
         config.env_raw("CIMBA_NOT_A_KNOB")
 
@@ -186,10 +187,7 @@ def test_gate_sweep_off_is_baseline_both_profiles():
     for g in gates.GATES:
         for profile in gates.PROFILES:
             ran = report[f"{g.name}/{profile}"]
-            assert (
-                "off==baseline" in ran
-                or "on==baseline(default-on backend)" in ran
-            ), (g.name, profile, ran)
+            assert "off==baseline" in ran, (g.name, profile, ran)
 
 
 def test_gate_sweep_catches_a_lying_gate():

@@ -541,7 +541,7 @@ def test_schedule_knobs_roundtrip_resolve_and_adoption(shared_cache):
     from cimba_tpu.tune import registry as reg
     from cimba_tpu.tune import space
 
-    assert space.SCHEDULE_FORMAT == 4
+    assert space.SCHEDULE_FORMAT == 5
     s = space.Schedule(
         waves_per_device=4, preempt_quantum=16, mem_fraction=0.5,
     )
@@ -599,19 +599,19 @@ def test_wave_footprint_ladder_and_store_manifest(tiny, tmp_path):
     spec = tiny
     programs: dict = {}
     fp = pc.wave_footprint_bytes(
-        programs, spec, mesh=None, pack=None, chunk_steps=4,
+        programs, spec, mesh=None, chunk_steps=4,
         with_metrics=False, lanes=8, params=(), n_replications=8,
     )
     assert isinstance(fp, int) and fp > 0
     n_keys = len(programs)
     fp2 = pc.wave_footprint_bytes(
-        programs, spec, mesh=None, pack=None, chunk_steps=4,
+        programs, spec, mesh=None, chunk_steps=4,
         with_metrics=False, lanes=8, params=(), n_replications=8,
     )
     assert fp2 == fp and len(programs) == n_keys   # memoized
     # a wider wave can only cost more
     fp_wide = pc.wave_footprint_bytes(
-        programs, spec, mesh=None, pack=None, chunk_steps=4,
+        programs, spec, mesh=None, chunk_steps=4,
         with_metrics=False, lanes=64, params=(), n_replications=64,
     )
     assert fp_wide > fp
@@ -643,7 +643,7 @@ def test_wave_footprint_ladder_and_store_manifest(tiny, tmp_path):
         # ... and the executable's devices, for execution_devices at load
         assert rec["devices"] == [jax.devices()[0].id], rec
     hyd = store.hydrate(
-        spec, pack=None, chunk_steps=4, with_metrics=False,
+        spec, chunk_steps=4, with_metrics=False,
     )
     assert hyd is not None
     # the hydrated chunk program carries the measured table under the

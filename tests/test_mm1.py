@@ -188,17 +188,16 @@ def test_agrees_with_queueing_theory():
 @pytest.mark.slow  # heavyweight: over the timed tier-1 budget; runs in tools/ci.sh cells
 def test_f32_profile_agrees_with_theory_and_f64():
     """The f32 profile — the accelerator-bench and kernel-path width
-    (``config.profile('f32')``; bench.py runs the battery under it,
-    BENCH_NOTES round 5) — is statistically valid on the XLA path: the
-    pooled sojourn mean lands on Pollaczek-Khinchine, and most
-    replications track their f64 exact twin to f32-accumulation
-    precision.  "Most", not all: when two event times land within f32
-    epsilon their order can flip relative to f64, and because fused
-    cycles pre-draw the next duration, a flip remaps those draws to
-    different objects — a statistically exchangeable (equally valid)
-    but numerically different sample path.  Measured here: 13/16 reps
-    agree to ~1e-5 relative; the flipped reps stay healthy and remain
-    unbiased draws of the same queue."""
+    (``config.profile('f32')``; benchmark/run.py's cells run under it)
+    — is statistically valid on the XLA path: the pooled sojourn mean
+    lands on Pollaczek-Khinchine, and most replications track their f64
+    exact twin to f32-accumulation precision.  "Most", not all: when two
+    event times land within f32 epsilon their order can flip relative to
+    f64, and because fused cycles pre-draw the next duration, a flip
+    remaps those draws to different objects — a statistically
+    exchangeable (equally valid) but numerically different sample path.
+    Measured here: 13/16 reps agree to ~1e-5 relative; the flipped reps
+    stay healthy and remain unbiased draws of the same queue."""
     from cimba_tpu import config
 
     reps, n_objects = 16, 1500

@@ -74,7 +74,8 @@ def test_awacs_positions_stay_in_arena_neighborhood():
 
 def test_awacs_nn_scores_pallas_matches_jnp():
     """The NN physics hook: the Pallas kernel (interpret mode here — the
-    Mosaic-compiled path runs on real TPU via bench.py --config awacs) and
+    Mosaic-compiled path runs on real TPU in benchmark/run.py's awacs.radar
+    cell) and
     the plain-jnp trace are the same matmul stack; results must agree to
     f32 roundoff."""
     rng = np.random.default_rng(7)

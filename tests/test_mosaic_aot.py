@@ -304,7 +304,7 @@ def test_random_blocks_compile(one_chip):
             assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_xla_chunk_ring_tiles_eight_lanes(one_chip):
+def test_xla_chunk_ring_tiles_eight_lanes(one_chip, carry_layout):
     """The XLA chunk program (the stream runner's, packed carry) keeps
     mm1's FIFO ring rank-1 per lane: vmapped, it is ``[lanes, 128]`` in
     dense ``(8,128)`` tiles.  A ``[lanes, 1, 128]`` ring tiles ``(1,128)``
@@ -317,10 +317,11 @@ def test_xla_chunk_ring_tiles_eight_lanes(one_chip):
     from cimba_tpu.serve import cache as pc
 
     L = 1024
+    carry_layout(True)  # the chip's layout
     with config.profile("f32"):
         spec, args = _mm1()
         init_j, chunk_j = pc.get_programs(
-            serve.ProgramCache(store=False), spec, mesh=None, pack=True,
+            serve.ProgramCache(store=False), spec, mesh=None,
             chunk_steps=1024, with_metrics=False)
         sims = jax.eval_shape(
             init_j, jnp.arange(L), ex._seed_column(1, L), None,

@@ -5,7 +5,8 @@ kernel path must be *bit-identical* to the plain-XLA f32 interpreter path —
 it runs the same make_step dispatcher, so any divergence is a bug in the
 kernel plumbing (lane layout, const hoisting, masking), never a tolerance.
 
-The Mosaic-compiled TPU path is exercised by bench.py on real hardware.
+The Mosaic-compiled TPU path is compiled offline by tests/test_mosaic_aot.py
+and run on the chip by chip_smoke.py.
 """
 
 import jax

@@ -127,9 +127,9 @@ def test_reconstructed_spec_hydrates_store_hit(saved):
     rebuilt = _tiny_spec()          # fresh function objects
     twin = dataclasses.replace(rebuilt)  # same-value twin
     assert ps.store_key(
-        rebuilt, False, mesh=None, pack=None, chunk_steps=CHUNK,
+        rebuilt, False, mesh=None, chunk_steps=CHUNK,
     ) == ps.store_key(
-        twin, False, mesh=None, pack=None, chunk_steps=CHUNK,
+        twin, False, mesh=None, chunk_steps=CHUNK,
     )
     h0 = st.stats()["hits"]
     for spec in (rebuilt, twin):
@@ -212,9 +212,9 @@ def test_fingerprint_drift_misses(saved):
     st, _ = saved
     other = _tiny_spec(t_stop=3.0)
     assert ps.store_key(
-        other, False, mesh=None, pack=None, chunk_steps=CHUNK,
+        other, False, mesh=None, chunk_steps=CHUNK,
     ) != ps.store_key(
-        _tiny_spec(), False, mesh=None, pack=None, chunk_steps=CHUNK,
+        _tiny_spec(), False, mesh=None, chunk_steps=CHUNK,
     )
     m0 = st.stats()["misses"]
     assert st.hydrate(other, chunk_steps=CHUNK) is None
@@ -242,9 +242,9 @@ def test_shared_callable_multiplicity_distinguishes_keys(saved):
                                                   _handler_b,
                                                   _handler_b])
     assert ps.store_key(
-        s1, False, mesh=None, pack=None, chunk_steps=CHUNK,
+        s1, False, mesh=None, chunk_steps=CHUNK,
     ) != ps.store_key(
-        s2, False, mesh=None, pack=None, chunk_steps=CHUNK,
+        s2, False, mesh=None, chunk_steps=CHUNK,
     )
 
 
@@ -297,7 +297,7 @@ def test_unstable_fingerprint_raises_and_misses(saved):
 
     spec = dataclasses.replace(_tiny_spec(), user_init=unstable_init)
     with pytest.raises(ps.UnstableStoreKey):
-        ps.store_key(spec, False, mesh=None, pack=None, chunk_steps=CHUNK)
+        ps.store_key(spec, False, mesh=None, chunk_steps=CHUNK)
     m0 = st.stats()["misses"]
     assert st.hydrate(spec, chunk_steps=CHUNK) is None
     assert st.stats()["misses"] == m0 + 1
@@ -367,7 +367,7 @@ def test_warm_manifest_no_execute_and_loud_miss(saved):
     assert out is st
     assert st.stats()["artifact_dispatches"] == d0  # truly no-execute
     key = pc.program_key(
-        spec, False, mesh=None, pack=None, chunk_steps=CHUNK,
+        spec, False, mesh=None, chunk_steps=CHUNK,
     )
     assert key in cache
     assert ("fold", False, _clock_path) in cache

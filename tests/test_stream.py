@@ -5,7 +5,7 @@ The contracts pinned here:
 * chunked runs (``make_run(max_steps=)`` re-dispatched by the host,
   donated carry) are TRAJECTORY-IDENTICAL to the monolithic while-loop:
   every Sim leaf bitwise equal, on mm1 and the M/G/1 sweep, both dtype
-  profiles, with and without the packed carry (``CIMBA_XLA_PACK``);
+  profiles, under both carry layouts (packed and per leaf);
 * the streamed experiment's wave fold is exactly the associative Pébay
   merge of the monolithic run's per-wave pools (bitwise vs the by-hand
   fold; counts/event totals exact vs the monolithic pool);
@@ -129,19 +129,20 @@ def test_stream_matches_monolithic_and_fold_oracle(mm1_mono):
     _assert_trees_equal(st.summary, oracle)
 
 
-def test_chunked_matches_monolithic_f32_packed():
+def test_chunked_matches_monolithic_f32_packed(carry_layout):
     """The accelerator headline arm's trace shape (f32 profile + packed
     carry through the BOUNDED while-loop) stays tier-1 on the cheap
     model; the full mm1/mg1 profile x pack batteries are the slow twins
     below (run by tools/ci.sh)."""
+    carry_layout(True)
     with config.profile("f32"):
         spec = _tiny_spec(t_stop=30.0)
         init = jax.jit(jax.vmap(lambda r: cl.init_sim(spec, 7, r, None)))
-        mono = jax.jit(jax.vmap(cl.make_run(spec, pack=True)))(
+        mono = jax.jit(jax.vmap(cl.make_run(spec)))(
             init(jnp.arange(4))
         )
         chunked = cl.make_chunked_run(
-            spec, pack=True, chunk_steps=7, poll_every=3
+            spec, chunk_steps=7, poll_every=3
         )(init(jnp.arange(4)))
         assert int(jnp.sum(mono.n_events)) > 100
         _assert_trees_equal(mono, chunked)
@@ -150,21 +151,22 @@ def test_chunked_matches_monolithic_f32_packed():
 @pytest.mark.slow  # heavyweight twin: over the timed tier-1 budget; runs in tools/ci.sh cells
 @pytest.mark.parametrize("pack", [False, True])
 @pytest.mark.parametrize("profile", ["f64", "f32"])
-def test_chunked_matches_monolithic_mm1_battery(profile, pack):
+def test_chunked_matches_monolithic_mm1_battery(profile, pack, carry_layout):
     """Every Sim leaf bitwise equal between the monolithic while-loop
     and chunked re-dispatch, across dtype profiles and carry layouts
     (chunk_steps chosen to NOT divide the run length: partial last
     chunks and mid-event-cycle boundaries are the interesting case)."""
+    carry_layout(pack)
     with config.profile(profile):
         spec, _ = mm1.build(record=True)
         init = jax.jit(
             jax.vmap(lambda r: cl.init_sim(spec, 7, r, mm1.params(50)))
         )
-        mono = jax.jit(jax.vmap(cl.make_run(spec, pack=pack)))(
+        mono = jax.jit(jax.vmap(cl.make_run(spec)))(
             init(jnp.arange(4))
         )
         chunked = cl.make_chunked_run(
-            spec, pack=pack, chunk_steps=13, poll_every=3
+            spec, chunk_steps=13, poll_every=3
         )(init(jnp.arange(4)))
         assert int(jnp.sum(mono.n_events)) > 300
         _assert_trees_equal(mono, chunked)
@@ -209,21 +211,22 @@ def test_wave_param_slicing_bitwise_mg1_sweep():
 
 @pytest.mark.slow  # heavyweight: over the timed tier-1 budget; runs in tools/ci.sh cells
 @pytest.mark.parametrize("pack", [False, True])
-def test_stream_and_chunked_mg1_sweep_match_monolithic(pack):
+def test_stream_and_chunked_mg1_sweep_match_monolithic(pack, carry_layout):
     """The sweep end to end (ragged final wave included): chunked sims
     bitwise the monolithic ones; streamed totals exact and pooled
     moments at merge-order rounding."""
+    carry_layout(pack)
     spec, _ = mg1.build()
     params, cells = mg1.sweep_params(30, reps_per_cell=1)
     R = len(cells)
-    res = ex.run_experiment(spec, params, R, seed=9, pack=pack)
+    res = ex.run_experiment(spec, params, R, seed=9)
     chunked = ex.run_experiment_chunked(
-        spec, params, R, seed=9, pack=pack, chunk_steps=41
+        spec, params, R, seed=9, chunk_steps=41
     )
     _assert_trees_equal(res.sims, chunked.sims)
 
     st = ex.run_experiment_stream(
-        spec, params, R, wave_size=8, chunk_steps=41, seed=9, pack=pack
+        spec, params, R, wave_size=8, chunk_steps=41, seed=9
     )
     assert st.n_waves == 3  # 8 + 8 + 4: the ragged last wave
     assert int(st.total_events) == int(res.total_events)
@@ -237,17 +240,20 @@ def test_stream_and_chunked_mg1_sweep_match_monolithic(pack):
 @pytest.mark.slow  # heavyweight twin: over the timed tier-1 budget; runs in tools/ci.sh cells
 @pytest.mark.parametrize("pack", [False, True])
 @pytest.mark.parametrize("profile", ["f64", "f32"])
-def test_chunked_matches_monolithic_mg1_sweep_bitwise(profile, pack):
+def test_chunked_matches_monolithic_mg1_sweep_bitwise(
+    profile, pack, carry_layout,
+):
     """The full acceptance battery on the second model class: every Sim
     leaf of the chunked M/G/1 sweep bitwise the monolithic run's, both
     profiles, both carry layouts."""
+    carry_layout(pack)
     with config.profile(profile):
         spec, _ = mg1.build()
         params, cells = mg1.sweep_params(60, reps_per_cell=2)
         R = len(cells)
-        res = ex.run_experiment(spec, params, R, seed=5, pack=pack)
+        res = ex.run_experiment(spec, params, R, seed=5)
         chunked = ex.run_experiment_chunked(
-            spec, params, R, seed=5, pack=pack, chunk_steps=97
+            spec, params, R, seed=5, chunk_steps=97
         )
         assert int(res.n_failed) == 0
         _assert_trees_equal(res.sims, chunked.sims)

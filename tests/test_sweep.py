@@ -463,19 +463,18 @@ def test_mg1_adaptive_spends_fewer_reps_than_fixed():
     """The statistical-efficiency claim at model scale: adaptive-R
     meets a relative halfwidth target in every cell of a CV-spread
     M/G/1 grid with >= 30% fewer total replications than fixed-R sized
-    for the worst cell (the bench.py --config sweep acceptance)."""
+    for the worst cell."""
     spec, _ = mg1.build()
     grid = mg1.sweep_grid(400, cvs=(0.25, 2.0), utilizations=(0.5, 0.9))
     cache = pc.ProgramCache()
     # round size 4 with min_reps=4: the easy low-CV cells can stop at
-    # one round while the heavy-tail cells accumulate — a finer round
-    # granularity than the bench's (savings are granularity-limited:
-    # every cell pays at least min_reps and whole rounds)
+    # one round while the heavy-tail cells accumulate (savings are
+    # granularity-limited: every cell pays at least min_reps and whole
+    # rounds)
     rule = sweep.HalfwidthTarget(target=0.05, relative=True, min_reps=4)
     # redistribute=False: the worst cell's total is then its demand at
     # round granularity, not inflated by a final oversized freed-lanes
-    # round — the honest fixed-R comparator (same rationale as
-    # bench.py --config sweep)
+    # round — the honest fixed-R comparator
     res = sweep.run_sweep(
         spec, grid, reps_per_cell=4, stop=rule, max_rounds=24, seed=5,
         cell_wave=4, max_wave=128, chunk_steps=1024,

@@ -47,20 +47,21 @@ R = 8
 
 @pytest.fixture(scope="module")
 def probe_spec():
-    """A tiny probe twin: cap 8 (below every hierarchy threshold, so
-    the event-set axes canonicalize away), ~8 resumes per lane at
-    t_end=4.0 — cheap compiles, real trajectories, a recorded ``wait``
-    summary for the default summary_path."""
+    """A tiny probe twin: cap 8, one process (every table below the
+    scan block, so the table-scan axes canonicalize away), ~8 resumes
+    per lane at t_end=4.0 — cheap compiles, real trajectories, a
+    recorded ``wait`` summary for the default summary_path."""
     spec, _ = tprobe.build(event_cap=8, per_resume=1, hold=0.5)
     return spec
 
 
 @pytest.fixture(scope="module")
-def big_probe_spec():
-    """The real mutation-bursty probe shape (cap 2048): the event-set
-    hierarchy is structurally LIVE here, so its axes survive
-    canonicalization."""
-    spec, _ = tprobe.build()
+def tall_spec():
+    """A model with 201-row process tables: taller than a 64-row scan
+    block, so that table-scan setting survives canonicalization."""
+    from cimba_tpu.models import awacs
+
+    spec, _ = awacs.build(200)
     return spec
 
 
@@ -108,58 +109,52 @@ def test_tune_knob_registered_and_gated():
 
 
 def test_schedule_roundtrip_digest_label():
-    s = Schedule(pack=True, chunk_steps=256, eventset_hier=False)
+    s = Schedule(table_scan=True, chunk_steps=256, table_block=64)
     assert Schedule.from_json(s.to_json()) == s
     assert s.digest() == Schedule.from_json(s.to_json()).digest()
-    assert s.label() == "chunk_steps=256,eventset_hier=False,pack=True"
+    assert s.label() == "chunk_steps=256,table_block=64,table_scan=True"
     assert Schedule().label() == "default"
     with pytest.raises(ValueError):
         Schedule.from_json({"format": 999})
 
 
-def test_candidates_prune_inert_knobs(probe_spec, big_probe_spec):
+def test_candidates_prune_inert_knobs(probe_spec, tall_spec):
     space = ScheduleSpace(
-        eventset_hier=(True, False), eventset_block=(64, 256),
-        pack=(True, False), chunk_steps=(256,),
+        table_scan=(True, False), table_block=(64, 256),
+        chunk_steps=(256,),
     )
     small = space.candidates(probe_spec)
-    big = space.candidates(big_probe_spec)
-    # cap 8 < 2*64: every event-set setting traces the flat program —
-    # the whole hier x block sub-grid collapses (prune, don't measure)
+    big = space.candidates(tall_spec)
+    # every table of the probe fits one block: every table-scan setting
+    # traces the dense program — the whole scan x block sub-grid
+    # collapses (prune, don't measure)
     assert all(
-        c.eventset_hier is None and c.eventset_block is None
-        for c in small
+        c.table_scan is None and c.table_block is None for c in small
     )
+    # 201 rows: the 64-row and the default 128-row blocks engage, the
+    # 256-row one does not
+    assert {(c.table_scan, c.table_block) for c in big} == {
+        (None, None), (True, None), (True, 64),
+    }
     assert len(big) > len(small)
     for cands in (small, big):
         assert cands[0].is_default()
         keys = [tuple(sorted(c.knobs().items())) for c in cands]
         assert len(keys) == len(set(keys)), "duplicate candidates"
-    # ambient-default-equal values are the default arm: hier=True under
-    # the default-on env, chunk_steps=1024, the backend-auto pack
-    assert Schedule(eventset_hier=True).canonical(
-        big_probe_spec
-    ).is_default()
+    # ambient-default-equal values are the default arm: the scan off
+    # (the default-off env), chunk_steps=1024
+    assert Schedule(table_scan=False).canonical(tall_spec).is_default()
     assert Schedule(chunk_steps=1024).canonical().is_default()
-    assert Schedule(
-        pack=config.xla_pack_enabled()
-    ).canonical().is_default()
-    # block is a dead knob when the hierarchy is off
-    c = Schedule(eventset_hier=False, eventset_block=64).canonical(
-        big_probe_spec
-    )
-    assert c.eventset_block is None and c.eventset_hier is False
+    # block is a dead knob when the scan is off
+    assert Schedule(table_block=64).canonical(tall_spec).is_default()
 
 
 def test_schedule_scope_binds_and_restores():
-    prev = (config.EVENTSET_HIER, config.EVENTSET_BLOCK, config.XLA_PACK)
-    with Schedule(eventset_hier=False, eventset_block=64,
-                  pack=True).scope():
-        assert config.EVENTSET_HIER is False
-        assert config.EVENTSET_BLOCK == 64
-        assert config.XLA_PACK is True
-    assert (config.EVENTSET_HIER, config.EVENTSET_BLOCK,
-            config.XLA_PACK) == prev
+    prev = (config.TABLE_SCAN, config.TABLE_SCAN_BLOCK)
+    with Schedule(table_scan=True, table_block=64).scope():
+        assert config.TABLE_SCAN is True
+        assert config.TABLE_SCAN_BLOCK == 64
+    assert (config.TABLE_SCAN, config.TABLE_SCAN_BLOCK) == prev
 
 
 # ---------------------------------------------------------------------------
@@ -230,7 +225,7 @@ def test_search_pins_arms_bitwise_and_writes_report(
     rep = tune.search_schedule(
         probe_spec, None, R, t_end=T_END, seed=7, repeats=2,
         candidates=[
-            Schedule(), Schedule(pack=True), Schedule(chunk_steps=8),
+            Schedule(), Schedule(chunk_steps=64), Schedule(chunk_steps=8),
             # wave-geometry arm: pinned against a default-knob twin at
             # ITS OWN wave size (merge order follows the partition)
             Schedule(wave_size=4),
@@ -239,14 +234,17 @@ def test_search_pins_arms_bitwise_and_writes_report(
     )
     by_name = {row["name"]: row for row in rep.arms}
     assert set(by_name) == {
-        "default", "pack=True", "chunk_steps=8", "wave_size=4",
+        "default", "chunk_steps=64", "chunk_steps=8", "wave_size=4",
     }
     for row in rep.arms:
         assert row["status"] == "ok", row
         assert row["pinned"] is True, row
         assert row["events"] == by_name["default"]["events"]
     # same-geometry arms reproduce the default digest EXACTLY
-    assert by_name["pack=True"]["digest"] == by_name["default"]["digest"]
+    assert (
+        by_name["chunk_steps=64"]["digest"]
+        == by_name["default"]["digest"]
+    )
     assert (
         by_name["chunk_steps=8"]["digest"]
         == by_name["default"]["digest"]

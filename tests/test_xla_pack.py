@@ -1,11 +1,10 @@
 """Packed XLA while-loop carry (core/carry.py via loop.make_run).
 
 Packing is a carry-LAYOUT change, never a semantic one: the packed run
-must be bitwise the per-leaf run on every Sim leaf, in both dtype
-profiles, batched and unbatched — and with the hierarchical event set
-riding along (the combined packed+hierarchical arm is the bench's new
-measured configuration).  ``pack=False`` / CPU default must reproduce
-the historical jaxpr exactly.
+(the accelerator backends' layout) must be bitwise the per-leaf run
+(the CPU's) on every Sim leaf, in both dtype profiles, batched and
+unbatched.  The layout follows the backend through
+``loop._packed_carry``; these tests drive both arms through it.
 """
 
 import jax
@@ -14,8 +13,9 @@ import numpy as np
 import pytest
 
 from cimba_tpu import config
-from cimba_tpu.core import carry
+from cimba_tpu.core import api, carry, cmd
 from cimba_tpu.core import loop as cl
+from cimba_tpu.core.model import Model
 from cimba_tpu.models import mm1
 
 
@@ -50,74 +50,104 @@ def test_pack_unpack_roundtrip_is_identity():
     "profile",
     [
         # heavyweight twins: over the timed tier-1 budget; tools/ci.sh cells
-        # run both (the cheaper unbatched/combined packed-vs-flat pins
+        # run both (the cheaper unbatched/timer-model packed-vs-flat pins
         # keep the packed bitwise contract tier-1)
         pytest.param("f64", marks=pytest.mark.slow),
         pytest.param("f32", marks=pytest.mark.slow),
     ],
 )
-def test_mm1_packed_matches_flat_bitwise(profile):
+def test_mm1_packed_matches_flat_bitwise(profile, carry_layout):
     with config.profile(profile):
         spec, _ = mm1.build(record=True)
         sims = jax.vmap(
             lambda r: cl.init_sim(spec, 7, r, mm1.params(50))
         )(jnp.arange(4))
-        flat = jax.jit(jax.vmap(cl.make_run(spec, pack=False)))(sims)
-        packed = jax.jit(jax.vmap(cl.make_run(spec, pack=True)))(sims)
+        carry_layout(False)
+        flat = jax.jit(jax.vmap(cl.make_run(spec)))(sims)
+        carry_layout(True)
+        packed = jax.jit(jax.vmap(cl.make_run(spec)))(sims)
         assert int(jnp.sum(flat.n_events)) > 300
         _assert_trees_equal(flat, packed)
 
 
 @pytest.mark.slow  # heavyweight: over the timed tier-1 budget; runs in tools/ci.sh cells
-def test_unbatched_packed_matches_flat():
+def test_unbatched_packed_matches_flat(carry_layout):
     spec, _ = mm1.build(record=False)
     sim = cl.init_sim(spec, 3, 0, mm1.params(40))
-    _assert_trees_equal(
-        jax.jit(cl.make_run(spec, pack=False))(sim),
-        jax.jit(cl.make_run(spec, pack=True))(sim),
-    )
+    carry_layout(False)
+    flat = jax.jit(cl.make_run(spec))(sim)
+    carry_layout(True)
+    _assert_trees_equal(flat, jax.jit(cl.make_run(spec))(sim))
 
 
 @pytest.mark.slow  # heavyweight: over the timed tier-1 budget; runs in tools/ci.sh cells
-def test_default_is_flat_jaxpr_on_cpu():
-    """SENTINEL: with CIMBA_XLA_PACK unset on the CPU backend, make_run
-    traces today's per-leaf jaxpr character-identically.  The
-    packed-differs and CIMBA_XLA_PACK=0 arms (both profiles) retired
-    into the gate-registry sweep (cimba_tpu/check/gates.py, via
-    tests/test_check.py and the ci.sh static-analysis cell)."""
+def test_default_is_flat_jaxpr_on_cpu(carry_layout):
+    """SENTINEL: on the CPU backend make_run traces the per-leaf jaxpr
+    character-identically, and the packed layout traces another."""
     if jax.default_backend() != "cpu":
-        pytest.skip("default-gate pin is for the CPU backend")
+        pytest.skip("default-layout pin is for the CPU backend")
     spec, _ = mm1.build(record=False)
     sim = cl.init_sim(spec, 1, 0, mm1.params(10))
     j_default = str(jax.make_jaxpr(cl.make_run(spec))(sim))
-    j_flat = str(jax.make_jaxpr(cl.make_run(spec, pack=False))(sim))
-    assert j_default == j_flat
+    carry_layout(False)
+    j_flat = str(jax.make_jaxpr(cl.make_run(spec))(sim))
+    carry_layout(True)
+    j_packed = str(jax.make_jaxpr(cl.make_run(spec))(sim))
+    assert j_default == j_flat != j_packed
 
 
-@pytest.mark.slow  # heavyweight: over the timed tier-1 budget; runs in tools/ci.sh cells (the ci.sh packed+hier smoke keeps a quick twin)
-def test_packed_plus_hier_combined_matches_flat():
-    """The full new arm (packed carry + hierarchical event set) against
-    the full old arm (per-leaf carry + flat scan) on a general-table-
-    heavy model: every shared Sim leaf bitwise equal."""
-    from test_eventset_hier import _layout, _timer_model
+def _timer_model(event_cap, per_resume, n_sched, n_exit):
+    """One process schedules ``per_resume`` far-future timers on each of
+    its first ``n_sched`` resumes (holding 0.1 between them, so the
+    table fills to per_resume * n_sched live timers before any fires),
+    then exits after ``n_exit`` total resumes — a general-table-heavy
+    workload (the shipped models keep the general table nearly empty).
+    Timer fires abort in-progress holds, so the pop interleavings cross
+    both tables."""
+    m = Model("tmr", n_ilocals=1, event_cap=event_cap)
 
-    def arm(hier, pack):
-        with _layout(hier):
-            spec = _timer_model(256, per_resume=10, n_sched=6, n_exit=16)
-            sims = jax.vmap(
-                lambda r: cl.init_sim(spec, 13, r, None)
-            )(jnp.arange(3))
-            return jax.jit(jax.vmap(cl.make_run(spec, pack=pack)))(sims)
+    @m.block
+    def tick(sim, p, sig):
+        k = api.local_i(sim, p, 0)
+        sim = api.add_local_i(sim, p, 0, 1)
+        arming = k < n_sched
+        for i in range(per_resume):
+            sim2, _ = api.timer_add(
+                sim, p, 3.0 + (i % 7) * 0.61 + (i % 3) * 1.7, 0
+            )
+            sim = cl._tree_select(arming, sim2, sim)
+        fin = k >= n_exit
+        return sim, cmd.select(
+            fin, cmd.exit_(), cmd.hold(0.1, next_pc=tick.pc)
+        )
 
-    old = arm(hier=False, pack=False)
-    new = arm(hier=True, pack=True)
-    assert not bool(jnp.any(old.err != 0))
-    new_by_path = dict(
+    m.process("ticker", entry=tick)
+    return m.build()
+
+
+@pytest.mark.slow  # heavyweight: over the timed tier-1 budget; runs in tools/ci.sh cells (the ci.sh packed timer-model smoke keeps a quick twin)
+def test_timer_model_packed_matches_flat(carry_layout):
+    """Packed carry against per-leaf carry on a general-table-heavy
+    model (256-slot table, timer fires aborting holds): every Sim leaf
+    bitwise equal, matched by path."""
+
+    def arm(packed):
+        carry_layout(packed)
+        spec = _timer_model(256, per_resume=10, n_sched=6, n_exit=16)
+        sims = jax.vmap(lambda r: cl.init_sim(spec, 13, r, None))(
+            jnp.arange(3)
+        )
+        return jax.jit(jax.vmap(cl.make_run(spec)))(sims)
+
+    flat, packed = arm(False), arm(True)
+    assert int(jnp.sum(flat.n_events)) > 40
+    assert not bool(jnp.any(flat.err != 0))
+    packed_by_path = dict(
         (jax.tree_util.keystr(p), l)
-        for p, l in jax.tree_util.tree_flatten_with_path(new)[0]
+        for p, l in jax.tree_util.tree_flatten_with_path(packed)[0]
     )
-    for path, a in jax.tree_util.tree_flatten_with_path(old)[0]:
-        b = new_by_path[jax.tree_util.keystr(path)]
+    for path, a in jax.tree_util.tree_flatten_with_path(flat)[0]:
+        b = packed_by_path[jax.tree_util.keystr(path)]
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b), err_msg=str(path)
         )
@@ -125,7 +155,7 @@ def test_packed_plus_hier_combined_matches_flat():
 
 @pytest.mark.slow  # heavyweight: over the timed tier-1 budget; runs in tools/ci.sh cells
 @pytest.mark.parametrize("profile", ["f64", "f32"])
-def test_mg1_sweep_packed_matches_flat_pooled(profile):
+def test_mg1_sweep_packed_matches_flat_pooled(profile, carry_layout):
     """M/G/1 sweep pooled statistics, packed vs flat, both profiles
     (the acceptance battery's second model)."""
     from cimba_tpu.models import mg1
@@ -137,8 +167,9 @@ def test_mg1_sweep_packed_matches_flat_pooled(profile):
         params, cells = mg1.sweep_params(120, reps_per_cell=2)
         R = len(cells)
         outs = []
-        for pack in (False, True):
-            res = ex.run_experiment(spec, params, R, seed=9, pack=pack)
+        for packed in (False, True):
+            carry_layout(packed)
+            res = ex.run_experiment(spec, params, R, seed=9)
             assert int(res.n_failed) == 0
             outs.append(res)
         _assert_trees_equal(outs[0].sims, outs[1].sims)

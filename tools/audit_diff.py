@@ -5,11 +5,10 @@ Usage::
 
     python tools/audit_diff.py A.json B.json [--json]
 
-``A``/``B`` are run cards (written by ``run_experiment_stream(audit=)``,
-``run_sweep(audit=)``, or ``bench.py`` under ``CIMBA_BENCH_RUN_CARD``)
-or bare digest-trail JSON lists.  The report names the FIRST divergent
-(wave, chunk, carry-class), environment drift, and result-digest
-equality.
+``A``/``B`` are run cards (written by
+``run_experiment_stream(audit=)`` or ``run_sweep(audit=)``) or bare
+digest-trail JSON lists. The report names the FIRST divergent (wave,
+chunk, carry-class), environment drift, and result-digest equality.
 
 CI-friendly exit codes::
 

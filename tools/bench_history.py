@@ -20,19 +20,20 @@ Usage::
 fingerprint, backend, workload-bucket) winner table beside the BENCH
 rounds, flagging groups whose winning schedule CHURNS across rounds.
 
-``--compile`` additionally collates the compile-wall lines
-(``bench.py --config compile_wall``, docs/25_compile_wall.md) into a
-per-(metric, table height) trend of compile wall seconds and program
-size across rounds, and flags a round whose scan-arm compile wall or
-equation count regressed beyond ``--max-regression`` percent — the
-compile-cost twin of the events/s regression check.
+``--compile`` additionally collates the compile-wall lines (the
+retired pre-chip harness's ``compile_wall`` config,
+docs/25_compile_wall.md) into a per-(metric, table height) trend of
+compile wall seconds and program size across rounds, and flags a round
+whose scan-arm compile wall or equation count regressed beyond
+``--max-regression`` percent — the compile-cost twin of the events/s
+regression check.
 
-``--fused`` additionally collates the wave-fusion lines (``bench.py
---config serve_fused``, docs/26_wave_fusion.md) into a per-round
-trend of the fused arm's events/s with the on-vs-off occupancy and
-events ratios and the superprogram's sublinearity, and flags a round
-whose ratios dropped beyond ``--max-regression`` percent or whose
-sublinearity crossed the JXL004 budget.
+``--fused`` additionally collates the wave-fusion lines (the retired
+pre-chip harness's ``serve_fused`` config, docs/26_wave_fusion.md)
+into a per-round trend of the fused arm's events/s with the on-vs-off
+occupancy and events ratios and the superprogram's sublinearity, and
+flags a round whose ratios dropped beyond ``--max-regression`` percent
+or whose sublinearity crossed the JXL004 budget.
 
 Exit codes: 0 ok, 1 regression beyond ``--max-regression`` percent,
 2 nothing to collate.  Stdlib-only (no jax import) — safe in any CI
@@ -312,14 +313,14 @@ def main(argv=None) -> int:
     )
     ap.add_argument(
         "--compile", action="store_true",
-        help="also collate compile-wall lines (bench.py --config "
-        "compile_wall) into a per-table-height trend with its own "
+        help="also collate compile-wall lines (the retired "
+        "harness's compile_wall config) into a per-table-height trend with its own "
         "regression check (docs/25_compile_wall.md)",
     )
     ap.add_argument(
         "--fused", action="store_true",
-        help="also collate wave-fusion lines (bench.py --config "
-        "serve_fused) into a per-round occupancy/events-ratio trend "
+        help="also collate wave-fusion lines (the retired "
+        "harness's serve_fused config) into a per-round occupancy/events-ratio trend "
         "with its own regression check (docs/26_wave_fusion.md)",
     )
     ap.add_argument(

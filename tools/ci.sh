@@ -3,7 +3,7 @@
 # `.github/workflows/ci.yml:12-158`, transposed to trace-time tiers):
 #   tests x {default, CIMBA_NDEBUG=1, CIMBA_NASSERT=1} x {1, 8 virtual devs}
 # (each cell includes the golden seed-pinned suite) plus behaviour smokes.
-# Speed is measured on the chip only (bench.py, chip_smoke.py).
+# Speed is measured on the chip only (benchmark/run.py, chip_smoke.py).
 #
 # Usage: tools/ci.sh [quick]
 #   quick = the default+8dev cell and the golden suite (PR gate);
@@ -139,26 +139,26 @@ print("compile wall smoke OK: bitwise", len(dense), "leaves |",
       f"scan-on eqns flat {sizes} | JXL004 fired at {n_bad} > {budget}")
 EOF
 
-# packed+hierarchical smoke: a timer-heavy model must run
-# bitwise-identical under the packed carry + hierarchical event-set min
-# arm and the flat oracle arm (docs/11_dispatch_cost.md; bench.py, which
-# measures both arms, runs on the chip only)
-run_cell "packed+hier smoke" python - <<'EOF'
+# packed timer-model smoke: a timer-heavy model must run
+# bitwise-identical under the packed carry (the accelerator backends'
+# layout) and the per-leaf carry (the CPU's), the rule in
+# core/loop._packed_carry overridden for each arm (docs/11_dispatch_cost.md)
+run_cell "packed timer-model smoke" python - <<'EOF'
 import sys
 import jax, jax.numpy as jnp, numpy as np
 sys.path.insert(0, "tests")
-from test_eventset_hier import _layout, _timer_model
+from test_xla_pack import _timer_model
 from cimba_tpu.core import loop as cl
-def arm(hier, pack):
-    with _layout(hier):
-        spec = _timer_model(256, per_resume=10, n_sched=6, n_exit=16)
-        sims = jax.vmap(lambda r: cl.init_sim(spec, 2, r, None))(jnp.arange(2))
-        return jax.jit(jax.vmap(cl.make_run(spec, pack=pack)))(sims)
-old, new = arm(False, False), arm(True, True)
+def arm(packed):
+    cl._packed_carry = lambda: packed
+    spec = _timer_model(256, per_resume=10, n_sched=6, n_exit=16)
+    sims = jax.vmap(lambda r: cl.init_sim(spec, 2, r, None))(jnp.arange(2))
+    return jax.jit(jax.vmap(cl.make_run(spec)))(sims)
+old, new = arm(False), arm(True)
 assert int(jnp.sum(old.n_events)) > 0 and not bool(jnp.any(old.err != 0))
 np.testing.assert_array_equal(np.asarray(old.clock), np.asarray(new.clock))
 np.testing.assert_array_equal(np.asarray(old.n_events), np.asarray(new.n_events))
-print("packed+hier trajectory smoke OK:", int(jnp.sum(old.n_events)), "events")
+print("packed timer-model trajectory smoke OK:", int(jnp.sum(old.n_events)), "events")
 EOF
 
 # stream smoke: the chunked, donated dispatch path and the wave-streamed
@@ -1181,7 +1181,7 @@ from cimba_tpu.serve import store as pstore
 spec, _ = probe.build(event_cap=8, per_resume=1, hold=0.5)
 rep = tune.search_schedule(
     spec, None, 8, t_end=4.0, seed=7, repeats=2,
-    candidates=[Schedule(), Schedule(pack=True), Schedule(chunk_steps=8)],
+    candidates=[Schedule(), Schedule(chunk_steps=64), Schedule(chunk_steps=8)],
     workload_label="ci-tiny",
 )
 assert all(r["pinned"] is not False for r in rep.arms), rep.arms

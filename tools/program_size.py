@@ -5,8 +5,8 @@ Traces and lowers a model's chunk program — never compiles, never
 executes — and prints the size numbers that predict the compile wall:
 jaxpr equation count, jaxpr/HLO text bytes, HLO proto bytes, and the
 trace/lower wall seconds.  The library half is
-``cimba_tpu.obs.program_size`` (shared with tune/measure, the serve
-store manifest, and ``bench.py --config compile_wall``).
+``cimba_tpu.obs.program_size`` (shared with tune/measure and the
+serve store manifest).
 
 Usage:
     python tools/program_size.py --model awacs --scale 1001 --scan on
