@@ -12,6 +12,7 @@ from cimba_tpu.core.loop import (
     init_sim,
     make_chunk,
     make_chunked_run,
+    make_lanes_run,
     make_run,
     make_step,
 )
@@ -31,6 +32,7 @@ __all__ = [
     "init_sim",
     "make_chunk",
     "make_chunked_run",
+    "make_lanes_run",
     "make_run",
     "make_step",
     "Model",

@@ -1961,7 +1961,14 @@ def make_step(
     form instead of a ``lax.switch`` that select-merges every Sim leaf
     per level; the lanes are bitwise the same either way.  Leave it
     False for a scalar (unbatched) run, which then executes one branch
-    per level."""
+    per level.
+
+    The step is ``step(sim, gate=None)``.  A false ``gate`` makes it a
+    no-op whose output is its input bitwise: the lanes' loop
+    (:func:`make_lanes_run`) passes each lane's liveness there, and
+    :func:`make_boundary_apply` each lane's ``boundary_pending``.
+    Without a gate the step traces as it did before the argument
+    existed."""
     blocks = list(spec.blocks)
 
     def _defer():
@@ -2216,10 +2223,13 @@ def make_step(
         else None
     )
 
-    def step(sim: Sim) -> Sim:
+    def step(sim: Sim, gate=None) -> Sim:
         event, take_e, take_w = ev.peek_merged(
             sim.events, sim.wakes, sim.procs.prio, K_PROC
         )
+        # every write below is gated by ``found`` (the dispatch's
+        # handlers included) or folds the gate in itself
+        found = event.found if gate is None else event.found & gate
         if _defer():
             # a resume whose target block is a boundary block is NOT
             # dispatched here: the event stays in its table, the lane
@@ -2231,14 +2241,19 @@ def make_step(
                 sim.procs.pc, jnp.maximum(event.subj, 0)
             )
             is_b = boundary_table[pc_t] != 0
-            boundary = event.found & (event.kind <= K_TIMER) & is_b
-            proceed = event.found & ~boundary
+            boundary = found & (event.kind <= K_TIMER) & is_b
+            proceed = found & ~boundary
             not_deferred = ~boundary
+            if gate is not None:
+                boundary = jnp.where(gate, boundary, sim.boundary_pending)
             sim = sim._replace(boundary_pending=boundary)
         else:
-            proceed = event.found
+            proceed = found
             not_deferred = True
         out_of_events = ~event.found  # BEFORE the boundary defer masks it
+        if gate is not None:
+            out_of_events = out_of_events & gate
+            not_deferred = _and(not_deferred, gate)
         event = event._replace(found=proceed)
         # event-set occupancy BEFORE the consume (the popped event still
         # pends): the high-water gauge of how close this replication came
@@ -2343,10 +2358,12 @@ def make_cond(spec: ModelSpec, t_end: Optional[float] = None,
 
 
 def _packed_carry() -> bool:
-    """The while loop's carry layout: packed on accelerator backends,
-    where the per-leaf carry's cost was measured (BENCH_NOTES round 5
-    floor probes), per leaf on the CPU.  Both layouts run the same
-    step and cond, so trajectories are bitwise identical either way."""
+    """The one-replication loop's carry layout (:func:`make_run`):
+    packed on accelerator backends, where the per-leaf carry's cost was
+    measured (BENCH_NOTES round 5 floor probes), per leaf on the CPU.
+    Both layouts run the same step and cond, so trajectories are
+    bitwise identical either way.  The batched lanes' loop
+    (:func:`make_lanes_run`) carries per leaf on every backend."""
     return jax.default_backend() != "cpu"
 
 
@@ -2354,7 +2371,6 @@ def make_run(
     spec: ModelSpec,
     t_end: Optional[float] = None,
     max_steps: Optional[int] = None,
-    batched: bool = False,
 ):
     """Build ``run(sim) -> sim``: dispatch events until the model stops
     (api.stop), fails, runs out of events, or passes ``t_end``
@@ -2380,9 +2396,11 @@ def make_run(
     tests/test_stream.py).  ``None`` (the default) keeps today's
     unbounded loop, jaxpr-identical to before this knob existed.
 
-    ``batched=True`` when the caller vmaps ``run`` (the step's merge-free
-    dispatch, :func:`make_step`); a scalar run keeps ``lax.switch``."""
-    step = make_step(spec, batched=batched)
+    ``run`` is the one-replication loop and dispatches by ``lax.switch``;
+    ``jax.vmap(run)`` gives the same lanes, but a batched caller builds
+    :func:`make_lanes_run` instead, whose step dispatches merge-free and
+    whose frozen lanes cost no per-iteration select."""
+    step = make_step(spec)
     cond = make_cond(spec, t_end)
     if max_steps is not None and max_steps <= 0:
         raise ValueError(f"max_steps must be positive, got {max_steps}")
@@ -2452,6 +2470,61 @@ def make_run(
     return run
 
 
+def make_lanes_run(
+    spec: ModelSpec,
+    t_end: Optional[float] = None,
+    defer_boundary: Optional[bool] = None,
+):
+    """Build ``run(sims, kmax=None) -> (steps, sims, live)`` over a
+    BATCHED Sim (leading lane axis): the lanes' loop of every batched
+    XLA program (:func:`make_chunk`, the boundary chunk's rounds,
+    ``runner.experiment``).  It runs outside ``vmap``, on one unbatched
+    condition::
+
+        while (steps < kmax) & any(live):
+            sims = vmap(step)(sims, gate=live)
+            live = vmap(cond)(sims)
+
+    A lane whose :func:`make_cond` is false steps with its gate off,
+    and a gated-off step returns its input bitwise (the self-gated
+    step, :func:`make_step`), so each lane dispatches exactly the
+    events of ``jax.vmap`` over :func:`make_run`.  ``vmap`` of a while
+    loop would instead freeze such lanes by a select of every carried
+    leaf at every iteration (jax's batched-while rule), and evaluate
+    the cond twice.  ``kmax`` (an int or a traced scalar; ``None``: no
+    bound) bounds the iterations, so no lane dispatches more; ``steps``
+    is the count run, ``live`` each lane's cond on the returned Sim.
+    ``defer_boundary`` as in :func:`make_step`.
+
+    The loop carries the Sim per leaf on every backend: on a v5e the
+    packed carry's (core/carry.py) per-lane columns cost more to read
+    back each step than the selects this loop saves (PERF.md, section
+    6)."""
+    step = make_step(spec, defer_boundary=defer_boundary, batched=True)
+    cond = make_cond(spec, t_end, defer_boundary=defer_boundary)
+
+    def lstep(sim, gate):
+        sim = step(sim, gate)
+        return sim, cond(sim)
+
+    def run(sims: Sim, kmax=None):
+        def lcond(c):
+            i, _, live = c
+            any_live = jnp.any(live)
+            return any_live if kmax is None else (i < kmax) & any_live
+
+        def lbody(c):
+            i, sims, live = c
+            sims, live = jax.vmap(lstep)(sims, live)
+            return i + jnp.asarray(1, _I), sims, live
+
+        return lax.while_loop(
+            lcond, lbody, (jnp.zeros((), _I), sims, jax.vmap(cond)(sims))
+        )
+
+    return run
+
+
 # --- chunked dispatch: watchdog-proof runs of any length ---------------------
 
 
@@ -2469,18 +2542,11 @@ def make_boundary_apply(spec: ModelSpec):
     def apply(sims: Sim) -> Sim:
         with jax.named_scope("runner.boundary"):
             pending = sims.boundary_pending
-            cleared = sims._replace(
-                boundary_pending=jnp.zeros_like(pending)
-            )
-            stepped = xstep(cleared)
-            return jax.tree.map(
-                lambda a, b: jnp.where(
-                    pending.reshape(pending.shape + (1,) * (a.ndim - 1)),
-                    a,
-                    b,
-                ),
-                stepped,
-                cleared,
+            # the step gated by ``pending``: the other lanes come out
+            # bitwise as they went in, with no per-leaf merge
+            return xstep(
+                sims._replace(boundary_pending=jnp.zeros_like(pending)),
+                pending,
             )
 
     return apply
@@ -2509,20 +2575,7 @@ def _make_boundary_chunk(spec: ModelSpec, t_end, max_steps: int):
     lane dispatches more.  Returns ``chunk(sims) -> (sims, any_live,
     counters)``, ``counters`` the :data:`BOUNDARY_COUNTERS` of this
     chunk (config.COUNT)."""
-    lstep = make_step(spec, defer_boundary=True, batched=True)
-    lcond = make_cond(spec, t_end, defer_boundary=True)
-
-    def lane_run(sim: Sim, kmax):
-        # the per-leaf carry, never the packed one: packing concatenates
-        # and slices the whole state every step, and a boundary model's
-        # few wide tables (AWACS: 100 kB a lane) pay for it (PERF.md,
-        # section 4)
-        return lax.while_loop(
-            lambda kc: lcond(kc[1]) & (kc[0] < kmax),
-            lambda kc: (kc[0] + jnp.asarray(1, _I), lstep(kc[1])),
-            (jnp.zeros((), _I), sim),
-        )
-
+    lanes_run = make_lanes_run(spec, t_end, defer_boundary=True)
     vcond = jax.vmap(make_cond(spec, t_end, defer_boundary=True))
     apply = make_boundary_apply(spec)
     round_steps = spec.boundary_round_steps or max_steps
@@ -2538,11 +2591,11 @@ def _make_boundary_chunk(spec: ModelSpec, t_end, max_steps: int):
         def rbody(c):
             used, sims, ctr = c
             limit = jnp.minimum(round_steps, max_steps - used)
-            k, sims = jax.vmap(lane_run, in_axes=(0, None))(sims, limit)
+            k, sims, _ = lanes_run(sims, limit)
             pending = sims.boundary_pending
             some = jnp.any(pending)
             sims = lax.cond(some, apply, lambda s: s, sims)
-            steps = jnp.max(k) + some.astype(_I)
+            steps = k + some.astype(_I)
             ctr = ctr + jnp.stack([
                 some.astype(_C),
                 jnp.sum(pending, dtype=_C),
@@ -2584,10 +2637,12 @@ def make_chunk(
     the chunk jaxpr is character-identical to one built before the
     knob existed (pinned in tests/test_audit.py).
 
-    A model with boundary blocks gets :func:`_make_boundary_chunk`'s
-    program, whose loops carry the Sim per leaf on every backend, and
-    whose outputs carry its counters third, before an audit
-    digest."""
+    The lanes' loop is :func:`make_lanes_run`'s, which carries the Sim
+    per leaf on every backend.  A model with boundary blocks gets
+    :func:`_make_boundary_chunk`'s program, whose outputs carry its
+    counters third, before an audit digest."""
+    if max_steps <= 0:
+        raise ValueError(f"max_steps must be positive, got {max_steps}")
     if spec.boundary_pcs:
         bchunk = _make_boundary_chunk(spec, t_end, max_steps)
         if not audit:
@@ -2601,27 +2656,20 @@ def make_chunk(
 
         return audited
 
-    bounded = make_run(
-        spec, t_end=t_end, max_steps=max_steps, batched=True
-    )
-    cond = make_cond(spec, t_end)
+    lanes_run = make_lanes_run(spec, t_end)
 
     if not audit:
         def chunk(sims: Sim):
-            sims = jax.vmap(bounded)(sims)
-            return sims, jnp.any(jax.vmap(cond)(sims))
+            _, sims, live = lanes_run(sims, max_steps)
+            return sims, jnp.any(live)
 
         return chunk
 
     from cimba_tpu.obs import audit as obs_audit
 
     def chunk(sims: Sim):
-        sims = jax.vmap(bounded)(sims)
-        return (
-            sims,
-            jnp.any(jax.vmap(cond)(sims)),
-            obs_audit.sim_digest(sims),
-        )
+        _, sims, live = lanes_run(sims, max_steps)
+        return sims, jnp.any(live), obs_audit.sim_digest(sims)
 
     return chunk
 
@@ -2640,11 +2688,10 @@ def make_refill(spec: ModelSpec):
     perturb its wave-mates — and a refilled lane starts from exactly
     the state its solo run would start from, which is what makes a
     refilled request's result bitwise its solo run's (trajectories are
-    lane-local under vmap; chunk phase is trajectory-invariant).  Works
-    on either carry layout: the batched Sim BETWEEN chunks is always
-    the plain per-leaf pytree (packing lives inside the while-loop
-    carry), so one refill program serves packed and per-leaf chunk
-    programs alike, under both dtype profiles.
+    lane-local under vmap; chunk phase is trajectory-invariant).  The
+    batched Sim between chunks is the plain per-leaf pytree, so one
+    refill program serves every chunk program of its class, under both
+    dtype profiles.
 
     The wave must carry the per-lane ``t_stop`` leaf (refill waves
     always do — lane death and reclamation are horizon-driven); a

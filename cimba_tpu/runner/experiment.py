@@ -38,7 +38,7 @@ from cimba_tpu.core.loop import (
     drive_chunks,
     init_sim,
     make_chunk,
-    make_run,
+    make_lanes_run,
 )
 from cimba_tpu.core.model import ModelSpec
 from cimba_tpu.stats import summary as sm
@@ -151,14 +151,13 @@ def run_experiment(
     ``profile_dir`` additionally wraps the execute leg in a
     ``jax.profiler.trace`` context writing there.
     """
-    run = make_run(spec, t_end=t_end, batched=True)
+    run = make_lanes_run(spec, t_end=t_end)
     pb = _broadcast_params(params, n_replications)
     reps = jnp.arange(n_replications)
 
-    def one(rep, p):
-        return run(init_sim(spec, seed, rep, p))
-
-    vm = jax.vmap(one)
+    def vm(reps, p):
+        sims = jax.vmap(lambda r, q: init_sim(spec, seed, r, q))(reps, p)
+        return run(sims)[1]
 
     timings = None
     if mesh is None:
@@ -1011,7 +1010,7 @@ def make_sharded_experiment(
     """
     from cimba_tpu.obs import metrics as _metrics
 
-    run = make_run(spec, t_end=t_end, batched=True)
+    run = make_lanes_run(spec, t_end=t_end)
     reps = jnp.arange(n_replications)
     with_metrics = _metrics.enabled()
 
@@ -1023,10 +1022,9 @@ def make_sharded_experiment(
         check_vma=False,
     )
     def sharded(reps_local, p_local, seed):
-        def one_seeded(rep, p):
-            return run(init_sim(spec, seed, rep, p))
-
-        sims = jax.vmap(one_seeded)(reps_local, p_local)
+        sims = run(jax.vmap(
+            lambda r, q: init_sim(spec, seed, r, q)
+        )(reps_local, p_local))[1]
         if (sims.metrics is None) == with_metrics:
             # the flag bound at build time; init_sim re-reads it at trace
             # time — fail with the subsystem's loud, named error instead

@@ -87,6 +87,15 @@ def test_boundary_counters_count_the_dwells():
     assert 51 <= c["boundary_rounds"] < 2 * 51
 
 
+def test_boundary_counters_are_the_vmapped_loops():
+    """The counters as the lanes' loop ran under ``vmap`` of a while
+    loop: the rounds, dwells and lane-steps of these lanes, to the
+    count."""
+    _, _, c = _per_event_and_chunked(16)
+    assert c == {"boundary_rounds": 52, "boundary_dispatches": 408,
+                 "lane_steps_run": 1256, "lane_steps_dispatched": 591}
+
+
 def test_stream_result_and_span_carry_the_counters():
     with config.profile("f32"):
         spec, _ = awacs.build(8, dwell=DWELL)
@@ -188,27 +197,29 @@ def test_dwell_is_a_build_parameter():
         assert int(sim.user["dwells"]) == dwells
 
 
-# sha256 of each program's jaxpr text (mm1 with a FIFO of 128, mg1 of
-# 512; 8 lanes, chunk_steps 16, horizon column 50; fold over the default
-# summary path): init and fold as before the boundary phase existed, the
-# chunk programs as the batched step's merge-free dispatch traces them
-# (their lanes pinned bitwise the lax.switch form's by
-# tests/test_batched_dispatch.py); 'chunk' the per-leaf carry (the CPU's),
-# 'chunk_packed' the packed one (an accelerator's).  AWACS (8 targets,
-# the same lanes, chunk steps and horizon): its boundary chunk, per leaf
-# on every backend, as it was when the carry layout was still an option.
-PARENT_SHA256 = {
+# sha256 of each program's jaxpr text as the current programs trace (mm1
+# with a FIFO of 128, mg1 of 512; 8 lanes, chunk_steps 16, horizon column
+# 50; fold over the default summary path): init and fold as before the
+# boundary phase existed; the chunk programs as the lanes' loop
+# (loop.make_lanes_run) traces them, on one any-lane-live condition with
+# the self-gated, merge-free step (their lanes pinned bitwise the
+# lax.switch form's by tests/test_batched_dispatch.py).  The lanes' loop
+# carries per leaf on every backend, so 'chunk' (the backend's carry rule
+# reading per leaf, the CPU's) and 'chunk_packed' (reading packed, an
+# accelerator's) are one program.  AWACS (8 targets, the same lanes, chunk
+# steps and horizon): its boundary chunk.
+PROGRAM_SHA256 = {
     ('awacs', 'f32', 'chunk'): (
-        "ad47541079da8e345f4635d4bdcbb213c77e6bde654a8884d2d5683d6eb37f16"
+        "e30f81f26d43d9e42af0956a354035685da096eb3e4a761b4279aea95a870fc9"
     ),
     ('awacs', 'f64', 'chunk'): (
-        "bfa1441bd980b8f8670940e612d59ce6b0101291818f8c2f46a4f5be7eba0791"
+        "d48e44e40f7d807b8a0e94b6f7952dc0a8cc6b10734b4878f18c0e282471dfe5"
     ),
     ('mg1', 'f32', 'chunk'): (
-        "6bbc6ef46d65be06aa5ddb9ca5d3e3228bcd56cabf01858b60dbeb94d825be6d"
+        "de1c9b42610667eb952b7d563c83fe7de5fcf05a30229d6c6e899553f6c2ac4e"
     ),
     ('mg1', 'f32', 'chunk_packed'): (
-        "a41df60e71cbd905b3edc4f817c802f5f58c513daee9386c8f9c77f3fa8ed3cb"
+        "de1c9b42610667eb952b7d563c83fe7de5fcf05a30229d6c6e899553f6c2ac4e"
     ),
     ('mg1', 'f32', 'fold'): (
         "3fb9aa6f030ef6666c746cc15cc0e69e93b7a2b2b9265406e9b533ffd6a0f68b"
@@ -217,10 +228,10 @@ PARENT_SHA256 = {
         "ab5b51ef2941702aeb459d7e2066820fabd3705dc7a7610b23b3fac718cbacac"
     ),
     ('mg1', 'f64', 'chunk'): (
-        "9aa609b02918e5e8c044427fa0c9e01e546fbacf90ac4e9f491ca42ff48719eb"
+        "916124d8b204447df088e37c700f7c5f649edeff8d850a9749f68952d53444db"
     ),
     ('mg1', 'f64', 'chunk_packed'): (
-        "6dd40d101032154442411d92a6039a8fdfba39123202f0e22c8368f878a25c72"
+        "916124d8b204447df088e37c700f7c5f649edeff8d850a9749f68952d53444db"
     ),
     ('mg1', 'f64', 'fold'): (
         "f05b06ed07c5fe26b8f4c734186230eac5f293b78b92a2fe9604a6bb3ddc1157"
@@ -229,10 +240,10 @@ PARENT_SHA256 = {
         "7fbc8bcd7c4f84dd1fd3ac7e9f6989c8fcbbf92a6ffb4881b37118be3fa702e8"
     ),
     ('mm1', 'f32', 'chunk'): (
-        "f6e6ee2d67ee018a6c3aeec94f8806ab734d1ffdc74014ae5087d6fc9dc416c2"
+        "9a0925afbce746fd266f95cd2c77710f5da63ad613aea8a4fcd3292a6d3192ff"
     ),
     ('mm1', 'f32', 'chunk_packed'): (
-        "8f828a50aa21460cc22dafc57b278d0bf0408164a174f27e36cbd8e6c4055b23"
+        "9a0925afbce746fd266f95cd2c77710f5da63ad613aea8a4fcd3292a6d3192ff"
     ),
     ('mm1', 'f32', 'fold'): (
         "18571c07247feaf6aef88328af2110e19e2ee1b9612becd2fb858544b581e4fe"
@@ -241,10 +252,10 @@ PARENT_SHA256 = {
         "1effc0a98b36a1cf043c8f47f7e4b44b7d5a7c10444e121cfb9f9f4fcf74037f"
     ),
     ('mm1', 'f64', 'chunk'): (
-        "0063c979d12e1db5567fa6c525612b9f5ba7861c1fcc469f891ec90b851e67a7"
+        "a4df909b217b960f77abb55eb093b5ef11ee9850bc2437d2e0b84b6612f67ddb"
     ),
     ('mm1', 'f64', 'chunk_packed'): (
-        "202ab6ac442670c8106c50b1267c0d9df8a9af9ba30f21f2d4c976e8997fa961"
+        "a4df909b217b960f77abb55eb093b5ef11ee9850bc2437d2e0b84b6612f67ddb"
     ),
     ('mm1', 'f64', 'fold'): (
         "2badfe19d0059a738d9932ea5bd3bd574c7fbc4a30187c5e0c36a38a1f580ec7"
@@ -294,7 +305,7 @@ def test_programs_without_boundary_blocks_are_unchanged(
     carry_layout(pack)
     for kind, text in _programs(name, profile, pack).items():
         digest = hashlib.sha256(text.encode()).hexdigest()
-        assert digest == PARENT_SHA256[(name, profile, kind)], kind
+        assert digest == PROGRAM_SHA256[(name, profile, kind)], kind
 
 
 def _digest(text):
@@ -304,14 +315,15 @@ def _digest(text):
 @pytest.mark.parametrize("profile", ["f32", "f64"])
 def test_awacs_chunk_program_is_unchanged(profile):
     text = _programs("awacs", profile, False)["chunk"]
-    assert _digest(text) == PARENT_SHA256[("awacs", profile, "chunk")]
+    assert _digest(text) == PROGRAM_SHA256[("awacs", profile, "chunk")]
 
 
 @pytest.mark.parametrize("backend,kind", [("cpu", "chunk"),
                                           ("tpu", "chunk_packed")])
 def test_backend_picks_the_carry(backend, kind, monkeypatch):
-    """The carry rule reads the backend: the CPU's mm1 chunk is the
-    per-leaf program, the chip's the packed one."""
+    """The carry rule reads the backend (``loop._packed_carry``, for the
+    one-replication ``make_run``); the mm1 chunk program is the same
+    per-leaf lanes' loop whichever way it reads."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     with config.profile("f32"):
         spec = mm1.build(queue_cap=128, record=False)[0]
@@ -324,4 +336,4 @@ def test_backend_picks_the_carry(backend, kind, monkeypatch):
             ex._horizon_column(50.0, lanes),
             ex._slice_params(mm1.params(10), lanes, 0, lanes))
         text = str(jax.make_jaxpr(chunk_j)(sims))
-    assert _digest(text) == PARENT_SHA256[("mm1", "f32", kind)]
+    assert _digest(text) == PROGRAM_SHA256[("mm1", "f32", kind)]

@@ -37,14 +37,13 @@ def log(**kw):
 def xla_scaling(N=500):
     log(phase="xla_scaling_start", backend=jax.default_backend(), N=N)
     spec, _ = mm1.build(record=False)
-    run = cl.make_run(spec, batched=True)
+    run = cl.make_lanes_run(spec)
 
     for R in (4096, 8192, 16384, 32768):
         def experiment(n):
-            def one(rep):
-                return run(cl.init_sim(spec, 2026, rep, mm1.params(n)))
-
-            sims = jax.vmap(one)(jnp.arange(R))
+            sims = run(jax.vmap(
+                lambda rep: cl.init_sim(spec, 2026, rep, mm1.params(n))
+            )(jnp.arange(R)))[1]
             return (
                 jnp.sum(sims.n_events),
                 jnp.sum((sims.err != 0).astype(jnp.int32)),
@@ -93,13 +92,12 @@ def awacs_scaling(t_end=40.0):
     log(phase="awacs_scaling_start", backend=jax.default_backend(),
         t_end=t_end)
     spec, _ = awacs.build(1000)
-    run = cl.make_run(spec, batched=True)
+    run = cl.make_lanes_run(spec)
     for R in (64, 256):
         def experiment(t):
-            def one(rep):
-                return run(cl.init_sim(spec, 2026, rep, (t,)))
-
-            sims = jax.vmap(one)(jnp.arange(R))
+            sims = run(jax.vmap(
+                lambda rep: cl.init_sim(spec, 2026, rep, (t,))
+            )(jnp.arange(R)))[1]
             return (
                 jnp.sum(sims.n_events),
                 jnp.sum((sims.err != 0).astype(jnp.int32)),

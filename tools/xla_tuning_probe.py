@@ -28,13 +28,12 @@ def log(**kw):
 def cell(tag, R, N, cap=128, prof="f32"):
     with config.profile(prof):
         spec, _ = mm1.build(queue_cap=cap, record=False)
-        run = cl.make_run(spec, batched=True)
+        run = cl.make_lanes_run(spec)
 
         def experiment(n):
-            def one(rep):
-                return run(cl.init_sim(spec, 2026, rep, mm1.params(n)))
-
-            sims = jax.vmap(one)(jnp.arange(R))
+            sims = run(jax.vmap(
+                lambda rep: cl.init_sim(spec, 2026, rep, mm1.params(n))
+            )(jnp.arange(R)))[1]
             return (
                 jnp.sum(sims.n_events.astype(jnp.int64)),
                 jnp.sum((sims.err != 0).astype(jnp.int32)),
